@@ -66,6 +66,8 @@ def main() -> None:
     ap.add_argument("--compare", default=None, metavar="BASELINE.json",
                     help="fail on >25%% speedup regression vs this baseline")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     # ms-scale 2048 cells anchor the smoke comparison - the sub-ms cells
     # alone are within timer noise of a shared CI runner

@@ -523,6 +523,14 @@ def main() -> None:
                          "(single-device sweep only)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import refuse_local_processes
+    if args.multiproc and args.process_id is None:
+        # the parent measures in-process before spawning: on a TPU host it
+        # would hold the chips its children need
+        refuse_local_processes("--multiproc")
+    enable_compile_cache()
+
     if args.process_id is not None:
         # --multiproc child: join the jax.distributed job BEFORE any
         # device query, then follow the coordinator

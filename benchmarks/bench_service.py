@@ -137,6 +137,8 @@ def main() -> None:
     ap.add_argument("--compare", default=None, metavar="BASELINE.json",
                     help="fail on >25%% speedup regression vs this baseline")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = reduced_config(ARCH)
     from repro.models import build_model

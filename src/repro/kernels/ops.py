@@ -20,10 +20,12 @@ import numpy as np
 from . import ref
 from .act_stats import act_stats_p
 from .kv_cache import (cache_scatter_p, cache_scatter_pages_p,
-                       decode_attend_i8kv_fused_p, decode_attend_i8kv_p)
+                       decode_attend_i8kv_fused_p, decode_attend_i8kv_p,
+                       scatter_rows_block)
 from .pdq_prologue import pdq_prologue_p
 from .quantize import dequantize_p, quantize_p
-from .w8a8_matmul import w8a8_matmul_p, w8a8_swiglu_matmul_p
+from .w8a8_matmul import (swiglu_block_rows, w8a8_matmul_p,
+                          w8a8_swiglu_matmul_p)
 
 _IMPL = "auto"
 
@@ -55,7 +57,9 @@ def _use_kernel() -> bool:
 # concatenates the column blocks in axis order.  The PDQ prologue is
 # intentionally NOT split: its (x_q, s_x, s1, s2) depend on the whole input
 # row, are O(K) to compute, and every shard needs them - recomputing
-# locally is cheaper than a broadcast.
+# locally is cheaper than a broadcast.  For the same reason the fused
+# SwiGLU gate/up kernel (``pdq_mlp``) runs whole on every shard: the
+# w_down prologue it emits needs the full silu(g)*u row.
 
 _TP: tuple[str, int] | None = None     # (mesh axis name, axis size)
 
@@ -163,6 +167,28 @@ def pdq_telemetry(enable: bool = True):
         yield col
     finally:
         _PDQ_TEL = prev
+
+
+def pdq_telemetry_scan(body, init, xs):
+    """``lax.scan(body, init, xs)`` for a body that traces PDQ projections
+    (the model's layer-stacked blocks).  Collector scalars traced inside a
+    scan body must not escape it: each iteration collects into its own
+    collector, returns the (3,) summary as a scan output, and the
+    layer-summed summary is recorded into the enclosing collector."""
+    outer = _PDQ_TEL
+
+    def wrapped(carry, x):
+        with pdq_telemetry(outer is not None) as col:
+            carry, y = body(carry, x)
+            return carry, (y, col.summary())
+
+    carry, (ys, tel) = jax.lax.scan(wrapped, init, xs)
+    if outer is not None:
+        tel = jnp.sum(tel, axis=0)
+        outer.fallbacks.append(tel[0])
+        outer.clip_hits.append(tel[1])
+        outer.clip_total.append(tel[2])
+    return carry, ys
 
 
 def _tel_clip(y, lo, hi):
@@ -429,9 +455,10 @@ def pdq_dense_from_prologue(x, x_q, s_x, s1, s2, wrec, *, out_dtype=None,
     flash-decode attend kernel's output stage (``decode_attend_i8kv`` with
     ``wo_prologue=True``); this entry consumes those (x_q, s_x, s1, s2)
     directly, so the projection costs ONE pallas_call instead of two.  The
-    fp ``x`` is still required: the guarded fallback and the TP fallback
-    precision recompute from it.  Numerics are identical to ``pdq_dense``
-    by construction (it is the same tail).
+    fp ``x`` is read only by the guarded fallback, which recomputes from it
+    (``pdq_mlp``'s fused path passes None: it never runs guarded).
+    Numerics are identical to ``pdq_dense`` by construction (it is the
+    same tail).
     """
     if out_dtype is None:
         out_dtype = jnp.float32
@@ -556,19 +583,22 @@ def pdq_mlp(x, grec, down_rec, *, out_dtype=None, block=(128, 128, 128),
     ``pdq_prologue_p`` launch runs between the two matmuls (DESIGN.md
     "Decode fast path").
 
+    Under tensor parallelism every shard runs the whole fused gate/up
+    kernel (w_down's prologue needs the full silu(g)*u row, which a
+    column-split shard does not hold) and only w_down splits its columns,
+    so the sharded MLP computes exactly what one device computes.
+
     Falls back to the exact unfused composition (``pdq_dense_grouped`` +
     jnp silu + ``pdq_dense``) whenever the fused epilogue cannot apply:
-    ref/auto-off-TPU mode (bit-identical numerics preserved), tensor
-    parallelism (each shard owns an N-slice of BOTH segments but the
-    prologue needs the full hsw row), an active ``pdq_guard`` (the
-    fallback branch needs the guarded gate/up output), or a group layout
-    that is not two equal lane-padded segments.
+    ref/auto-off-TPU mode (bit-identical numerics preserved), an active
+    ``pdq_guard`` (the fallback branch needs the guarded gate/up output),
+    or a group layout that is not two equal lane-padded segments.
     """
     if out_dtype is None:
         out_dtype = jnp.float32
     segs = grec["segs"]
     bm, bn, bk = block
-    fused = (_use_kernel() and not _PDQ_GUARD and _TP is None
+    fused = (_use_kernel() and not _PDQ_GUARD
              and len(segs.sizes) == 2 and segs.padded[0] == segs.padded[1]
              and segs.padded[0] % bn == 0)
     if not fused:
@@ -595,14 +625,13 @@ def pdq_mlp(x, grec, down_rec, *, out_dtype=None, block=(128, 128, 128),
     def blockwise(a):
         return jnp.repeat(a, reps, axis=-1, total_repeat_length=nb)
 
-    # the staging scratch holds a full (bm, Nt) f32 row block: shrink bm
-    # for wide MLPs so it stays well under VMEM
-    while bm > 8 and bm * Nt * 4 > 8 * 1024 * 1024:
-        bm //= 2
+    # the staging scratch holds a full (bm, Nt) f32 row block: the row
+    # block comes from the shape so the stage fits fast memory
+    bm = swiglu_block_rows(M, Nt, bn, bm)
     pads = dict(axis=0, mult=bm)
     lo_b = blockwise(lo_g).reshape(M, nb)
     hi_b = blockwise(hi_g).reshape(M, nb)
-    y, _hsw, hsw_q, sxo, s1o, s2o = w8a8_swiglu_matmul_p(
+    y, hsw_q, sxo, s1o, s2o = w8a8_swiglu_matmul_p(
         _pad_to(_pad_to(x_q.reshape(M, K), 0, bm), 1, bk),
         _pad_to(grec["q"], 0, bk),
         _pad_to(_norm_row(s_x, M, jnp.float32), **pads, value=1.0),
@@ -610,21 +639,19 @@ def pdq_mlp(x, grec, down_rec, *, out_dtype=None, block=(128, 128, 128),
         grec["scale"].reshape(1, Nt), grec["colsum"].reshape(1, Nt),
         _pad_to(lo_b, **pads), _pad_to(hi_b, **pads),
         block=(bm, bn, bk), interpret=_interpret(), out_dtype=jnp.float32)
-    _tel_clip(y[:M], jnp.repeat(lo_b, bn, axis=-1),
-              jnp.repeat(hi_b, bn, axis=-1))
+    tel = (y[:M], jnp.repeat(lo_b, bn, axis=-1), jnp.repeat(hi_b, bn, axis=-1))
+    if _TP is not None and Nt % _TP[1] == 0:
+        # every shard ran the whole gate/up matmul: each counts only its
+        # own column slice, as a column-split projection does
+        idx = jax.lax.axis_index(_TP[0])
+        tel = tuple(_tp_cols(a, Nt // _TP[1], idx, 1) for a in tel)
+    _tel_clip(*tel)
 
-    dff, N2 = down_rec["q"].shape
-    hq = hsw_q[:M, :dff].reshape(*lead, dff)
-    sxo = sxo[:M].reshape(*lead, 1)
-    lo2, hi2, so2, zo2 = pdq_interval(down_rec, s1o[:M].reshape(*lead, 1),
-                                      s2o[:M].reshape(*lead, 1))
-    lo_g2 = (-128.0 - zo2) * so2
-    hi_g2 = (127.0 - zo2) * so2
-    y2 = w8a8_matmul(hq, down_rec["q"], sxo, 0, down_rec["scale"],
-                     colsum=down_rec["colsum"], fp_range=(lo_g2, hi_g2),
-                     out_dtype=out_dtype, block=block)
-    _tel_clip(y2, lo_g2, hi_g2)
-    return y2
+    dff = down_rec["q"].shape[0]
+    return pdq_dense_from_prologue(
+        None, hsw_q[:M, :dff].reshape(*lead, dff), sxo[:M].reshape(*lead, 1),
+        s1o[:M].reshape(*lead, 1), s2o[:M].reshape(*lead, 1), down_rec,
+        out_dtype=out_dtype, block=block)
 
 
 def pdq_dense_unfused(x, wrec):
@@ -820,11 +847,18 @@ def cache_scatter_rows(dst, src, src_map, *, batch_axis: int = 0, _entry=None):
         take = jnp.take(src, jnp.clip(src_map, 0, src.shape[0] - 1), axis=0)
         keep = (src_map >= 0).reshape((B,) + (1,) * (dst.ndim - 1))
         return jnp.where(keep, take, dst)
-    d2 = _pad_to(dst.reshape(B, R), 1, 128)
-    s2 = _pad_to(src.reshape(src.shape[0], R), 1, 128)
+    # lane-dense (rows, C, 128) view; pad C to 32 rows only when no legal
+    # block divides it (never for power-of-two cache shapes)
+    C = -(-R // 128)
+    mult = 128 if scatter_rows_block(C, dst.dtype.itemsize) else 32 * 128
+
+    def rows3(a):
+        a = _pad_to(a.reshape(a.shape[0], R), 1, mult)
+        return a.reshape(a.shape[0], -1, 128)
+
     entry = cache_scatter_p if _entry is None else _entry
-    out = entry(src_map, d2, s2, interpret=_interpret())
-    return out[:, :R].reshape(dst.shape)
+    out = entry(src_map, rows3(dst), rows3(src), interpret=_interpret())
+    return out.reshape(B, -1)[:, :R].reshape(dst.shape)
 
 
 # ---------------------------------------------------------------------------

@@ -38,34 +38,50 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _block_col(ref, j):
+    """Column ``j`` of a (bm, E) epilogue block as (bm, 1).
+
+    Per-(row, N-block) operands arrive lane-dense - the whole (bm, N/bn)
+    row block, resident across the N and K grid axes - and the N-grid
+    coordinate picks its column here with a masked lane max, which returns
+    the selected value exactly.  E == 1 is the per-row layout."""
+    v = ref[...]
+    if v.shape[1] == 1:
+        return v
+    hit = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1) == j
+    low = (jnp.finfo(v.dtype).min if jnp.issubdtype(v.dtype, jnp.floating)
+           else jnp.iinfo(v.dtype).min)
+    return jnp.max(jnp.where(hit, v, low), axis=1, keepdims=True)
+
+
 def _kernel(x_ref, w_ref, sx_ref, zx_ref, sw_ref, colsum_ref, sout_ref, zout_ref,
             lo_ref, hi_ref, o_ref, acc_ref, *, n_k: int, requant: bool,
             fp_clamp: bool):
+    j = pl.program_id(1)
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        x_ref[...].astype(jnp.int32),
-        w_ref[...].astype(jnp.int32),
-        preferred_element_type=jnp.int32,
-    )
+    # int8 x int8 operands straight into the MXU, int32 accumulation
+    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                            preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
         acc = acc_ref[...] - zx_ref[...] * colsum_ref[...]          # (bm, bn)
         y = acc.astype(jnp.float32) * (sx_ref[...] * sw_ref[...])
         if requant:
-            q = jnp.round(y / sout_ref[...]) + zout_ref[...].astype(jnp.float32)
+            q = (jnp.round(y / _block_col(sout_ref, j))
+                 + _block_col(zout_ref, j).astype(jnp.float32))
             o_ref[...] = jnp.clip(q, -128, 127).astype(jnp.int8)
         else:
             if fp_clamp:
                 # PDQ fp-out epilogue: the surrogate-predicted interval is
                 # applied in-register, so chained fp consumers skip the
                 # int8 requant -> dequant double rounding entirely.
-                y = jnp.clip(y, lo_ref[...], hi_ref[...])
+                y = jnp.clip(y, _block_col(lo_ref, j), _block_col(hi_ref, j))
             o_ref[...] = y.astype(o_ref.dtype)
 
 
@@ -116,7 +132,6 @@ def w8a8_matmul_p(
         assert op.shape == (M, epi_cols), (
             f"{name} must be (M, {epi_cols}) with per_nblock={per_nblock}, "
             f"got {op.shape}")
-    epi_idx = (lambda i, j, k: (i, j)) if per_nblock else (lambda i, j, k: (i, 0))
     n_k = K // bk
     grid = (M // bm, N // bn, n_k)
 
@@ -132,10 +147,10 @@ def w8a8_matmul_p(
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),    # z_x
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),    # s_w
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),    # colsum
-            pl.BlockSpec((bm, 1), epi_idx),                   # s_out
-            pl.BlockSpec((bm, 1), epi_idx),                   # z_out
-            pl.BlockSpec((bm, 1), epi_idx),                   # lo
-            pl.BlockSpec((bm, 1), epi_idx),                   # hi
+            pl.BlockSpec((bm, epi_cols), lambda i, j, k: (i, 0)),   # s_out
+            pl.BlockSpec((bm, epi_cols), lambda i, j, k: (i, 0)),   # z_out
+            pl.BlockSpec((bm, epi_cols), lambda i, j, k: (i, 0)),   # lo
+            pl.BlockSpec((bm, epi_cols), lambda i, j, k: (i, 0)),   # hi
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int8 if requant else out_dtype),
@@ -151,7 +166,7 @@ def w8a8_matmul_p(
 
 def _swiglu_kernel(x_ref, w_ref, sx_ref, zx_ref, sw_ref, colsum_ref,
                    lo_ref, hi_ref,
-                   o_ref, hsw_ref, hswq_ref, osx_ref, os1_ref, os2_ref,
+                   o_ref, hswq_ref, osx_ref, os1_ref, os2_ref,
                    acc_ref, stage_ref, *, n_j: int, n_k: int, bn: int, P: int):
     j = pl.program_id(1)
     k = pl.program_id(2)
@@ -160,41 +175,77 @@ def _swiglu_kernel(x_ref, w_ref, sx_ref, zx_ref, sw_ref, colsum_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        x_ref[...].astype(jnp.int32),
-        w_ref[...].astype(jnp.int32),
-        preferred_element_type=jnp.int32,
-    )
+    # int8 x int8 operands straight into the MXU, int32 accumulation
+    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                            preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
         acc = acc_ref[...] - zx_ref[...] * colsum_ref[...]          # (bm, bn)
         y = acc.astype(jnp.float32) * (sx_ref[...] * sw_ref[...])
-        y = jnp.clip(y, lo_ref[...], hi_ref[...])
+        y = jnp.clip(y, _block_col(lo_ref, j), _block_col(hi_ref, j))
         o_ref[...] = y.astype(o_ref.dtype)
-        # stage the clamped fp row block: the grid is row-major (j then k
-        # fastest within one i), so by (j == n_j-1, k == n_k-1) the whole
-        # (bm, N) output row lives in scratch and the SwiGLU pairing +
-        # next-layer prologue can run without a second launch.
-        pl.store(stage_ref, (slice(None), pl.ds(j * bn, bn)), y)
+        # stage the clamped fp block at its N-block index: the grid is
+        # row-major (j then k fastest within one i), so by (j == n_j-1,
+        # k == n_k-1) the whole (bm, N) output row lives in scratch and the
+        # SwiGLU pairing + next-layer prologue can run without a second
+        # launch.
+        stage_ref[j] = y
 
     @pl.when((k == n_k - 1) & (j == n_j - 1))
     def _swiglu_prologue():
-        g = stage_ref[:, :P]                                        # gate
-        u = stage_ref[:, P:]                                        # up
-        hsw = jax.nn.silu(g) * u                                    # (bm, P)
-        hsw_ref[...] = hsw
         # PDQ prologue of the w_down projection (ref.pdq_prologue_ref
-        # semantics on the (bm, P) rows): lane-padding columns of both
-        # segments are exactly 0 (zero weights, interval widened to
-        # contain 0), so reducing over the padded extent equals reducing
-        # over the real d_ff columns.
-        amax = jnp.maximum(jnp.max(jnp.abs(hsw), axis=-1, keepdims=True), 1e-8)
-        sx = amax / 127.0
+        # semantics on the (bm, P) rows of hsw = silu(gate) * up), in two
+        # passes over bn-wide column chunks so no (bm, P) temporary is
+        # live: pass 1 reduces amax/s1/s2, pass 2 quantizes with the row
+        # scale.  Lane-padding columns of both segments are exactly 0
+        # (zero weights, interval widened to contain 0), so reducing over
+        # the padded extent equals reducing over the real d_ff columns.
+        n_p = P // bn
+
+        def hsw(c):
+            return jax.nn.silu(stage_ref[c]) * stage_ref[n_p + c]   # (bm, bn)
+
+        h = hsw(0)
+        amax = jnp.max(jnp.abs(h), axis=-1, keepdims=True)
+        s1 = jnp.sum(h, axis=-1, keepdims=True)
+        s2 = jnp.sum(h * h, axis=-1, keepdims=True)
+        for c in range(1, n_p):
+            h = hsw(c)
+            amax = jnp.maximum(amax, jnp.max(jnp.abs(h), axis=-1, keepdims=True))
+            s1 = s1 + jnp.sum(h, axis=-1, keepdims=True)
+            s2 = s2 + jnp.sum(h * h, axis=-1, keepdims=True)
+        sx = jnp.maximum(amax, 1e-8) / 127.0
         osx_ref[...] = sx
-        os1_ref[...] = jnp.sum(hsw, axis=-1, keepdims=True)
-        os2_ref[...] = jnp.sum(hsw * hsw, axis=-1, keepdims=True)
-        hswq_ref[...] = jnp.clip(jnp.round(hsw / sx), -127.0, 127.0).astype(jnp.int8)
+        os1_ref[...] = s1
+        os2_ref[...] = s2
+        for c in range(n_p):
+            hswq_ref[:, c * bn:(c + 1) * bn] = jnp.clip(
+                jnp.round(hsw(c) / sx), -127.0, 127.0).astype(jnp.int8)
+
+
+# fast memory the fused SwiGLU kernel may use: the compiler's default
+# scoped VMEM limit is 16 MiB on v5e, less headroom for its own buffers
+_SWIGLU_VMEM_BYTES = 12 * 1024 * 1024
+
+
+def swiglu_block_rows(M: int, N: int, bn: int, bm: int = 128) -> int:
+    """Row block for ``w8a8_swiglu_matmul_p``: at most ``bm``, no taller
+    than M rounded up to 32 rows, and small enough that the kernel's fast
+    memory - the (bm, N) f32 stage, the double-buffered (bm, N/2) int8
+    prologue output and (bm, bn) f32 output tiles - stays within
+    ``_SWIGLU_VMEM_BYTES``.  Halves from ``bm`` down to 32."""
+    bm = min(bm, max(32, -(-M // 32) * 32))
+
+    def need(b):
+        return b * N * 4 + 2 * b * (N // 2) + 2 * b * bn * 4 + b * bn * 4
+
+    while bm > 32 and need(bm) > _SWIGLU_VMEM_BYTES:
+        bm //= 2
+    assert need(bm) <= _SWIGLU_VMEM_BYTES, (
+        f"w8a8_swiglu_matmul_p: N={N} needs {need(bm)} bytes of VMEM even "
+        f"at bm={bm}; budget {_SWIGLU_VMEM_BYTES}")
+    return bm
 
 
 def w8a8_swiglu_matmul_p(
@@ -223,9 +274,10 @@ def w8a8_swiglu_matmul_p(
     Requires the two segments to occupy equal column extents P = N/2
     (gate columns [0, P), up columns [P, N) - the ``group_quantize_weights``
     layout for (w_gate, w_up)).  Returns
-    (y (M, N) ``out_dtype``, hsw (M, P) f32, hsw_q (M, P) int8,
-     s_x, s1, s2 each (M, 1) f32) with hsw = silu(y[:, :P]) * y[:, P:]
-    and (hsw_q, s_x, s1, s2) = pdq_prologue(hsw).
+    (y (M, N) ``out_dtype``, hsw_q (M, P) int8, s_x, s1, s2 each (M, 1)
+    f32) with (hsw_q, s_x, s1, s2) = pdq_prologue(hsw) of
+    hsw = silu(y[:, :P]) * y[:, P:].  Pick ``bm`` with
+    ``swiglu_block_rows`` so the stage fits fast memory.
     """
     M, K = x_q.shape
     _, N = w_q.shape
@@ -240,7 +292,6 @@ def w8a8_swiglu_matmul_p(
     assert lo.shape == (M, nb) and hi.shape == (M, nb), (lo.shape, hi.shape)
     n_k = K // bk
     grid = (M // bm, nb, n_k)
-    epi_idx = lambda i, j, k: (i, j)                                # noqa: E731
     kern = functools.partial(_swiglu_kernel, n_j=nb, n_k=n_k, bn=bn, P=P)
     return pl.pallas_call(
         kern,
@@ -252,12 +303,11 @@ def w8a8_swiglu_matmul_p(
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),    # z_x
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),    # s_w
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),    # colsum
-            pl.BlockSpec((bm, 1), epi_idx),                   # lo
-            pl.BlockSpec((bm, 1), epi_idx),                   # hi
+            pl.BlockSpec((bm, nb), lambda i, j, k: (i, 0)),   # lo
+            pl.BlockSpec((bm, nb), lambda i, j, k: (i, 0)),   # hi
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),   # y
-            pl.BlockSpec((bm, P), lambda i, j, k: (i, 0)),    # hsw
             pl.BlockSpec((bm, P), lambda i, j, k: (i, 0)),    # hsw_q
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),    # s_x out
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),    # s1
@@ -265,13 +315,12 @@ def w8a8_swiglu_matmul_p(
         ],
         out_shape=[
             jax.ShapeDtypeStruct((M, N), out_dtype),
-            jax.ShapeDtypeStruct((M, P), jnp.float32),
             jax.ShapeDtypeStruct((M, P), jnp.int8),
             jax.ShapeDtypeStruct((M, 1), jnp.float32),
             jax.ShapeDtypeStruct((M, 1), jnp.float32),
             jax.ShapeDtypeStruct((M, 1), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32),
-                        pltpu.VMEM((bm, N), jnp.float32)],
+                        pltpu.VMEM((nb, bm, bn), jnp.float32)],
         interpret=interpret,
     )(x_q, w_q, s_x, z_x, s_w, colsum, lo, hi)

@@ -12,13 +12,21 @@ import jax
 import numpy as np
 
 
+def _auto(n: int) -> tuple:
+    """Mesh axis types for jit's automatic sharding propagation, which the
+    model code is written for (``jax.make_mesh`` defaults to explicit
+    axes, under which a gather from a sharded table must name its output
+    sharding)."""
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi_pod stacks 2 pods (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = int(np.prod(shape))
     if len(jax.devices()) == n:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
     # dry-run host platform exposes 512 placeholder devices; the single-pod
     # mesh uses the first 256 of them.
     from jax.sharding import Mesh
@@ -36,7 +44,8 @@ def make_host_mesh(data: int | None = None, model: int | None = None):
         data = n // model
     elif model is None:
         model = n // data
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 def parse_mesh(spec: str) -> tuple[int, int]:
@@ -82,17 +91,41 @@ def strip_forced_device_count(flags: str) -> str:
                     if not f.startswith("--xla_force_host_platform_device_count"))
 
 
+def cpu_platform() -> bool:
+    """True when JAX is pinned to the CPU backend (``JAX_PLATFORMS=cpu``).
+
+    Read from the environment, not from jax: the callers decide before
+    the first device query, and on a TPU host that query claims the chip
+    for this process (a chip belongs to one process at a time)."""
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+
+
+def refuse_local_processes(what: str) -> None:
+    """Exit with a clear message unless JAX is pinned to the CPU: spawning
+    several JAX processes on one host is a CPU-only test topology.  On an
+    accelerator each process would claim every local chip; multi-process
+    serving there means one process per host, each started by hand with
+    --process-id/--coordinator."""
+    if not cpu_platform():
+        raise SystemExit(
+            f"{what} starts several JAX processes on this host, which only "
+            f"works on the CPU backend (JAX_PLATFORMS=cpu): on a TPU host "
+            f"one process holds the chips, so run one process per host "
+            f"with --process-id/--coordinator instead")
+
+
 def bootstrap_mesh_env(argv) -> None:
     """Force the right number of virtual host devices for a --mesh run on
-    a CPU host: D*M for a single process, D*M // --num-processes for a
-    ``jax.distributed`` child (identified by --process-id).
+    the CPU backend: D*M for a single process, D*M // --num-processes for a
+    ``jax.distributed`` child (identified by --process-id).  Off the CPU
+    backend the mesh uses the real devices and nothing is forced.
 
     Importing this module does not initialize the jax backend, so
     XLA_FLAGS set here still takes effect - call before the first device
     query (launch/serve.py and benchmarks/bench_serve.py call it at
     module import, before anything touches jax.devices())."""
     spec = mesh_arg(argv)
-    if spec is None:
+    if spec is None or not cpu_platform():
         return
     data, model = parse_mesh(spec)
     want = data * model
@@ -134,9 +167,10 @@ def pick_coordinator(addr: str | None, *, attempts: int = 5) -> str:
 def init_distributed(coordinator: str, num_processes: int,
                      process_id: int, *, attempts: int = 3,
                      backoff: float = 1.0) -> None:
-    """``jax.distributed`` bootstrap for one serve process: CPU collectives
-    go through gloo (the CPU client's only cross-process implementation),
-    then the coordination service connects this process to its peers.
+    """``jax.distributed`` bootstrap for one serve process: on the CPU
+    backend collectives go through gloo (the CPU client's only
+    cross-process implementation), then the coordination service connects
+    this process to its peers.
     Must run before the first device query.
 
     The initialize is retried with exponential backoff (bounded): the
@@ -144,7 +178,7 @@ def init_distributed(coordinator: str, num_processes: int,
     (TIME_WAIT) or the coordinator child can come up a beat after a
     worker - both transient, both previously fatal."""
     import jax as _jax
-    if os.environ.get("JAX_PLATFORMS", "cpu") == "cpu":
+    if cpu_platform():
         _jax.config.update("jax_cpu_collectives_implementation", "gloo")
     for attempt in range(attempts):
         try:

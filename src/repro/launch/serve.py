@@ -8,18 +8,24 @@ PDQ-int8 path, single-device, mesh-distributed, or multi-process.
 
 ``--mesh DxM`` serves over a ('data', 'model') device mesh
 (ShardedServeEngine: slots data-parallel across D replicas, projection
-columns tensor-parallel across M shards).  On a CPU host the driver forces
-enough virtual devices automatically - this line must run before jax
-imports, hence the early environ bootstrap below.
+columns tensor-parallel across M shards).  On the CPU backend
+(``JAX_PLATFORMS=cpu``) the driver forces enough virtual devices
+automatically - this line must run before jax imports, hence the early
+environ bootstrap below.
 
 ``--num-processes N`` additionally splits the mesh over N OS processes
-joined by ``jax.distributed`` (MultiHostServeEngine): this process becomes
-a LAUNCHER that spawns N children (each re-runs this driver with
---process-id i), streams their output, and exits non-zero the moment any
-child dies - so a hung or crashed worker is an actionable failure, not a
-silent stall.  Child 0 is the serving coordinator; it prints per-process
-admit/occupancy stats at the end.  A child can also be started by hand
-(e.g. one per host) with explicit --process-id/--coordinator.
+joined by ``jax.distributed`` (MultiHostServeEngine).  On the CPU backend
+this process becomes a LAUNCHER that spawns N children (each re-runs this
+driver with --process-id i), streams their output, and exits non-zero the
+moment any child dies - so a hung or crashed worker is an actionable
+failure, not a silent stall.  Child 0 is the serving coordinator; it
+prints per-process admit/occupancy stats at the end.  On a TPU host the
+launcher refuses (one process holds the chips): start one child per host
+by hand with explicit --process-id/--coordinator.
+
+A canned-trace run exits non-zero when any request failed in a launch
+(kind ``exec`` or ``nonfinite``), even though request isolation kept the
+engine serving.
 """
 from __future__ import annotations
 
@@ -194,8 +200,10 @@ def spawn_processes(args, argv) -> int:
     (process 0) only: it drains, snapshots (with --snapshot) and releases
     the workers through the command protocol, so the whole fleet exits
     cleanly."""
+    from repro.launch.mesh import (pick_coordinator, refuse_local_processes,
+                                   strip_forced_device_count)
+    refuse_local_processes("--num-processes")
     env = dict(os.environ)
-    from repro.launch.mesh import pick_coordinator, strip_forced_device_count
     env["XLA_FLAGS"] = strip_forced_device_count(env.get("XLA_FLAGS", ""))
     coordinator = pick_coordinator(args.coordinator)
     procs = [subprocess.Popen(
@@ -325,6 +333,9 @@ def main(argv=None):
     if args.num_processes > 1 and args.process_id is None:
         raise SystemExit(spawn_processes(args, argv))
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if multiproc:
         # child: join the jax.distributed job BEFORE any device query
         if not args.coordinator:
@@ -442,6 +453,15 @@ def main(argv=None):
                   f"{hs['slots']}")
     for r in reqs[:3]:
         print(f"  req {r.uid}: {r.generated}")
+    # request isolation keeps the engine serving past a failed launch; a
+    # canned trace still has to report it: a compile error or non-finite
+    # logits is a broken run, not a served one
+    broken = eng.failures.count("exec") + eng.failures.count("nonfinite")
+    if broken:
+        first = next(e for e in eng.failures.events
+                     if e["kind"] in ("exec", "nonfinite"))
+        raise SystemExit(f"serve: {broken} requests failed in a launch; "
+                         f"first: {first['detail'][:2000]}")
 
 
 if __name__ == "__main__":

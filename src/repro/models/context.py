@@ -17,21 +17,9 @@ import contextlib
 import dataclasses
 from typing import Any
 
-import jax
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.ops import tp_ctx, tp_shard  # noqa: F401  (re-export)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` when available, else the jax<0.5 experimental one
-    (whose replication check is spelled ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -274,6 +274,9 @@ def lin_grouped(x: jax.Array, ws) -> tuple:
 GROUP_SIBLING_SETS = (("wq", "wk", "wv"), ("w_gate", "w_up"),
                       ("wq_a", "wkv_a"))
 CROSS_SIBLING_SETS = (("wk", "wv"),)
+# top-level param subtrees whose leaves carry a leading layer axis (the
+# scanned pattern blocks and the encoder stack; models/transformer.py)
+STACKED_KEYS = ("blocks", "enc_blocks")
 
 
 def quantize_param_tree(params, path_pred=None, alpha: float = 6.0,
@@ -327,6 +330,11 @@ def quantize_param_tree(params, path_pred=None, alpha: float = 6.0,
                     done.update(names)
             for k, v in node.items():
                 if k in done:
+                    continue
+                if not path and k in STACKED_KEYS:
+                    # layer-stacked (lax.scan) params: quantize each layer's
+                    # slice, restacking the records along the layer axis
+                    out[k] = jax.vmap(lambda t, k=k: rec(t, k, k))(v)
                     continue
                 out[k] = rec(v, join(path, k), k)
             return out

@@ -15,6 +15,8 @@ from jax.sharding import PartitionSpec as P
 
 from jax.ad_checkpoint import checkpoint_name
 
+from repro.kernels import ops
+
 from . import context
 from .attention import (AttnDims, gqa_apply, gqa_init, init_cache, mla_apply,
                         mla_init, mla_init_cache)
@@ -115,7 +117,7 @@ def _apply_ffn(p_ffn, cfg: ArchConfig, kind: str, h: jax.Array, mode: str,
             return yy, jax.lax.pmean(ax, ctx.token_axes)
         if mask is None:
             mask = jnp.ones((B * S,), bool)
-        y, aux = context.shard_map(
+        y, aux = jax.shard_map(
             f, mesh=ctx.mesh,
             in_specs=(context.moe_param_specs(routed), P(ctx.token_axes, None),
                       P(ctx.token_axes)),
@@ -130,7 +132,7 @@ def _apply_ffn(p_ffn, cfg: ArchConfig, kind: str, h: jax.Array, mode: str,
             return yy, jax.lax.pmean(ax, ctx.data_axes)
         if mask is None:
             mask = jnp.ones((B * S,), bool)
-        y, aux = context.shard_map(
+        y, aux = jax.shard_map(
             f, mesh=ctx.mesh,
             in_specs=(context.moe_param_specs(routed), P(ctx.data_axes, None),
                       P(ctx.data_axes)),
@@ -268,7 +270,7 @@ def _encoder_apply(params, cfg: ArchConfig, frames: jax.Array):
                                mode="train", cache=None, causal=False)
         return hh, ()
 
-    h, _ = jax.lax.scan(body, h, params["enc_blocks"])
+    h, _ = ops.pdq_telemetry_scan(body, h, params["enc_blocks"])
     return rms_norm(h, params["enc_norm"], cfg.norm_eps)
 
 
@@ -337,7 +339,7 @@ def lm_apply(params, cfg: ArchConfig, *, tokens=None, positions, mode: str,
         body = jax.checkpoint(block_body, prevent_cse=False, policy=policy)
 
     xs = (params["blocks"], caches["blocks"] if caches else None)
-    (h, aux_total), blocks_nc = jax.lax.scan(body, (h, aux_total), xs)
+    (h, aux_total), blocks_nc = ops.pdq_telemetry_scan(body, (h, aux_total), xs)
     new_caches["blocks"] = blocks_nc
 
     for i, kind in enumerate(cfg.tail):

@@ -53,7 +53,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import pool_shardings, serve_pool_specs
 from repro.kernels import ops
-from repro.models.context import shard_map
 
 from .engine import DEFAULT_BUCKETS, ServeEngine, decode_scan
 
@@ -120,8 +119,8 @@ class ShardedServeEngine(ServeEngine):
                 return out, jax.lax.psum(col.summary(), ("data", "model"))
 
         specs = (out_specs, P()) if tel else out_specs
-        return shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=specs, check_vma=False)
+        return jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=specs, check_vma=False)
 
     def _traced_sharded_jit(self, fn, counter: str, in_specs, out_specs,
                             donate: tuple[int, ...] = (), tel: bool = False,
@@ -158,10 +157,10 @@ class ShardedServeEngine(ServeEngine):
                 toks, ok, state, tel = fn(*args)
             return toks, ok, state, jax.lax.psum(tel, ("data", "model"))
 
-        mapped = shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                           out_specs=(dp, dp, serve_pool_specs(self.caches),
-                                      P()),
-                           check_vma=False)
+        mapped = jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                               out_specs=(dp, dp, serve_pool_specs(self.caches),
+                                          P()),
+                               check_vma=False)
         stats = self.stats
 
         def wrapped(*args):

@@ -96,7 +96,6 @@ def test_moe_ep_matches_local_dispatch():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.models.context import shard_map
         from repro.models.moe import MoEConfig, moe_init, moe_ffn_tokens
         cfg = MoEConfig(n_experts=8, top_k=2, d_ff_expert=16,
                         capacity_factor=8.0)
@@ -107,7 +106,7 @@ def test_moe_ep_matches_local_dispatch():
         mesh = jax.make_mesh((2, 4), ("data", "model"))
         def f(rp, xt):
             return moe_ffn_tokens(rp, xt, cfg, axis_name="model")
-        y_ep, aux_ep = shard_map(
+        y_ep, aux_ep = jax.shard_map(
             f, mesh=mesh,
             in_specs=({"router": P(None, None), "we_gate": P("model", None, None),
                        "we_up": P("model", None, None),

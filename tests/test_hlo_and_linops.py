@@ -82,14 +82,24 @@ def test_lin_quantized_is_one_prologue_one_matmul():
 
 
 def test_quantize_param_tree_selects_matrices_only():
+    # "blocks" leaves carry a leading layer axis (the scanned layer stack)
     params = {"attn": {"wq": jnp.ones((32, 32)), "norm": jnp.ones((32,))},
               "embed": {"embedding": jnp.ones((100, 32))},
-              "blocks": {"we_gate": jnp.ones((4, 32, 16))}}
+              "moe": {"we_gate": jnp.ones((4, 32, 16))},
+              "blocks": {"we_gate": jnp.ones((2, 4, 32, 16)),
+                         "wo": jnp.ones((2, 32, 16)),
+                         "norm": jnp.ones((2, 32))}}
     out = quantize_param_tree(params)
     assert is_quantized(out["attn"]["wq"])
     assert not is_quantized(out["attn"]["norm"])
     assert not is_quantized(out["embed"]["embedding"])   # embeddings stay fp
-    assert not is_quantized(out["blocks"]["we_gate"])    # 3-D stacks stay fp
+    assert not is_quantized(out["moe"]["we_gate"])       # 3-D stacks stay fp
+    assert not is_quantized(out["blocks"]["we_gate"])    # ... per layer too
+    assert not is_quantized(out["blocks"]["norm"])
+    # a stacked layer's matrices are quantized per layer, records restacked
+    wo = out["blocks"]["wo"]
+    assert is_quantized(wo) and wo["q"].shape == (2, 32, 16)
+    assert wo["scale"].shape == (2, 16) and wo["mu_w"].shape == (2,)
 
 
 def test_quantize_param_tree_groups_sibling_sets():

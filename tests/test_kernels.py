@@ -524,7 +524,7 @@ def test_w8a8_swiglu_matmul_kernel_vs_unfused():
     lo = -20.0 * jnp.ones((M, nb))
     hi = 20.0 * jnp.ones((M, nb))
 
-    y, hsw, hsw_q, sxo, s1o, s2o = w8a8_swiglu_matmul_p(
+    y, hsw_q, sxo, s1o, s2o = w8a8_swiglu_matmul_p(
         x_q, w_q, s_x, z_x, s_w, colsum, lo, hi, interpret=True)
     y_want = w8a8_matmul_p(x_q, w_q, s_x, z_x, s_w, colsum,
                            jnp.ones((M, nb)), jnp.zeros((M, nb), jnp.int32),
@@ -532,7 +532,6 @@ def test_w8a8_swiglu_matmul_kernel_vs_unfused():
                            per_nblock=True, interpret=True)
     np.testing.assert_allclose(y, y_want, rtol=1e-5, atol=1e-5)
     hsw_want = jax.nn.silu(y_want[:, :P]) * y_want[:, P:]
-    np.testing.assert_allclose(hsw, hsw_want, rtol=1e-5, atol=1e-5)
     wq_, wsx, ws1, ws2 = ref.pdq_prologue_ref(hsw_want)
     np.testing.assert_allclose(sxo, wsx, rtol=1e-5)
     np.testing.assert_allclose(s1o, ws1, rtol=1e-4, atol=1e-4)

@@ -172,6 +172,43 @@ def test_raising_launch_fails_only_its_requests(small_model):
         assert _toks(got)[r.uid] == _toks(ref)[r.uid]
 
 
+@pytest.mark.parametrize("raise_kind", [None, "prefill"],
+                         ids=["clean", "failed_launch"])
+def test_canned_trace_exit_code_reports_failed_launches(small_model,
+                                                        monkeypatch,
+                                                        raise_kind):
+    """Request isolation keeps ``launch/serve.py`` serving past a failed
+    launch, but its canned-trace run then exits non-zero naming the
+    failure (a Mosaic compile error is such a launch failure); a clean run
+    returns normally."""
+    import repro.launch.serve as launcher
+    import repro.serve as serve_pkg
+    from repro.launch import compile_cache
+
+    cfg, m, params = small_model
+    build = serve_pkg.build_engine
+
+    def build_with_fault(sc, **kw):
+        eng = build(sc, cfg=cfg, params=params)
+        if raise_kind:
+            eng.fault = FaultPlan(raise_kind=raise_kind).injector()
+            eng.fault.bind(eng)
+        return eng
+
+    monkeypatch.setattr(serve_pkg, "build_engine", build_with_fault)
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(launcher.signal, "signal", lambda *a: None)
+    argv = ["--reduced", "--requests", "3", "--slots", "2", "--max-new", "2",
+            "--prompt-len", "6", "--buckets", "8", "--max-len", "32"]
+    if raise_kind is None:
+        launcher.main(argv)
+        return
+    with pytest.raises(SystemExit) as exc:
+        launcher.main(argv)
+    assert "requests failed in a launch" in str(exc.value.code)
+    assert "injected prefill launch fault" in str(exc.value.code)
+
+
 # ---------------------------------------------------------------------------
 # Guarded PDQ -> fp-dequant fallback
 # ---------------------------------------------------------------------------

@@ -20,6 +20,8 @@ import sys
 import tempfile
 import textwrap
 
+import pytest
+
 from repro.distributed.sharding import process_replicas
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -144,17 +146,9 @@ _MULTI = _CASES + """
 
 
 def _env(devices: int) -> dict:
-    env = dict(os.environ, PYTHONPATH="src",
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
-    # point the subprocesses at their own compilation-cache subdir: the
-    # SPMD executables of the 2-process topology are traced ONLY here, so
-    # this is where the CI job's persistent cache gets populated - while
-    # staying out of the surrounding suite's cache namespace
-    base = env.get("JAX_COMPILATION_CACHE_DIR")
-    if base:
-        env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
-            base, f"multihost{devices}")
-    return env
+    # several jax processes on one host: a CPU-backend-only topology
+    return dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu",
+                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
 
 
 def _free_port() -> int:
@@ -314,6 +308,54 @@ def test_process_replicas_single_process_layout():
     n = jax.device_count()
     mesh = Mesh(np.asarray(jax.devices()).reshape(n, 1), ("data", "model"))
     assert process_replicas(mesh) == {jax.process_index(): list(range(n))}
+
+
+@pytest.mark.parametrize("platforms", ["cpu", "tpu", ""],
+                         ids=["cpu", "tpu", "unset"])
+def test_local_process_topology_is_cpu_only(monkeypatch, platforms):
+    """Several JAX processes on one host, and virtual devices for --mesh,
+    exist on the CPU backend only: off it the spawners refuse before any
+    child starts and XLA_FLAGS is left alone (on a TPU host one process
+    holds the chips)."""
+    import repro.launch.serve as launcher
+    from repro.launch import mesh as lmesh
+
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    monkeypatch.setenv("XLA_FLAGS", "")
+    lmesh.bootstrap_mesh_env(["--mesh", "2x2"])
+    forced = "--xla_force_host_platform_device_count=4" in os.environ["XLA_FLAGS"]
+    assert forced == (platforms == "cpu")
+    if platforms == "cpu":
+        lmesh.refuse_local_processes("--multiproc")
+        return
+    started = []
+    monkeypatch.setattr(launcher.subprocess, "Popen",
+                        lambda *a, **k: started.append(a))
+    args = launcher.build_args(["--num-processes", "2"])
+    with pytest.raises(SystemExit, match="JAX_PLATFORMS=cpu"):
+        launcher.spawn_processes(args, [])
+    assert started == []
+
+
+def test_compile_cache_dir_is_env_or_checkout(monkeypatch, tmp_path):
+    """The persistent compile cache lives in $JAX_COMPILATION_CACHE_DIR
+    when it is set (and nothing is set in code), else at the fixed
+    <checkout>/.jax_cache."""
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == prev
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = enable_compile_cache()
+        assert path == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 # ------------------------------------------------- PR-7: ingress front door
