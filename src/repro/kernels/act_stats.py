@@ -58,6 +58,7 @@ def act_stats_p(
             jax.ShapeDtypeStruct((M, 1), jnp.float32),
             jax.ShapeDtypeStruct((M, 1), jnp.float32),
         ],
+        name="act_stats",
         interpret=interpret,
     )(x)
     return out[0][:, 0], out[1][:, 0]

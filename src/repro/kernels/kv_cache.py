@@ -121,6 +121,7 @@ def decode_attend_i8kv_p(
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, Dh), jnp.float32),
         ],
+        name="decode_attend_i8kv",
         interpret=interpret,
     )(length, q, k_q, v_q, k_scale.reshape(Hkv, 1, S),
       v_scale.reshape(Hkv, 1, S))
@@ -212,6 +213,7 @@ def decode_attend_i8kv_fused_p(
             pltpu.VMEM((G, Dh), jnp.float32),
             pltpu.VMEM((Hkv, G, Dh), jnp.float32),
         ],
+        name="decode_attend_i8kv_fused",
         interpret=interpret,
     )(length, q, k_q, v_q, k_scale.reshape(Hkv, 1, S),
       v_scale.reshape(Hkv, 1, S))
@@ -290,6 +292,7 @@ def cache_scatter_p(
         _scatter_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, C, L), dst.dtype),
+        name="cache_scatter",
         interpret=interpret,
     )(src_map.astype(jnp.int32), dst, src)
 

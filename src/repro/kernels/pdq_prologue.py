@@ -88,5 +88,6 @@ def pdq_prologue_p(
             jax.ShapeDtypeStruct((M, 1), jnp.float32),
             jax.ShapeDtypeStruct((M, 1), jnp.float32),
         ],
+        name="pdq_prologue",
         interpret=interpret,
     )(x)

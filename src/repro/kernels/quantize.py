@@ -44,6 +44,7 @@ def quantize_p(x, scale, zero_point, *, block=(256, 256), interpret=False):
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j)), sspec, sspec],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int8),
+        name="quantize",
         interpret=interpret,
     )(x, scale, zero_point)
 
@@ -64,5 +65,6 @@ def dequantize_p(q, scale, zero_point, *, out_dtype=jnp.float32, block=(256, 256
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j)), sspec, sspec],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        name="dequantize",
         interpret=interpret,
     )(q, scale, zero_point)

@@ -155,6 +155,7 @@ def w8a8_matmul_p(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int8 if requant else out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        name="w8a8_matmul",
         interpret=interpret,
     )(x_q, w_q, s_x, z_x, s_w, colsum, s_out, z_out, lo, hi)
 
@@ -322,5 +323,6 @@ def w8a8_swiglu_matmul_p(
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32),
                         pltpu.VMEM((nb, bm, bn), jnp.float32)],
+        name="w8a8_swiglu_matmul",
         interpret=interpret,
     )(x_q, w_q, s_x, z_x, s_w, colsum, lo, hi)

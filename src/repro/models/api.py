@@ -314,6 +314,9 @@ def build_model(cfg: ArchConfig) -> ModelBundle:
                 return jnp.zeros(tuple(shape), m.dtype)
             return tmap(one)
 
+        # the named scopes label these ops in a profiler trace (the op
+        # name metadata of every HLO instruction they lower to)
+        @jax.named_scope("paged_gather")
         def gather(pool, pt, lengths):
             """Physical pages -> a (B, ...) logical tree the unmodified
             decode step runs on.  pt: (B, n_pp) int32 page tables;
@@ -347,6 +350,7 @@ def build_model(cfg: ArchConfig) -> ModelBundle:
                                                  batch_axis=ba)
             return tmap(one, pool, base1)
 
+        @jax.named_scope("paged_writeback")
         def writeback(pool, logical, pt, positions, n_steps=None,
                       max_steps: int = 1):
             """Scatter each live row's decode-written pages back into the

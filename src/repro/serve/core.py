@@ -330,16 +330,21 @@ class SchedulerCore:
             pages_used=0, preemptions=0, spills=0, spill_restores=0,
             prefix_hits=0, prefix_shared_pages=0, cow_copies=0)
 
-    def _refresh_page_stats(self) -> None:
+    def _refresh_page_stats(self, kind: str) -> None:
+        """Fold the pools' counters into ``stats`` after a ``kind`` round
+        (timed as that round's plan work)."""
         if not self.paged:
             return
-        self.stats["pages_used"] = sum(p.used_pages() for p in self.page_pools)
-        self.stats["cow_copies"] = sum(p.stats["cow_copies"]
-                                       for p in self.page_pools)
-        self.stats["prefix_hits"] = sum(s.stats["prefix_hits"]
-                                        for s in self.prefix_stores)
-        self.stats["prefix_shared_pages"] = sum(
-            s.stats["prefix_shared_pages"] for s in self.prefix_stores)
+        with self.tel.span("page_stats", tid=tmod.TID_PLAN, phase="plan",
+                           kind=kind):
+            self.stats["pages_used"] = sum(p.used_pages()
+                                           for p in self.page_pools)
+            self.stats["cow_copies"] = sum(p.stats["cow_copies"]
+                                           for p in self.page_pools)
+            self.stats["prefix_hits"] = sum(s.stats["prefix_hits"]
+                                            for s in self.prefix_stores)
+            self.stats["prefix_shared_pages"] = sum(
+                s.stats["prefix_shared_pages"] for s in self.prefix_stores)
 
     # ------------------------------------------------------- telemetry taps
     def stats_snapshot(self) -> dict[str, Any]:
@@ -371,6 +376,10 @@ class SchedulerCore:
         self.tel.observe_pdq(fb, hits, total)
 
     # ------------------------------------------------------------ exec hooks
+    # Each launch hook times its two halves as children of the launch
+    # span: ``dispatch:<kind>`` (the jitted calls up to their return: the
+    # enqueue, plus any wait for donated buffers) and ``fetch:<kind>``
+    # (the first blocking host copy until the results are on the host).
     def _exec_prefill(self, plan: PrefillPlan, extras):
         """Run ONE bucketed prefill + cache scatter; return ``(nxt, ok)``:
         the sampled next token per pool row and a per-row finite flag
@@ -384,6 +393,18 @@ class SchedulerCore:
 
     def _exec_decode(self, plan: DecodePlan):
         raise NotImplementedError
+
+    def _dispatch_span(self, kind: str):
+        return self.tel.span(f"dispatch:{kind}", tid=tmod.TID_LAUNCH,
+                             phase="dispatch", kind=kind)
+
+    def _fetch(self, kind: str, tel_sum, *arrays) -> tuple[np.ndarray, ...]:
+        """The launch's ``fetch:<kind>`` half: the pdq summary's host copy
+        (the first blocking one), then ``arrays``, as host numpy."""
+        with self.tel.span(f"fetch:{kind}", tid=tmod.TID_LAUNCH,
+                           phase="fetch", kind=kind):
+            self._observe_pdq(tel_sum)
+            return tuple(np.asarray(a) for a in arrays)
 
     def _submit_one(self, req: Request, extras) -> bool:
         raise NotImplementedError(
@@ -1005,7 +1026,8 @@ class SchedulerCore:
                     self.prefill_straggler.flagged
                 if self.tel.enabled:
                     self.tel.launch_histogram(kind).observe(dt)
-                with self.tel.span(f"apply:{kind}", tid=tmod.TID_APPLY):
+                with self.tel.span(f"apply:{kind}", tid=tmod.TID_APPLY,
+                                   phase="apply", kind=kind):
                     apply_fn(plan, res)
 
         def flush():
@@ -1021,7 +1043,8 @@ class SchedulerCore:
                     if not any(per):
                         continue
                 if key[0] == "chunk":
-                    with self.tel.span("plan:chunked", tid=tmod.TID_PLAN):
+                    with self.tel.span("plan:chunked", tid=tmod.TID_PLAN,
+                                       phase="plan", kind="chunked"):
                         plan = self._plan_chunked(groups[key], per=per)
                     plan.share_ok = share
                     launch("chunked", plan,
@@ -1029,7 +1052,8 @@ class SchedulerCore:
                            lambda p=plan: self._exec_chunked(p, extras),
                            self._apply_chunked)
                 else:
-                    with self.tel.span("plan:prefill", tid=tmod.TID_PLAN):
+                    with self.tel.span("plan:prefill", tid=tmod.TID_PLAN,
+                                       phase="plan", kind="prefill"):
                         plan = self._plan_prefill(per, key[1])
                     plan.share_ok = share
                     launch("prefill", plan,
@@ -1072,7 +1096,7 @@ class SchedulerCore:
         for r in reversed(holdback):
             self.pending.appendleft(r)
         flush()
-        self._refresh_page_stats()
+        self._refresh_page_stats("prefill")
         return admitted
 
     # ---------------------------------------------------------------- decode
@@ -1280,16 +1304,15 @@ class SchedulerCore:
         The launch is timed into the straggler EMA (plus any injected
         virtual delay) and guarded by request isolation: a raising decode
         launch fails the live requests and keeps the engine serving."""
-        if self.paged:
-            # every live slot must own the page its next write hits BEFORE
-            # the page tables are snapshotted into the plan
-            self._ensure_decode_pages()
-        with self.tel.span("plan:decode", tid=tmod.TID_PLAN):
+        with self.tel.span("plan:decode", tid=tmod.TID_PLAN, phase="plan",
+                           kind="decode"):
+            if self.paged:
+                # every live slot must own the page its next write hits
+                # BEFORE the page tables are snapshotted into the plan
+                self._ensure_decode_pages()
             plan = self._plan_decode()
         if plan is None:
             return 0
-        if self.tel.enabled:
-            self.tel.round_occupancy.observe(len(plan.live))
         t0 = time.perf_counter()
         try:
             self.fault.on_exec("decode", self._round)
@@ -1313,9 +1336,10 @@ class SchedulerCore:
             self.stats["straggler_flags"] = self.straggler.flagged
             if self.tel.enabled:
                 self.tel.launch_histogram("decode").observe(dt)
-            with self.tel.span("apply:decode", tid=tmod.TID_APPLY):
+            with self.tel.span("apply:decode", tid=tmod.TID_APPLY,
+                               phase="apply", kind="decode"):
                 self._apply_decode(plan, res)
-        self._refresh_page_stats()
+        self._refresh_page_stats("decode")
         return len([r for r in self.active if r is not None])
 
     def run(self, requests: list[Request], extras=None) -> list[Request]:

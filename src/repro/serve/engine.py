@@ -333,18 +333,17 @@ class ServeEngine(SchedulerCore):
         temperature share the executable)."""
         self._sampler = jax.jit(self._sample_fn())
 
-    def _sample_rows(self, kind: str, plan, logits) -> tuple[np.ndarray,
-                                                             np.ndarray]:
-        """Sample every batch row of a launch; returns numpy
-        (tokens (slots,), ok (slots,)).  Applies the fault injector's
-        logits poisoning first (no-op outside fault tests)."""
+    def _sample_rows(self, kind: str, plan, logits):
+        """Sample every batch row of a launch; returns device arrays
+        (tokens (slots,), ok (slots,)) without waiting for them.  Applies
+        the fault injector's logits poisoning first (no-op outside fault
+        tests)."""
         rows = self.fault.poison_rows(kind, plan)
         if rows:
             logits = jnp.asarray(logits).at[np.asarray(rows)].set(jnp.nan)
-        toks, ok = self._sampler(self.rng, logits,
-                                 jnp.asarray(plan.row_uids, jnp.int32),
-                                 jnp.asarray(plan.row_steps, jnp.int32))
-        return np.asarray(toks), np.asarray(ok)
+        return self._sampler(self.rng, logits,
+                             jnp.asarray(plan.row_uids, jnp.int32),
+                             jnp.asarray(plan.row_steps, jnp.int32))
 
     def _extras_batch(self, batch: dict, extras) -> dict:
         if extras:
@@ -361,13 +360,14 @@ class ServeEngine(SchedulerCore):
     def _exec_prefill(self, plan: PrefillPlan, extras):
         batch = self._extras_batch({"tokens": jnp.asarray(plan.tokens)},
                                    extras)
-        (logits, sub), tel = self._prefill_many(self.params, batch,
-                                                self._prefill_pool,
-                                                jnp.asarray(plan.seq_lens))
-        self._land_sub(plan, sub)
-        out = self._sample_rows("prefill", plan, logits)
-        self._observe_pdq(tel)     # already computed: rides the token gather
-        return out
+        with self._dispatch_span("prefill"):
+            (logits, sub), tel = self._prefill_many(
+                self.params, batch, self._prefill_pool,
+                jnp.asarray(plan.seq_lens))
+            self._land_sub(plan, sub)
+            toks, ok = self._sample_rows("prefill", plan, logits)
+        # the pdq summary rides the token gather: one fetch for all three
+        return self._fetch("prefill", tel, toks, ok)
 
     def _land_sub(self, plan, sub) -> None:
         """Land a finished prefill batch in the pool: page-wise through the
@@ -386,39 +386,39 @@ class ServeEngine(SchedulerCore):
             raise NotImplementedError(
                 "chunked prefill is text-only (no vision/encdec extras)")
         _, tokens, seq_lens = plan.first
-        (logits, sub), tel = self._prefill_many(
-            self.params, {"tokens": jnp.asarray(tokens)},
-            self._prefill_pool, jnp.asarray(seq_lens))
-        for _, tokens, seq_lens, start_lens in plan.chunks:
-            (logits, sub), t2 = self._prefill_chunk(
-                self.params, {"tokens": jnp.asarray(tokens)}, sub,
-                jnp.asarray(seq_lens), jnp.asarray(start_lens))
-            tel = tel + t2        # lazy device add: one fetch per launch set
-        self._land_sub(plan, sub)
-        out = self._sample_rows("chunked", plan, logits)
-        self._observe_pdq(tel)
-        return out
+        with self._dispatch_span("chunked"):
+            (logits, sub), tel = self._prefill_many(
+                self.params, {"tokens": jnp.asarray(tokens)},
+                self._prefill_pool, jnp.asarray(seq_lens))
+            for _, tokens, seq_lens, start_lens in plan.chunks:
+                (logits, sub), t2 = self._prefill_chunk(
+                    self.params, {"tokens": jnp.asarray(tokens)}, sub,
+                    jnp.asarray(seq_lens), jnp.asarray(start_lens))
+                tel = tel + t2    # lazy device add: one fetch per launch set
+            self._land_sub(plan, sub)
+            toks, ok = self._sample_rows("chunked", plan, logits)
+        return self._fetch("chunked", tel, toks, ok)
 
     def _exec_decode(self, plan: DecodePlan):
-        row_args = (jnp.asarray(plan.row_uids, jnp.int32),
-                    jnp.asarray(plan.row_steps, jnp.int32),
-                    jnp.asarray(plan.n_steps, jnp.int32))
-        if self.paged:
-            toks, ok, self.caches, tel = self._decode_paged(
-                self.rng, self.params, self.caches,
-                jnp.asarray(plan.page_tables), jnp.asarray(plan.tokens),
-                jnp.asarray(plan.positions), *row_args)
-        else:
-            toks, ok, self.caches, tel = self._decode(
-                self.rng, self.params, self.caches,
-                jnp.asarray(plan.tokens), jnp.asarray(plan.positions),
-                *row_args)
-        self._observe_pdq(tel)
+        with self._dispatch_span("decode"):
+            row_args = (jnp.asarray(plan.row_uids, jnp.int32),
+                        jnp.asarray(plan.row_steps, jnp.int32),
+                        jnp.asarray(plan.n_steps, jnp.int32))
+            if self.paged:
+                toks, ok, self.caches, tel = self._decode_paged(
+                    self.rng, self.params, self.caches,
+                    jnp.asarray(plan.page_tables), jnp.asarray(plan.tokens),
+                    jnp.asarray(plan.positions), *row_args)
+            else:
+                toks, ok, self.caches, tel = self._decode(
+                    self.rng, self.params, self.caches,
+                    jnp.asarray(plan.tokens), jnp.asarray(plan.positions),
+                    *row_args)
+        toks, ok = self._fetch("decode", tel, toks, ok)
         # fault poisoning moved host-side: sampling now runs in-program, so
         # the injector marks rows bad AFTER the launch instead of NaN-ing
         # logits before it (same observable effect: the row evicts)
-        ok = self._poison_ok("decode", plan, np.asarray(ok))
-        return np.asarray(toks), ok
+        return toks, self._poison_ok("decode", plan, ok)
 
     # ------------------------------------------------------ paged-pool hooks
     def _copy_map(self, replica: int, pairs) -> np.ndarray:

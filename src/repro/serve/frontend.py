@@ -14,9 +14,10 @@ Routes:
     (snapshot under the engine's stats lock - the loop thread keeps
     mutating while we serialize).
   * ``GET /metrics`` - Prometheus text exposition (version 0.0.4) of the
-    engine's metric registry: TTFT / per-token / queue-wait / launch
-    histograms, pdq_fallbacks / pdq_clip_rate quantization health, shed
-    and occupancy series (serve/telemetry.py).
+    engine's metric registry: TTFT / per-token / queue-wait / launch /
+    front-door delivery histograms, serve-loop phase seconds, pdq
+    fallback and clip-saturation counters, shed counts
+    (serve/telemetry.py).
   * ``GET /v1/events`` - the structured failure/eviction/preemption/
     straggler event ring as JSONL, one event object per line.
 
@@ -39,6 +40,7 @@ from __future__ import annotations
 
 import asyncio
 import json
+import time
 
 from .core import EngineDraining
 from .service import OverloadedError, ServeService
@@ -243,10 +245,12 @@ class HttpFrontend:
                      b"Cache-Control: no-cache\r\n"
                      b"Connection: close\r\n\r\n")
         await writer.drain()
+        tel = self.service.engine.tel
         idx = 0
         while True:
             ev.clear()
             toks, fin = stream.drain()
+            since = stream.drained_since
             for t in toks:
                 writer.write(b"data: " + json.dumps(
                     {"token": t, "index": idx}).encode() + b"\n\n")
@@ -257,6 +261,9 @@ class HttpFrontend:
                 # TokenStream buffer is the primary guard; this is the
                 # transport-level backstop)
                 await asyncio.wait_for(writer.drain(), self.write_timeout)
+                if tel.enabled:
+                    # scheduler push of the write's oldest token -> out
+                    tel.deliver.observe(time.perf_counter() - since)
             if fin is not None:
                 reason, error = fin
                 writer.write(b"data: " + json.dumps(

@@ -552,11 +552,12 @@ class MultiHostServeEngine(ShardedServeEngine):
                     extras=None, land_rows=None, land_js=None):
         u, s = self._us(uids, steps)
         with self._deadline("prefill launch"):
-            (nxt, ok, sub), tel = self._prefill_many(
-                self._rng_glob, self.params, self._batch(tokens, extras),
-                self._prefill_pool, self._glob(seq_lens, P("data")), u, s)
-            self._land_global(sub, src_map, land_rows, land_js)
-            jax.block_until_ready((nxt, ok, tel, self.caches))
+            with self._dispatch_span("prefill"):
+                (nxt, ok, sub), tel = self._prefill_many(
+                    self._rng_glob, self.params, self._batch(tokens, extras),
+                    self._prefill_pool, self._glob(seq_lens, P("data")), u, s)
+                self._land_global(sub, src_map, land_rows, land_js)
+            self._block("prefill", (nxt, ok, tel, self.caches))
         nxt, ok = np.asarray(nxt), np.asarray(ok)
         self._observe_pdq(tel)      # psum'd fleet totals, replicated
         self._track_remote(nxt, ok, uids, steps)
@@ -568,11 +569,12 @@ class MultiHostServeEngine(ShardedServeEngine):
                              np.asarray(steps, np.int32))
         u, s = self._chunk_us
         with self._deadline("chunked-prefill launch"):
-            (nxt, ok, self._chunk_sub), tel = self._prefill_many(
-                self._rng_glob, self.params,
-                {"tokens": self._glob(tokens, P("data"))},
-                self._prefill_pool, self._glob(seq_lens, P("data")), u, s)
-            jax.block_until_ready((nxt, ok, tel, self._chunk_sub))
+            with self._dispatch_span("chunked"):
+                (nxt, ok, self._chunk_sub), tel = self._prefill_many(
+                    self._rng_glob, self.params,
+                    {"tokens": self._glob(tokens, P("data"))},
+                    self._prefill_pool, self._glob(seq_lens, P("data")), u, s)
+            self._block("chunked", (nxt, ok, tel, self._chunk_sub))
         self._observe_pdq(tel)
         self._chunk_nxt = (np.asarray(nxt), np.asarray(ok))
         return self._chunk_nxt
@@ -580,12 +582,13 @@ class MultiHostServeEngine(ShardedServeEngine):
     def _do_chunk_next(self, tokens, seq_lens, start_lens):
         u, s = self._chunk_us
         with self._deadline("chunked-prefill launch"):
-            (nxt, ok, self._chunk_sub), tel = self._prefill_chunk(
-                self._rng_glob, self.params,
-                {"tokens": self._glob(tokens, P("data"))},
-                self._chunk_sub, self._glob(seq_lens, P("data")),
-                self._glob(start_lens, P("data")), u, s)
-            jax.block_until_ready((nxt, ok, tel, self._chunk_sub))
+            with self._dispatch_span("chunked"):
+                (nxt, ok, self._chunk_sub), tel = self._prefill_chunk(
+                    self._rng_glob, self.params,
+                    {"tokens": self._glob(tokens, P("data"))},
+                    self._chunk_sub, self._glob(seq_lens, P("data")),
+                    self._glob(start_lens, P("data")), u, s)
+            self._block("chunked", (nxt, ok, tel, self._chunk_sub))
         self._observe_pdq(tel)
         self._chunk_nxt = (np.asarray(nxt), np.asarray(ok))
         return self._chunk_nxt
@@ -605,23 +608,31 @@ class MultiHostServeEngine(ShardedServeEngine):
         self._chunk_track = None
         self._chunk_nxt = None
 
+    def _block(self, kind: str, outs) -> None:
+        """The ``fetch:<kind>`` half of a multi-process launch: wait for
+        every local shard (see ``_broadcast`` on why all of them)."""
+        with self.tel.span(f"fetch:{kind}", tid=tmod.TID_LAUNCH,
+                           phase="fetch", kind=kind):
+            jax.block_until_ready(outs)
+
     def _do_decode(self, tokens, positions, uids, steps, n_steps,
                    page_tables=None):
         u, s = self._us(uids, steps)
         ns = self._glob(np.asarray(n_steps, np.int32), P("data"))
         with self._deadline("decode launch"):
-            if self.paged:
-                nxt, ok, self.caches, tel = self._decode_paged(
-                    self._rng_glob, self.params, self.caches,
-                    self._glob(page_tables, P("data", None)),
-                    self._glob(tokens, P("data")),
-                    self._glob(positions, P("data")), u, s, ns)
-            else:
-                nxt, ok, self.caches, tel = self._decode(
-                    self._rng_glob, self.params, self.caches,
-                    self._glob(tokens, P("data")),
-                    self._glob(positions, P("data")), u, s, ns)
-            jax.block_until_ready((nxt, ok, tel, self.caches))
+            with self._dispatch_span("decode"):
+                if self.paged:
+                    nxt, ok, self.caches, tel = self._decode_paged(
+                        self._rng_glob, self.params, self.caches,
+                        self._glob(page_tables, P("data", None)),
+                        self._glob(tokens, P("data")),
+                        self._glob(positions, P("data")), u, s, ns)
+                else:
+                    nxt, ok, self.caches, tel = self._decode(
+                        self._rng_glob, self.params, self.caches,
+                        self._glob(tokens, P("data")),
+                        self._glob(positions, P("data")), u, s, ns)
+            self._block("decode", (nxt, ok, tel, self.caches))
         nxt, ok = np.asarray(nxt), np.asarray(ok)
         self._observe_pdq(tel)
         self._track_remote(nxt, ok, uids, steps)

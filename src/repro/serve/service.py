@@ -51,6 +51,7 @@ import numpy as np
 
 from repro.distributed.fault import save_snapshot
 
+from . import telemetry as tmod
 from .core import EngineDraining, Request
 
 __all__ = ["OverloadedError", "ServeService", "TokenStream"]
@@ -87,6 +88,10 @@ class TokenStream:
         self.overflowed = False
         self.submitted_at = time.perf_counter()
         self.first_token_at: float | None = None
+        # when the buffer last went from empty to non-empty; ``drain``
+        # hands it over as ``drained_since`` (the oldest drained token)
+        self._since: float | None = None
+        self.drained_since: float | None = None
 
     # ------------------------------------------------------------- producer
     def _notify(self, wakers) -> None:
@@ -108,8 +113,10 @@ class TokenStream:
             if len(self._buf) >= self.max_buffer:
                 self.overflowed = True
                 return False
+            if not self._buf:
+                self._since = time.perf_counter()
             if self.first_token_at is None:
-                self.first_token_at = time.perf_counter()
+                self.first_token_at = self._since
             self._buf.append(int(tok))
             wakers = list(self._wakers)
         self._notify(wakers)
@@ -131,9 +138,12 @@ class TokenStream:
 
     def drain(self) -> tuple[list[int], tuple[str, str | None] | None]:
         """Take every undelivered token; the finish tuple (reason, error)
-        rides along once the request left the engine, else None."""
+        rides along once the request left the engine, else None.  Sets
+        ``drained_since`` to when the oldest of the taken tokens was
+        pushed (``time.perf_counter``; None when none were taken)."""
         with self._lock:
             toks, self._buf = self._buf, []
+            self.drained_since, self._since = self._since, None
             return toks, self._finish
 
     @property
@@ -347,30 +357,18 @@ class ServeService:
 
     def _loop(self) -> None:
         eng = self.engine
+        tel = eng.tel
+        # every round is timed by phase (serve_loop_seconds_total): the
+        # engine's plan/dispatch/fetch/apply spans, the ingress sweep and
+        # the idle wait here, and the rest of the round as ``other``
+        tel.loop_edge(first=True)
         while True:
+            tel.loop_edge()
             if eng.drained:
                 break
-            eng.fault.on_round(eng._round)
-            for prompt, max_new in eng.fault.ingress_burst(eng._round):
-                try:                    # injected bursts go through the
-                    self.submit(prompt, max_new=max_new, stream=False)
-                except OverloadedError:
-                    pass                # watermark like everything else
-            if eng.drained:
-                break
-            # multi-host residual: worker-side submits ride the ack exchange
-            # as queue counts; pull any announced requests into the queue
-            # (no-op [] on single-process engines)
-            for req in eng.poll_ingress():
-                eng.pending.append(req)
-            with self._mutex:
-                while self._ingress:
-                    eng.pending.append(self._ingress.popleft())
-                cancels = list(self._cancels)
-                self._cancels.clear()
-            for uid, kind, reason in cancels:
-                eng.cancel(uid, kind=kind, reason=reason)
-            eng._expire_deadlines()
+            with tel.span("ingress", tid=tmod.TID_LOOP, phase="ingress"):
+                if self._ingress_sweep():
+                    break               # drained by an injected fault
             admitted = 0
             if eng.pending and eng._free_total():
                 admitted = eng._admit(self.extras)
@@ -385,8 +383,37 @@ class ServeService:
             with self._mutex:
                 busy = bool(self._ingress or self._cancels)
             if not busy and not eng.drained:
-                self._wake.wait(self.idle_wait)
+                with tel.span("idle", tid=tmod.TID_LOOP, phase="idle"):
+                    self._wake.wait(self.idle_wait)
+        tel.loop_edge()
         self._drain_epilogue()
+
+    def _ingress_sweep(self) -> bool:
+        """Round-boundary ingress: injected faults, queued submits and
+        cancels, deadline expiry.  True when the engine drained meanwhile."""
+        eng = self.engine
+        eng.fault.on_round(eng._round)
+        for prompt, max_new in eng.fault.ingress_burst(eng._round):
+            try:                    # injected bursts go through the
+                self.submit(prompt, max_new=max_new, stream=False)
+            except OverloadedError:
+                pass                # watermark like everything else
+        if eng.drained:
+            return True
+        # multi-host residual: worker-side submits ride the ack exchange
+        # as queue counts; pull any announced requests into the queue
+        # (no-op [] on single-process engines)
+        for req in eng.poll_ingress():
+            eng.pending.append(req)
+        with self._mutex:
+            while self._ingress:
+                eng.pending.append(self._ingress.popleft())
+            cancels = list(self._cancels)
+            self._cancels.clear()
+        for uid, kind, reason in cancels:
+            eng.cancel(uid, kind=kind, reason=reason)
+        eng._expire_deadlines()
+        return False
 
     def _drain_epilogue(self) -> None:
         eng = self.engine

@@ -48,7 +48,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import pool_shardings, serve_pool_specs
@@ -257,15 +256,15 @@ class ShardedServeEngine(ServeEngine):
     def _exec_prefill(self, plan, extras):
         batch = self._extras_batch({"tokens": jnp.asarray(plan.tokens)},
                                    extras)
-        (toks, ok, sub), tel = self._prefill_many(
-            self.rng, self.params, batch, self._prefill_pool,
-            jnp.asarray(plan.seq_lens),
-            jnp.asarray(plan.row_uids, jnp.int32),
-            jnp.asarray(plan.row_steps, jnp.int32))
-        self._land_sub(plan, sub)
-        self._observe_pdq(tel)
-        ok = self._poison_ok("prefill", plan, np.asarray(ok))
-        return np.asarray(toks), ok
+        with self._dispatch_span("prefill"):
+            (toks, ok, sub), tel = self._prefill_many(
+                self.rng, self.params, batch, self._prefill_pool,
+                jnp.asarray(plan.seq_lens),
+                jnp.asarray(plan.row_uids, jnp.int32),
+                jnp.asarray(plan.row_steps, jnp.int32))
+            self._land_sub(plan, sub)
+        toks, ok = self._fetch("prefill", tel, toks, ok)
+        return toks, self._poison_ok("prefill", plan, ok)
 
     def _exec_chunked(self, plan, extras):
         if extras:
@@ -274,17 +273,19 @@ class ShardedServeEngine(ServeEngine):
         uids = jnp.asarray(plan.row_uids, jnp.int32)
         steps = jnp.asarray(plan.row_steps, jnp.int32)
         _, tokens, seq_lens = plan.first
-        (toks, ok, sub), tel = self._prefill_many(
-            self.rng, self.params, {"tokens": jnp.asarray(tokens)},
-            self._prefill_pool, jnp.asarray(seq_lens), uids, steps)
-        for _, tokens, seq_lens, start_lens in plan.chunks:
-            # intermediate chunks sample throwaway tokens (same per-row
-            # keys, discarded logits) - only the final chunk's row matters
-            (toks, ok, sub), t2 = self._prefill_chunk(
-                self.rng, self.params, {"tokens": jnp.asarray(tokens)}, sub,
-                jnp.asarray(seq_lens), jnp.asarray(start_lens), uids, steps)
-            tel = tel + t2        # lazy device add: one fetch per launch set
-        self._land_sub(plan, sub)
-        self._observe_pdq(tel)
-        ok = self._poison_ok("chunked", plan, np.asarray(ok))
-        return np.asarray(toks), ok
+        with self._dispatch_span("chunked"):
+            (toks, ok, sub), tel = self._prefill_many(
+                self.rng, self.params, {"tokens": jnp.asarray(tokens)},
+                self._prefill_pool, jnp.asarray(seq_lens), uids, steps)
+            for _, tokens, seq_lens, start_lens in plan.chunks:
+                # intermediate chunks sample throwaway tokens (same per-row
+                # keys, discarded logits) - only the final chunk's row
+                # matters
+                (toks, ok, sub), t2 = self._prefill_chunk(
+                    self.rng, self.params, {"tokens": jnp.asarray(tokens)},
+                    sub, jnp.asarray(seq_lens), jnp.asarray(start_lens),
+                    uids, steps)
+                tel = tel + t2    # lazy device add: one fetch per launch set
+            self._land_sub(plan, sub)
+        toks, ok = self._fetch("chunked", tel, toks, ok)
+        return toks, self._poison_ok("chunked", plan, ok)

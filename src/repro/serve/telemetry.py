@@ -5,31 +5,38 @@ Two halves, deliberately decoupled from the scheduler so every engine
 way:
 
   * ``Tracer`` - a bounded ring of completed spans.  The engines wrap
-    request lifecycle edges (submit -> queued -> admit -> prefill/chunk ->
-    decode -> finish/evict) and per-round phases (plan build, device
-    launch, sample/apply, cache land, page COW copy, snapshot) in
-    ``tracer.span(...)``; the multi-host coordinator additionally
-    reconstructs worker-side launch spans from the timing slots riding
-    the command-header exchange (``Tracer.add``).  ``export()`` emits
-    Chrome trace-event JSON ({"traceEvents": [...]}; "X" complete events
-    plus "M" process/thread-name metadata) loadable in Perfetto or
+    per-round phases (plan build, device launch and its dispatch/fetch
+    halves, sample/apply, page COW copy, snapshot, the service loop's
+    ingress and idle wait) in ``Telemetry.span(...)`` and add the request
+    lifecycle (submit -> queued -> admit -> finish/evict) retroactively;
+    the multi-host coordinator additionally reconstructs worker-side
+    launch spans from the timing slots riding the command-header
+    exchange (``Tracer.add``).  ``export()`` emits Chrome trace-event
+    JSON ({"traceEvents": [...]}; "X" complete events plus "M"
+    process/thread-name metadata) loadable in Perfetto or
     chrome://tracing - one process row per jax process, one thread row
-    per engine phase.  When disabled, ``span()`` returns a shared no-op
-    context manager: the hot path pays one attribute check.
+    per engine phase.
 
   * ``MetricsRegistry`` - counters, gauges and fixed-bucket histograms
-    (TTFT, per-token latency, queue wait, launch wall time,
-    admission-round occupancy, pdq health) rendered in the Prometheus
-    text exposition format by ``render()`` (HELP/TYPE lines, cumulative
-    ``_bucket{le=...}`` + ``_sum``/``_count`` series, label escaping).
-    Histograms also answer ``percentile(q)`` from their buckets for the
-    drain/exit printout, and ``merge()`` other histograms losslessly
-    (fleet aggregation: per-worker timings fold into one distribution).
+    (TTFT, per-token latency, queue wait, launch wall time, serve-loop
+    phase seconds, front-door delivery, pdq health) rendered in the
+    Prometheus text exposition format by ``render()`` (HELP/TYPE lines,
+    cumulative ``_bucket{le=...}`` + ``_sum``/``_count`` series, label
+    escaping).  Histograms also answer ``percentile(q)`` from their
+    buckets for the drain/exit printout, and ``merge()`` other histograms
+    losslessly (fleet aggregation: per-worker timings fold into one
+    distribution).
 
 The facade ``Telemetry`` bundles one of each with the enable/trace
-switches the engines thread from ``ServeConfig``.  Everything here is
-thread-safe: the service loop thread records while the HTTP thread
-scrapes.
+switches the engines thread from ``ServeConfig``.  ``Telemetry.span`` is
+the one instrumentation call: it always enters a
+``jax.profiler.TraceAnnotation`` of the span's name (a check in C++ when
+no profiler session is active), records into the ring when tracing is
+on, and adds its seconds to ``serve_loop_seconds_total{phase, kind}``
+when it times a serve-loop phase - one pair of clock reads for all
+three.  Everything here is thread-safe: the service loop thread records
+while the HTTP thread scrapes.  JAX is imported lazily (for the
+annotation), so the module itself stays stdlib-only.
 """
 from __future__ import annotations
 
@@ -44,10 +51,10 @@ import time
 # to multi-second cold compiles all land in a finite bucket.
 LATENCY_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
                    0.5, 1.0, 2.5, 5.0, 10.0, 30.0)
-# admission-round occupancy (requests admitted / slots live per round)
-OCCUPANCY_BUCKETS = (0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)
-# fraction buckets (e.g. pdq clip-saturation rate per launch)
-RATIO_BUCKETS = (0.0001, 0.001, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0)
+# the serve loop's phases (serve_loop_seconds_total{phase}): ``other`` is
+# each round's wall time outside the named ones, so they sum to the wall
+LOOP_PHASES = ("ingress", "plan", "dispatch", "fetch", "apply", "idle",
+               "other")
 
 
 def _fmt(v: float) -> str:
@@ -250,7 +257,7 @@ class MetricsRegistry:
 
 
 class _NullSpan:
-    """Shared no-op context manager: the disabled-tracer fast path."""
+    """Shared no-op context manager: the annotation where JAX is absent."""
     __slots__ = ()
 
     def __enter__(self):
@@ -263,33 +270,14 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("_tracer", "name", "cat", "tid", "args", "_t0")
-
-    def __init__(self, tracer, name, cat, tid, args):
-        self._tracer = tracer
-        self.name = name
-        self.cat = cat
-        self.tid = tid
-        self.args = args
-
-    def __enter__(self):
-        self._t0 = self._tracer.now_us()
-        return self
-
-    def __exit__(self, *exc):
-        t1 = self._tracer.now_us()
-        self._tracer.add(self.name, cat=self.cat, ts=self._t0,
-                         dur=t1 - self._t0, tid=self.tid,
-                         args=self.args or None)
-        return False
-
-
 class Tracer:
     """Bounded span ring -> Chrome trace-event JSON (Perfetto-loadable).
 
     Timestamps are microseconds since tracer construction on
-    ``time.perf_counter`` (monotonic).  ``add`` accepts retroactive spans
+    ``time.perf_counter`` (monotonic).  The export's ``otherData`` carries
+    the epoch as a pair - the clock's reading and ``time.time_ns()`` taken
+    together - so a ring trace can be shifted onto the wall clock of a
+    ``jax.profiler`` trace.  ``add`` accepts retroactive spans
     with an explicit pid: the multi-host coordinator reconstructs worker
     launch spans from the header timing slots (ts = arrival - duration on
     the coordinator clock), so the merged trace carries one process row
@@ -302,6 +290,7 @@ class Tracer:
         self.pid = pid
         self._clock = clock
         self._epoch = clock()
+        self._epoch_unix_ns = time.time_ns()
         self._lock = threading.Lock()
         self._ring: collections.deque = collections.deque(maxlen=capacity)
         self.dropped = 0
@@ -321,11 +310,6 @@ class Tracer:
 
     def name_thread(self, pid: int, tid: int, name: str) -> None:
         self._thread_names[(int(pid), int(tid))] = str(name)
-
-    def span(self, name: str, *, cat: str = "phase", tid: int = 0, **args):
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, cat, tid, args)
 
     def add(self, name: str, *, cat: str = "phase", ts: float, dur: float,
             pid: int | None = None, tid: int = 0, args=None) -> None:
@@ -365,7 +349,9 @@ class Tracer:
                              (pid, tid), f"tid {tid}")}})
         return {"traceEvents": meta + spans,
                 "displayTimeUnit": "ms",
-                "otherData": {"dropped_spans": self.dropped}}
+                "otherData": {"dropped_spans": self.dropped,
+                              "epoch_clock_s": self._epoch,
+                              "epoch_unix_ns": self._epoch_unix_ns}}
 
     def write(self, path: str) -> None:
         with open(path, "w") as f:
@@ -380,9 +366,54 @@ TID_PLAN = 1         # plan build (host numpy)
 TID_LAUNCH = 2       # device launch (prefill/chunk/decode/copy)
 TID_APPLY = 3        # sample gather + result apply
 TID_SNAPSHOT = 4     # drain snapshot capture
+TID_LOOP = 5         # service loop: ingress sweep, idle wait
 _TID_NAMES = {TID_REQUEST: "requests", TID_PLAN: "plan",
               TID_LAUNCH: "launch", TID_APPLY: "apply",
-              TID_SNAPSHOT: "snapshot"}
+              TID_SNAPSHOT: "snapshot", TID_LOOP: "loop"}
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where JAX is absent."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+class _TelSpan:
+    """One ``Telemetry.span``: the profiler annotation around the phase,
+    and on its exit the ring's span and/or the loop-phase counter, both
+    from the same two clock reads."""
+    __slots__ = ("_tel", "_ann", "_name", "_cat", "_tid", "_args",
+                 "_counter", "_t0")
+
+    def __init__(self, tel, ann, name, cat, tid, args, counter):
+        self._tel = tel
+        self._ann = ann
+        self._name = name
+        self._cat = cat
+        self._tid = tid
+        self._args = args
+        self._counter = counter
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = self._tel.clock()
+        return self
+
+    def __exit__(self, *exc):
+        tel = self._tel
+        dt = tel.clock() - self._t0
+        if self._counter is not None:
+            self._counter.inc(dt)
+            tel._named_s += dt
+        tr = tel.tracer
+        if tr.enabled:
+            tr.add(self._name, cat=self._cat, ts=tr.to_us(self._t0),
+                   dur=dt * 1e6, tid=self._tid, args=self._args or None)
+        self._ann.__exit__(*exc)
+        return False
 
 
 class Telemetry:
@@ -390,13 +421,21 @@ class Telemetry:
     standard serving metric handles the scheduler hooks feed.  ``enabled``
     gates ALL recording (the <=2% overhead budget is measured against
     this switch); ``trace`` additionally turns on span capture (ring
-    memory + a clock read per phase, so it is a separate opt-in via
-    ``--trace-out``)."""
+    memory, so it is a separate opt-in via ``--trace-out``).  Profiler
+    annotations are independent of both: ``span`` always writes one, and
+    it costs nothing until a ``jax.profiler`` session is active.
+
+    ``serve_loop_seconds_total{phase, kind}`` accounts the serving loop
+    thread's wall time: the spans that name a ``phase`` add their
+    seconds, and ``loop_edge`` - called once per round by the service
+    loop - books what the round spent outside them as ``other``."""
 
     def __init__(self, *, enabled: bool = True, trace: bool = False,
                  pid: int = 0, capacity: int = 65536,
                  clock=time.perf_counter):
         self.enabled = bool(enabled)
+        self.clock = clock
+        self._annotate = _trace_annotation()
         self.metrics = MetricsRegistry()
         self.tracer = Tracer(enabled=self.enabled and bool(trace),
                              capacity=capacity, pid=pid, clock=clock)
@@ -404,6 +443,9 @@ class Telemetry:
                                  + (" (coordinator)" if pid == 0 else ""))
         for tid, name in _TID_NAMES.items():
             self.tracer.name_thread(pid, tid, name)
+        self._phases: dict[tuple[str, str], Counter] = {}
+        self._named_s = 0.0        # named-phase seconds since the last edge
+        self._edge: float | None = None
         m = self.metrics
         if self.enabled:
             self.ttft = m.histogram(
@@ -413,9 +455,10 @@ class Telemetry:
                 "inter-token latency after the first token")
             self.queue_wait = m.histogram(
                 "serve_queue_wait_seconds", "submit -> slot admission wait")
-            self.round_occupancy = m.histogram(
-                "serve_round_occupancy",
-                "live slots at each decode round", buckets=OCCUPANCY_BUCKETS)
+            self.deliver = m.histogram(
+                "serve_frontdoor_deliver_seconds",
+                "oldest token of an SSE write pushed by the scheduler -> "
+                "the write drained to the socket")
             self.shed = m.counter(
                 "serve_shed_total",
                 "requests shed at the admission watermark (HTTP 429)")
@@ -427,13 +470,44 @@ class Telemetry:
                 "pdq_clip_hits", "int8 outputs saturated at the clip edges")
             self.pdq_clip_total = m.counter(
                 "pdq_clip_total", "int8 outputs checked for clip saturation")
-            self.pdq_clip_rate = m.gauge(
-                "pdq_clip_rate",
-                "cumulative int8 clip-saturation rate (hits / total)")
 
     def span(self, name: str, *, cat: str = "phase", tid: int = TID_LAUNCH,
-             **args):
-        return self.tracer.span(name, cat=cat, tid=tid, **args)
+             phase: str | None = None, kind: str = "loop", **args):
+        """Context manager around one phase: a profiler annotation
+        ``name``; a ring span when tracing; with ``phase`` (one of
+        ``LOOP_PHASES``) its seconds in ``serve_loop_seconds_total{phase,
+        kind}``.  Spans that count a phase must not nest in one another."""
+        ann = self._annotate(name) if self._annotate is not None \
+            else _NULL_SPAN
+        counter = (self.phase_counter(phase, kind)
+                   if phase is not None and self.enabled else None)
+        if counter is None and not self.tracer.enabled:
+            return ann
+        return _TelSpan(self, ann, name, cat, tid, args, counter)
+
+    def phase_counter(self, phase: str, kind: str) -> Counter:
+        c = self._phases.get((phase, kind))
+        if c is None:
+            assert phase in LOOP_PHASES, phase
+            c = self._phases[(phase, kind)] = self.metrics.counter(
+                "serve_loop_seconds_total",
+                "serving loop thread wall time by phase", phase=phase,
+                kind=kind)
+        return c
+
+    def loop_edge(self, *, first: bool = False) -> None:
+        """A serving-loop round boundary: the wall time since the previous
+        edge not spent in a named phase is booked as ``other``, so the
+        phases sum to the loop's wall time between its first and last
+        edge.  ``first`` opens the accounting without booking."""
+        if not self.enabled:
+            return
+        t = self.clock()
+        if self._edge is not None and not first:
+            self.phase_counter("other", "loop").inc(
+                max(0.0, t - self._edge - self._named_s))
+        self._edge = t
+        self._named_s = 0.0
 
     def launch_histogram(self, kind: str, process: int | None = None
                          ) -> Histogram:
@@ -455,9 +529,6 @@ class Telemetry:
         self.pdq_fallbacks.inc(float(fallbacks))
         self.pdq_clip_hits.inc(float(clip_hits))
         self.pdq_clip_total.inc(float(clip_total))
-        if self.pdq_clip_total.value > 0:
-            self.pdq_clip_rate.set(
-                self.pdq_clip_hits.value / self.pdq_clip_total.value)
 
     def summary(self) -> dict:
         """Drain/exit printout payload: p50/p90/p99 of the latency

@@ -11,17 +11,25 @@ Pins the observability contract:
     span is a "X" complete event with numeric ts/dur and int pid/tid, and
     process/thread metadata rows name every (pid, tid) in the trace;
   * a served engine populates the standard series (TTFT, per-token, queue
-    wait, launch wall time, round occupancy, pdq health) and ``GET
+    wait, launch wall time, loop phase seconds, pdq health) and ``GET
     /metrics`` + ``GET /v1/events`` serve them over the front door;
+  * the serve loop's phases (serve_loop_seconds_total) sum to the loop
+    thread's wall time, ``fetch`` is booked once per decode dispatch, and
+    the front door observes one delivery per SSE write that carried
+    tokens;
+  * ``Telemetry.span`` writes a ``jax.profiler`` annotation of its name
+    with the ring off, and the ring's export carries its clock epoch;
   * /v1/stats and /metrics survive a concurrent scrape storm racing the
     serving loop (the PR-9 snapshot-under-lock fix - list-valued counters
     used to be serialized while the loop thread resized them);
   * the device-side pdq collector counts clip saturation and guard
     fallbacks without adding pallas_calls (census pinned elsewhere).
 """
+import glob
 import http.client
 import json
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -33,14 +41,15 @@ try:
 except ImportError:
     from _hypo_compat import given, settings, strategies as st
 
-from test_serve_service import _http, _prompts, _req, _wait
+from test_serve_service import _http, _prompts, _req, _sse, _wait
 
 from repro.configs import reduced_config
 from repro.kernels import ops
 from repro.models import build_model
 from repro.models.linops import quantize_weight
 from repro.serve import Request, ServeConfig, ServeService, build_engine
-from repro.serve.telemetry import (LATENCY_BUCKETS, Histogram,
+from repro.serve.service import TokenStream
+from repro.serve.telemetry import (LATENCY_BUCKETS, LOOP_PHASES, Histogram,
                                    MetricsRegistry, Telemetry, Tracer)
 
 
@@ -112,10 +121,13 @@ def test_registry_is_shared_by_handle_and_lookup():
     assert again is tel.ttft and again.count == 1
     text = tel.metrics.render()
     for name in ("serve_ttft_seconds", "serve_per_token_seconds",
-                 "serve_queue_wait_seconds", "serve_round_occupancy",
-                 "serve_shed_total", "pdq_fallbacks", "pdq_clip_hits",
-                 "pdq_clip_total", "pdq_clip_rate"):
+                 "serve_queue_wait_seconds",
+                 "serve_frontdoor_deliver_seconds", "serve_shed_total",
+                 "pdq_fallbacks", "pdq_clip_hits", "pdq_clip_total"):
         assert f"# TYPE {name}" in text, name
+    # the two series that duplicated exported numbers are gone
+    assert "serve_round_occupancy" not in text
+    assert "pdq_clip_rate" not in text
 
 
 def test_disabled_telemetry_renders_empty_and_spans_are_noops():
@@ -186,8 +198,8 @@ def test_histogram_merge_rejects_mismatched_buckets():
 def test_tracer_exports_valid_chrome_trace():
     clock = iter(np.arange(0.0, 10.0, 0.001))
     tr = Tracer(enabled=True, pid=0, clock=lambda: next(clock))
-    with tr.span("launch:decode", cat="phase", tid=2, rows=4):
-        pass
+    tr.add("launch:decode", cat="phase", ts=tr.now_us(), dur=1000.0, tid=2,
+           args={"rows": 4})
     tr.add("launch:prefill", ts=100.0, dur=250.0, pid=1, tid=2,
            args={"process": 1})
     tr.name_process(1, "jax process 1")
@@ -290,7 +302,9 @@ def test_served_engine_populates_standard_series(small_model):
     assert tel.ttft.count == 3
     assert tel.per_token.count == sum(len(r.generated) - 1 for r in reqs)
     assert tel.queue_wait.count == 3
-    assert tel.round_occupancy.count > 0
+    phases = {dict(labels)["phase"] for labels in
+              tel.metrics.get("serve_loop_seconds_total")}
+    assert {"plan", "dispatch", "fetch", "apply"} <= phases
     kinds = {k for labels, _ in
              tel.metrics.get("serve_launch_seconds").items()
              for lk, k in labels if lk == "kind"}
@@ -301,7 +315,9 @@ def test_served_engine_populates_standard_series(small_model):
         assert s["count"] > 0 and 0 <= s["p50"] <= s["p90"] <= s["p99"]
     names = {e["name"] for e in tel.tracer.events()}
     assert {"plan:prefill", "launch:prefill", "apply:prefill",
-            "plan:decode", "launch:decode", "apply:decode"} <= names
+            "plan:decode", "launch:decode", "apply:decode",
+            "dispatch:prefill", "fetch:prefill",
+            "dispatch:decode", "fetch:decode"} <= names
     assert any(n.startswith("req 0") for n in names)
     # request spans ride the request thread row with uid attribution
     req_spans = [e for e in tel.tracer.events() if e["tid"] == 0]
@@ -345,9 +361,12 @@ def test_metrics_and_events_endpoints(small_model):
         for name in ("serve_ttft_seconds_bucket", "serve_ttft_seconds_count",
                      "serve_per_token_seconds_sum",
                      "serve_queue_wait_seconds_count",
-                     "serve_launch_seconds_bucket", "serve_round_occupancy",
-                     "pdq_fallbacks", "pdq_clip_rate"):
+                     "serve_launch_seconds_bucket",
+                     "serve_loop_seconds_total", "pdq_fallbacks",
+                     "pdq_clip_hits"):
             assert name in text, name
+        assert 'serve_loop_seconds_total{kind="decode",phase="fetch"}' \
+            in text
         assert 'serve_launch_seconds_bucket{kind="prefill"' in text
         assert "serve_ttft_seconds_count 3" in text
         st, body, hdrs = _req(fe.port, "GET", "/v1/events")
@@ -416,3 +435,116 @@ def test_stats_and_metrics_survive_concurrent_scrape_storm(small_model):
     snap = eng.stats_snapshot()
     assert snap["completed"] == len(streams)
     assert isinstance(snap["replica_admits"], list)
+
+
+# ---------------------------------------------------------------------------
+# the serve loop's phases, the front-door stamp, profiler annotations
+# ---------------------------------------------------------------------------
+
+
+def _phase_seconds(tel) -> dict:
+    return {(dict(k)["phase"], dict(k)["kind"]): c.value for k, c in
+            tel.metrics.get("serve_loop_seconds_total").items()}
+
+
+def test_loop_phases_sum_to_the_loop_wall_time(small_model):
+    cfg, m, params = small_model
+    eng = _engine(cfg, params, paged=True, page_size=16, decode_steps=2)
+    svc = ServeService(eng, max_pending=8)
+    t0 = time.perf_counter()
+    svc.start()
+    for s in [svc.submit(p, max_new=6) for p in _prompts(cfg, [5, 9, 20])]:
+        s.result(timeout=300)
+    time.sleep(0.3)                          # an idle stretch
+    svc.submit(_prompts(cfg, [7], seed=1)[0], max_new=3).result(timeout=300)
+    svc.stop()
+    wall = time.perf_counter() - t0
+    sec = _phase_seconds(eng.tel)
+    assert {p for p, _ in sec} == set(LOOP_PHASES)
+    assert sec[("idle", "loop")] >= 0.25
+    assert abs(sum(sec.values()) - wall) <= 0.02 * wall, (sum(sec.values()),
+                                                          wall)
+
+
+def test_fetch_is_booked_once_per_decode_dispatch(small_model):
+    cfg, m, params = small_model
+    eng = _engine(cfg, params, trace=True, decode_steps=2)
+    reqs = [Request(uid=i, prompt=p, max_new=5)
+            for i, p in enumerate(_prompts(cfg, [4, 11]))]
+    eng.run(reqs)
+    spans = [e for e in eng.tel.tracer.events()
+             if e["name"] == "fetch:decode"]
+    assert len(spans) == eng.stats["decode_steps"] > 0
+    # fetch nests in its launch, after its dispatch
+    launches = [e for e in eng.tel.tracer.events()
+                if e["name"] == "launch:decode"]
+    dispatches = [e for e in eng.tel.tracer.events()
+                  if e["name"] == "dispatch:decode"]
+    for ln, d, f in zip(launches, dispatches, spans):
+        assert ln["ts"] <= d["ts"] <= d["ts"] + d["dur"] <= f["ts"] + 1e-3
+        assert f["ts"] + f["dur"] <= ln["ts"] + ln["dur"] + 1e-3
+    sec = _phase_seconds(eng.tel)
+    assert sec[("fetch", "decode")] == pytest.approx(
+        sum(e["dur"] for e in spans) * 1e-6, rel=1e-3, abs=1e-5)
+
+
+def test_frontdoor_observes_one_delivery_per_sse_write(small_model,
+                                                       monkeypatch):
+    cfg, m, params = small_model
+    eng = _engine(cfg, params, decode_steps=2)
+    writes = []                              # drains that carried tokens
+    drain = TokenStream.drain
+
+    def counting(self):
+        toks, fin = drain(self)
+        if toks:
+            writes.append(self.drained_since)
+        return toks, fin
+
+    monkeypatch.setattr(TokenStream, "drain", counting)
+    svc = ServeService(eng, max_pending=8).start()
+    with _http(svc) as fe:
+        got = [_sse(fe.port, {"prompt": p.tolist(), "max_tokens": 6,
+                              "stream": True})[0]
+               for p in _prompts(cfg, [5, 12])]
+    svc.stop()
+    assert [len(t) for t in got] == [6, 6]
+    h = eng.tel.deliver
+    assert 2 <= h.count == len(writes) <= 12
+    assert all(t is not None for t in writes)
+    assert 0.0 < h.sum < 60.0
+
+
+def test_span_writes_a_profiler_annotation_with_the_ring_off(tmp_path):
+    tel = Telemetry(enabled=True, trace=False)
+    assert not tel.tracer.enabled
+    x = jnp.ones((64, 64))
+    with jax.profiler.trace(str(tmp_path)):
+        with tel.span("plan:decode", phase="plan", kind="decode"):
+            time.sleep(0.002)
+        with tel.span("fetch:decode"):
+            (x @ x).block_until_ready()
+    assert tel.tracer.events() == []
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    host = {ev.name: ev.duration_ns for plane in pd.planes
+            if plane.name.startswith("/host:CPU")
+            for line in plane.lines for ev in line.events}
+    assert host["plan:decode"] >= 2e6 and "fetch:decode" in host
+    assert _phase_seconds(tel)[("plan", "decode")] >= 0.002
+
+
+def test_trace_export_carries_the_clock_epoch():
+    clock = iter(np.arange(5.0, 10.0, 0.001))
+    before = time.time_ns()
+    tel = Telemetry(trace=True, clock=lambda: next(clock))
+    after = time.time_ns()
+    with tel.span("apply:decode"):
+        pass
+    other = json.loads(json.dumps(tel.tracer.export()))["otherData"]
+    assert other["epoch_clock_s"] == 5.0
+    assert before <= other["epoch_unix_ns"] <= after
+    # a span's wall time is epoch_unix_ns + ts * 1e3: here 1 ms after it
+    ev, = tel.tracer.events()
+    assert ev["ts"] == pytest.approx(1000.0) and ev["dur"] == \
+        pytest.approx(1000.0)
