@@ -741,13 +741,25 @@ def dequantize(q, scale, zero_point, *, per_channel: bool = False, out_dtype=jnp
     return y[:M, :N].reshape(*lead, N).astype(out_dtype)
 
 
-def decode_attend_i8kv(q, k_q, v_q, k_scale, v_scale, length, *, bs: int = 256,
-                       wo_prologue: bool = False, pro_dtype=None):
+def decode_attend_i8kv(q, k_q, v_q, k_scale, v_scale, length, *, layer=None,
+                       new=None, bs: int = 256, wo_prologue: bool = False,
+                       pro_dtype=None):
     """Batched flash-decode over an int8 KV cache in KERNEL layout.
 
     q: (B, H, Dh) f32; k_q/v_q: (B, Hkv, S, Dh) int8;
     k_scale/v_scale: (B, Hkv, S) f32; length: (B,) int32.
     Returns (B, H, Dh) f32.
+
+    ``layer`` (a traced or static int) reads a LAYER-STACKED cache
+    instead: k_q/v_q (L, B, Hkv, S, Dh), scales (L, B, Hkv, S), and the
+    kernel streams layer ``layer``'s tiles straight out of the stack (the
+    index is scalar-prefetched), so the decode step never materialises a
+    per-layer slice of the cache.
+
+    ``new`` = (slots (B,), k, v (B, Hkv, Dh) int8, k_scale, v_scale
+    (B, Hkv) f32) is the step's token: the same launch writes it into the
+    cache at (row, ``slots[row]``) before attending it, in place, and the
+    call returns (its result, (k_q, v_q, k_scale, v_scale) updated).
 
     ``wo_prologue=True`` additionally runs the wo projection's PDQ prologue
     over the flattened (H * Dh,) output row inside the attend kernel's
@@ -761,56 +773,59 @@ def decode_attend_i8kv(q, k_q, v_q, k_scale, v_scale, length, *, bs: int = 256,
 
     The cache is head-major so the per-step decode path does no layout
     work: ``models.attention.init_cache`` allocates it this way (S rounded
-    up to a 128 multiple) and ``_cache_write`` scatters new tokens straight
-    into kernel layout.  With S % block == 0 the ``_pad_to`` calls below
-    are trace-time no-ops; only ragged direct callers pay a one-off batched
-    pad (outside the vmapped per-token path, not per decode step).
+    up to a 128 multiple).  With S % block == 0 the ``_pad_to`` calls below
+    are trace-time no-ops; only ragged direct callers pay a one-off pad.
     """
+    stacked = layer is not None
+    cache = (k_q, v_q, k_scale, v_scale)
+    if not stacked:
+        cache = tuple(a[None] for a in cache)
+        layer = 0
     B, H, Dh = q.shape
-    Hkv, S = k_q.shape[1], k_q.shape[2]
+    Hkv, S = cache[0].shape[2], cache[0].shape[3]
     G = H // Hkv
 
     if not _use_kernel():
+        if new is not None:
+            slots, *tok = new
+            at = (layer, jnp.arange(B)[:, None], jnp.arange(Hkv),
+                  slots[:, None])
+            cache = tuple(a.at[at].set(t) for a, t in zip(cache, tok))
         # jnp oracle keeps the logical (S, Hkv, ...) layout
-        k_l = jnp.transpose(k_q, (0, 2, 1, 3))
-        v_l = jnp.transpose(v_q, (0, 2, 1, 3))
-        ks_l = jnp.transpose(k_scale, (0, 2, 1))
-        vs_l = jnp.transpose(v_scale, (0, 2, 1))
-        o = jax.vmap(ref.decode_attend_i8kv_ref)(q, k_l, v_l, ks_l, vs_l, length)
-        if not wo_prologue:
-            return o
-        of = o.astype(pro_dtype) if pro_dtype is not None else o
-        o_q, s_x, s1, s2 = ref.pdq_prologue_ref(of.reshape(B, H * Dh))
-        return o, o_q, s_x, s1, s2
-
-    # prefer a scan block that divides S (true whenever the cache came from
-    # init_cache, which rounds S to a 128 multiple) over padding per call
-    bss = min(bs, S)
-    while bss > 32 and S % bss:
-        bss //= 2
-    k_q = _pad_to(k_q, 2, bss)
-    v_q = _pad_to(v_q, 2, bss)
-    k_scale = _pad_to(k_scale, 2, bss, value=1.0)
-    v_scale = _pad_to(v_scale, 2, bss, value=1.0)
-
-    if wo_prologue:
-        def one_fused(q1, k1, v1, ks1, vs1, len1):
-            o, oq, sx, s1, s2 = decode_attend_i8kv_fused_p(
-                q1.reshape(Hkv, G, Dh), k1, v1, ks1, vs1,
-                len1.reshape(1, 1).astype(jnp.int32),
-                bs=bss, interpret=_interpret())
-            return (o.reshape(H, Dh), oq.reshape(H * Dh),
-                    sx.reshape(1), s1.reshape(1), s2.reshape(1))
-
-        return jax.vmap(one_fused)(q, k_q, v_q, k_scale, v_scale, length)
-
-    def one(q1, k1, v1, ks1, vs1, len1):
-        o = decode_attend_i8kv_p(q1.reshape(Hkv, G, Dh), k1, v1, ks1, vs1,
-                                 len1.reshape(1, 1).astype(jnp.int32),
-                                 bs=bss, interpret=_interpret())
-        return o.reshape(H, Dh)
-
-    return jax.vmap(one)(q, k_q, v_q, k_scale, v_scale, length)
+        k_l, v_l, ks_l, vs_l = (
+            jnp.swapaxes(jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+                         1, 2) for a in cache)
+        o = jax.vmap(ref.decode_attend_i8kv_ref)(q, k_l, v_l, ks_l, vs_l,
+                                                  length)
+        if wo_prologue:
+            of = o.astype(pro_dtype) if pro_dtype is not None else o
+            o = (o, *ref.pdq_prologue_ref(of.reshape(B, H * Dh)))
+    else:
+        # prefer a scan block that divides S (true whenever the cache came
+        # from init_cache, which rounds S to a 128 multiple) over padding
+        bss = min(bs, S)
+        while bss > 32 and S % bss:
+            bss //= 2
+        cache = (_pad_to(cache[0], 3, bss), _pad_to(cache[1], 3, bss),
+                 _pad_to(cache[2], 3, bss, value=1.0),
+                 _pad_to(cache[3], 3, bss, value=1.0))
+        kern = decode_attend_i8kv_fused_p if wo_prologue else decode_attend_i8kv_p
+        o = kern(q.reshape(B, Hkv, G, Dh), *cache, length,
+                 jnp.asarray(layer, jnp.int32), new, bs=bss,
+                 interpret=_interpret())
+        if new is not None:
+            o, cache = o
+            cache = (cache[0][:, :, :, :S], cache[1][:, :, :, :S],
+                     cache[2][..., :S], cache[3][..., :S])
+        if wo_prologue:
+            o, oq, sx, s1, s2 = o
+            o = (o.reshape(B, H, Dh), oq.reshape(B, H * Dh), sx.reshape(B, 1),
+                 s1.reshape(B, 1), s2.reshape(B, 1))
+        else:
+            o = o.reshape(B, H, Dh)
+    if new is None:
+        return o
+    return o, (cache if stacked else tuple(a[0] for a in cache))
 
 
 def cache_scatter_rows(dst, src, src_map, *, batch_axis: int = 0, _entry=None):

@@ -229,28 +229,45 @@ def _quant_kv_token(k_new, v_new):
     return kq, ks, vq, vs
 
 
-def _cache_write(cache, k_new, v_new, positions, quant: str):
-    """Write S_new tokens at ring positions (pos % W for windows)."""
-    B, S_new = positions.shape
-    W = cache["pos"].shape[1]              # logical length (int8 caches pad S)
-    slots = positions % W
-    bidx = jnp.arange(B)[:, None]
+def _cache_write(cache, k_new, v_new, positions, quant: str, layer=None):
+    """Write S_new tokens at ring positions (pos % W for windows).
+
+    ``layer`` marks a LAYER-STACKED cache (every leaf (L, B, ...)): the
+    tokens land at (layer, row, slot) by one scatter per leaf, so a decode
+    step that carries the stack through the layer scan updates it in place
+    and rewrites nothing else of the layer."""
+    slots = positions % cache["pos"].shape[-1]   # logical length (int8 pads S)
+    at = () if layer is None else (layer,)
+    bidx = jnp.arange(positions.shape[0])[:, None]
+    cache = dict(cache)
     if quant != "none":
         kq, ks, vq, vs = _quant_kv_token(k_new, v_new)
-        cache = dict(cache)
         # kernel-layout cache (B, Hkv, Sp, Dh): advanced indexing brings
         # the (B, S_new) gather dims to the front, so the (B, S_new, Hkv,
         # Dh) update lands without any transpose.
-        cache["k"] = cache["k"].at[bidx, :, slots].set(kq)
-        cache["v"] = cache["v"].at[bidx, :, slots].set(vq)
-        cache["k_scale"] = cache["k_scale"].at[bidx, :, slots].set(ks)
-        cache["v_scale"] = cache["v_scale"].at[bidx, :, slots].set(vs)
+        idx = at + (bidx, slice(None), slots)
+        cache["k"] = cache["k"].at[idx].set(kq)
+        cache["v"] = cache["v"].at[idx].set(vq)
+        cache["k_scale"] = cache["k_scale"].at[idx].set(ks)
+        cache["v_scale"] = cache["v_scale"].at[idx].set(vs)
     else:
-        cache = dict(cache)
-        cache["k"] = cache["k"].at[bidx, slots].set(k_new.astype(cache["k"].dtype))
-        cache["v"] = cache["v"].at[bidx, slots].set(v_new.astype(cache["v"].dtype))
-    cache["pos"] = cache["pos"].at[bidx, slots].set(positions)
-    cache["len"] = jnp.maximum(cache["len"], positions[:, -1] + 1)
+        idx = at + (bidx, slots)
+        cache["k"] = cache["k"].at[idx].set(k_new.astype(cache["k"].dtype))
+        cache["v"] = cache["v"].at[idx].set(v_new.astype(cache["v"].dtype))
+    return _cache_advance(cache, positions, layer)
+
+
+def _cache_advance(cache, positions, layer=None):
+    """Record written positions: ``pos`` at their slots, and ``len``."""
+    B = positions.shape[0]
+    slots = positions % cache["pos"].shape[-1]
+    at = () if layer is None else (layer,)
+    cache = dict(cache)
+    cache["pos"] = cache["pos"].at[at + (jnp.arange(B)[:, None], slots)].set(
+        positions)
+    length = jnp.maximum(_at_layer(cache["len"], layer), positions[:, -1] + 1)
+    cache["len"] = (length if layer is None
+                    else cache["len"].at[layer].set(length))
     return cache
 
 
@@ -292,6 +309,21 @@ def _cache_kv_float(cache, dtype):
     return cache["k"], cache["v"]
 
 
+def is_gqa_cache(cache) -> bool:
+    """A GQA K/V cache (fp, or int8 with its scales; global or a window
+    ring), as opposed to an MLA latent, SSM or cross-attention cache."""
+    return "k" in cache and set(cache) <= {"k", "v", "k_scale", "v_scale",
+                                           "pos", "len"}
+
+
+def _at_layer(a: jax.Array, layer) -> jax.Array:
+    """Layer ``layer`` of a stacked cache leaf; the leaf itself when
+    ``layer`` is None (the cache is one layer's)."""
+    if layer is None:
+        return a
+    return jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
+
+
 def _valid_k_pos(cache_pos: jax.Array) -> jax.Array:
     """Cache slot positions with empty slots (-1) pushed beyond every real
     query position, so the causal mask of ``chunked_attention`` (which has
@@ -310,6 +342,7 @@ def gqa_apply(
     causal: bool = True,
     seq_lens: jax.Array | None = None,   # (B,) valid prefix per right-padded row
     chunked: bool = False,            # continuation chunk: attend the cache
+    layer: jax.Array | None = None,   # decode: the cache is this layer's stack
 ):
     B, S, d = x.shape
     H, Hkv, Dh = dims.n_heads, dims.n_kv_heads, dims.head_dim
@@ -369,32 +402,42 @@ def gqa_apply(
                               parallel_q=True)
         return lin(o.reshape(B, S, H * Dh), p["wo"]), cache
 
-    # decode: S == 1
-    cache = _cache_write(cache, k, v, positions, dims.quant_kv)
+    # decode: S == 1.  With ``layer`` the cache is the whole layer stack,
+    # carried through the layer scan: the token is written in place and
+    # the layer is read where it lies (the fp path's dynamic slice fuses
+    # into its dot).
     q1 = q[:, 0]                                            # (B, H, Dh)
-    if ("k_scale" in cache and dims.attn_softcap is None and dims.window is None):
-        if is_quantized(p["wo"]) and not is_segment_view(p["wo"]):
-            # fused path: the attend kernel's output stage also runs the wo
-            # projection's PDQ prologue over the flattened row, so the
-            # quantized wo costs one W8A8 launch instead of prologue+matmul
-            o, o_q, s_x, s1, s2 = ops.decode_attend_i8kv(
-                q1.astype(jnp.float32), cache["k"], cache["v"],
-                cache["k_scale"], cache["v_scale"], cache["len"],
-                wo_prologue=True, pro_dtype=x.dtype)
+    if "k_scale" in cache and dims.attn_softcap is None and dims.window is None:
+        # the int8-KV flash-decode kernel (ref off-TPU) writes the token
+        # into the stack itself, in the launch that attends it
+        kq, ks, vq, vs = _quant_kv_token(k, v)
+        slots = positions[:, 0] % cache["pos"].shape[-1]
+        new = (slots, kq[:, 0], vq[:, 0], ks[:, 0], vs[:, 0])
+        cache = _cache_advance(cache, positions, layer)
+        kv = (cache["k"], cache["v"], cache["k_scale"], cache["v_scale"])
+        length = _at_layer(cache["len"], layer)
+        fused = is_quantized(p["wo"]) and not is_segment_view(p["wo"])
+        o, kv = ops.decode_attend_i8kv(
+            q1.astype(jnp.float32), *kv, length, layer=layer, new=new,
+            wo_prologue=fused, pro_dtype=x.dtype if fused else None)
+        cache = dict(cache, k=kv[0], v=kv[1], k_scale=kv[2], v_scale=kv[3])
+        if fused:
+            # the attend kernel's output stage also ran the wo projection's
+            # PDQ prologue over the flattened row, so the quantized wo
+            # costs one W8A8 launch instead of prologue+matmul
+            o, o_q, s_x, s1, s2 = o
             y = ops.pdq_dense_from_prologue(
                 o.reshape(B, 1, H * Dh).astype(x.dtype),
                 o_q.reshape(B, 1, H * Dh),
                 s_x.reshape(B, 1, 1), s1.reshape(B, 1, 1), s2.reshape(B, 1, 1),
                 p["wo"], out_dtype=x.dtype)
             return y, cache
-        # int8-KV flash-decode kernel path (falls back to ref off-TPU)
-        o = ops.decode_attend_i8kv(
-            q1.astype(jnp.float32), cache["k"], cache["v"],
-            cache["k_scale"], cache["v_scale"], cache["len"])
         o = o.astype(x.dtype)
     else:
-        kf, vf = _cache_kv_float(cache, x.dtype)
-        o = decode_attention(q1, kf, vf, positions[:, 0], cache["pos"],
+        cache = _cache_write(cache, k, v, positions, dims.quant_kv, layer)
+        one = {n: _at_layer(a, layer) for n, a in cache.items()}
+        kf, vf = _cache_kv_float(one, x.dtype)
+        o = decode_attention(q1, kf, vf, positions[:, 0], one["pos"],
                              window=dims.window, attn_softcap=dims.attn_softcap)
     return lin(o.reshape(B, 1, H * Dh), p["wo"]), cache
 

@@ -2,7 +2,12 @@
 
 The repeated pattern blocks run under lax.scan over stacked params (compile
 time stays O(pattern), not O(n_layers)); head/tail layers are unrolled.
-Caches are threaded through the scan as xs/ys.  ``mode`` is one of
+Caches are threaded through the scan as xs/ys, except in decode, where the
+layer-stacked GQA K/V caches (fp or int8, global or a local-window ring)
+ride the scan carry with the layer index as xs: each layer writes its
+token in place at (layer, row, slot) and reads its layer where it lies, so
+no step slices, writes back or copies a whole layer of cache.  MLA latent,
+SSM and cross-attention caches keep xs/ys.  ``mode`` is one of
 'train' | 'prefill' | 'decode'.
 """
 from __future__ import annotations
@@ -18,8 +23,8 @@ from jax.ad_checkpoint import checkpoint_name
 from repro.kernels import ops
 
 from . import context
-from .attention import (AttnDims, gqa_apply, gqa_init, init_cache, mla_apply,
-                        mla_init, mla_init_cache)
+from .attention import (AttnDims, gqa_apply, gqa_init, init_cache,
+                        is_gqa_cache, mla_apply, mla_init, mla_init_cache)
 from .config import ArchConfig
 from .layers import embed_init, mlp_apply, mlp_init, rms_norm, softcap
 from .moe import moe_ffn_dense_masked, moe_ffn_tokens, moe_init
@@ -150,11 +155,13 @@ def _apply_ffn(p_ffn, cfg: ArchConfig, kind: str, h: jax.Array, mode: str,
 
 def layer_apply(p, cfg: ArchConfig, kind: str, h, positions, *, mode: str,
                 cache=None, memory=None, causal: bool = True, seq_lens=None,
-                chunked: bool = False):
+                chunked: bool = False, layer=None):
     """Returns (h, new_cache, aux).  ``seq_lens`` (B,) marks the valid
     prefix of right-padded bucketed-prefill rows (None = no padding);
     ``chunked`` marks a chunked-prefill continuation (the cache rows
-    already hold earlier chunks, which attention must see)."""
+    already hold earlier chunks, which attention must see); ``layer``
+    marks a GQA ``cache`` as the whole layer stack, of which this is
+    layer ``layer`` (decode)."""
     eps = cfg.norm_eps
     if kind == "mamba":
         y, new_cache = ssm_apply(p["ssm"], cfg.ssm, rms_norm(h, p["norm"], eps),
@@ -169,7 +176,8 @@ def layer_apply(p, cfg: ArchConfig, kind: str, h, positions, *, mode: str,
     else:
         a, new_cache = gqa_apply(p["attn"], _attn_dims(cfg, kind), xin, positions,
                                  mode=mode, cache=cache, causal=causal,
-                                 seq_lens=seq_lens, chunked=chunked)
+                                 seq_lens=seq_lens, chunked=chunked,
+                                 layer=layer)
     a = checkpoint_name(a, "attn_out")
     h = h + a
 
@@ -314,21 +322,33 @@ def lm_apply(params, cfg: ArchConfig, *, tokens=None, positions, mode: str,
         aux_total += aux
 
     shared_p = params.get("shared_block")
+    # decode carries each pattern position's stacked GQA K/V cache through
+    # the scan and updates it in place (module docstring); every other
+    # cache rides xs/ys, one layer's slice per iteration
+    blocks_c = caches["blocks"] if caches else (None,) * len(cfg.pattern)
+    in_place = tuple(mode == "decode" and c is not None and is_gqa_cache(c)
+                     for c in blocks_c)
+    stack = tuple(c if ip else None for c, ip in zip(blocks_c, in_place))
+    xs_c = tuple(None if ip else c for c, ip in zip(blocks_c, in_place))
 
     def block_body(carry, xs):
-        hh, aux_acc = carry
-        block_p, block_c = xs
-        ncs = []
+        (hh, aux_acc), stack = carry
+        block_p, block_c, layer = xs
+        ncs, stack = [], list(stack)
         for j, kind in enumerate(cfg.pattern):
             pj = shared_p if kind == "shared" else block_p[j]
-            cj = block_c[j] if block_c is not None else None
+            ip = in_place[j]
+            cj = stack[j] if ip else block_c[j]
             hh, ncj, aux = layer_apply(pj, cfg, kind if kind != "shared" else "global",
                                        hh, positions, mode=mode, cache=cj,
                                        memory=memory, seq_lens=seq_lens,
-                                       chunked=chunked)
+                                       chunked=chunked,
+                                       layer=layer if ip else None)
+            if ip:
+                stack[j], ncj = ncj, None
             ncs.append(ncj if ncj is not None else ())
             aux_acc = aux_acc + aux
-        return (hh, aux_acc), tuple(ncs)
+        return ((hh, aux_acc), tuple(stack)), tuple(ncs)
 
     body = block_body
     if mode == "train" and cfg.remat == "full":
@@ -338,9 +358,11 @@ def lm_apply(params, cfg: ArchConfig, *, tokens=None, positions, mode: str,
             "moe_out", "attn_out")
         body = jax.checkpoint(block_body, prevent_cse=False, policy=policy)
 
-    xs = (params["blocks"], caches["blocks"] if caches else None)
-    (h, aux_total), blocks_nc = ops.pdq_telemetry_scan(body, (h, aux_total), xs)
-    new_caches["blocks"] = blocks_nc
+    xs = (params["blocks"], xs_c, jnp.arange(cfg.n_blocks, dtype=jnp.int32))
+    ((h, aux_total), stack), blocks_nc = ops.pdq_telemetry_scan(
+        body, ((h, aux_total), stack), xs)
+    new_caches["blocks"] = tuple(st if ip else nc for st, nc, ip
+                                 in zip(stack, blocks_nc, in_place))
 
     for i, kind in enumerate(cfg.tail):
         c = caches["tail"][i] if caches else None

@@ -168,13 +168,23 @@ def test_decode_i8kv_kernel_vs_ref(s, hkv, g, dh, frac):
     length = jnp.int32(int(s * frac))
 
     want = ref.decode_attend_i8kv_ref(q, k_q, v_q, k_s, v_s, length)
+    # kernel layout, as layer 1 of a two-layer stack of one batch row
     got = decode_attend_i8kv_p(
-        q.reshape(hkv, g, dh),
-        jnp.transpose(k_q, (1, 0, 2)), jnp.transpose(v_q, (1, 0, 2)),
-        jnp.transpose(k_s, (1, 0)), jnp.transpose(v_s, (1, 0)),
-        jnp.full((1, 1), length, jnp.int32), bs=128, interpret=True,
+        q.reshape(1, hkv, g, dh),
+        _stack_layer1(jnp.transpose(k_q, (1, 0, 2))),
+        _stack_layer1(jnp.transpose(v_q, (1, 0, 2))),
+        _stack_layer1(jnp.transpose(k_s, (1, 0))),
+        _stack_layer1(jnp.transpose(v_s, (1, 0))),
+        jnp.full((1,), length, jnp.int32), jnp.int32(1), bs=128,
+        interpret=True,
     ).reshape(H, dh)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def _stack_layer1(a):
+    """``a`` as batch row 0 of layer 1 in a two-layer stack whose layer 0
+    holds other values: a kernel that reads the wrong layer fails."""
+    return jnp.stack([jnp.flip(a, -1), a])[:, None]
 
 
 @pytest.mark.parametrize("s", [200, 256])   # ragged (padded per call) + aligned
@@ -198,6 +208,46 @@ def test_decode_i8kv_ops_batched(s):
         q, jnp.transpose(k_q, (0, 2, 1, 3)), jnp.transpose(v_q, (0, 2, 1, 3)),
         jnp.transpose(k_s, (0, 2, 1)), jnp.transpose(v_s, (0, 2, 1)), lens)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("s", [200, 256])   # ragged (padded per call) + aligned
+@pytest.mark.parametrize("wo_prologue", [False, True], ids=["plain", "fused"])
+def test_decode_i8kv_writes_token_in_place(wo_prologue, s):
+    """With ``new`` the attend launch writes the step's token into its layer
+    of the stack, then attends it: the kernel's stacks equal the written
+    stacks bit for bit (every other entry unchanged), and its output is the
+    oracle's over them."""
+    L, B, Hkv, G, Dh = 3, 3, 2, 2, 64
+    keys = jax.random.split(jax.random.PRNGKey(11), 9)
+    q = jax.random.normal(keys[0], (B, Hkv * G, Dh))
+    cache = (_rand_i8(keys[1], (L, B, Hkv, s, Dh)),
+             _rand_i8(keys[2], (L, B, Hkv, s, Dh)),
+             jax.random.uniform(keys[3], (L, B, Hkv, s), minval=0.01, maxval=0.05),
+             jax.random.uniform(keys[4], (L, B, Hkv, s), minval=0.01, maxval=0.05))
+    slots = jnp.array([0, 130, s - 1], jnp.int32)   # first, second, last block
+    tok = (_rand_i8(keys[5], (B, Hkv, Dh)), _rand_i8(keys[6], (B, Hkv, Dh)),
+           jax.random.uniform(keys[7], (B, Hkv), minval=0.01, maxval=0.05),
+           jax.random.uniform(keys[8], (B, Hkv), minval=0.01, maxval=0.05))
+    at = (1, jnp.arange(B)[:, None], jnp.arange(Hkv), slots[:, None])
+    want = tuple(a.at[at].set(t) for a, t in zip(cache, tok))
+    got = {}
+    for impl in ("ref", "kernel"):
+        ops.set_impl(impl)
+        try:
+            got[impl] = ops.decode_attend_i8kv(
+                q, *cache, slots + 1, layer=jnp.int32(1), new=(slots, *tok),
+                bs=128, wo_prologue=wo_prologue,
+                pro_dtype=jnp.float32 if wo_prologue else None)
+        finally:
+            ops.set_impl("auto")
+    for impl, (_, stacks) in got.items():
+        for w, g in zip(want, stacks):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                          err_msg=impl)
+    o_ref, o_kernel = got["ref"][0], got["kernel"][0]
+    if wo_prologue:
+        o_ref, o_kernel = o_ref[0], o_kernel[0]
+    np.testing.assert_allclose(o_kernel, o_ref, rtol=2e-4, atol=2e-4)
 
 
 # ---------------------------------------------------------------------------
@@ -430,11 +480,11 @@ def test_raw_kernels_reject_non_block_multiples():
                       jnp.ones((1, 100)), jnp.zeros((1, 100), jnp.int32),
                       s, z, requant=True)
     with pytest.raises(AssertionError, match="block-multiple"):
-        decode_attend_i8kv_p(jnp.zeros((2, 2, 64)),
-                             jnp.zeros((2, 200, 64), jnp.int8),
-                             jnp.zeros((2, 200, 64), jnp.int8),
-                             jnp.ones((2, 200)), jnp.ones((2, 200)),
-                             jnp.ones((1, 1), jnp.int32), bs=128)
+        decode_attend_i8kv_p(jnp.zeros((1, 2, 2, 64)),
+                             jnp.zeros((1, 1, 2, 200, 64), jnp.int8),
+                             jnp.zeros((1, 1, 2, 200, 64), jnp.int8),
+                             jnp.ones((1, 1, 2, 200)), jnp.ones((1, 1, 2, 200)),
+                             jnp.ones((1,), jnp.int32), jnp.int32(0), bs=128)
 
 
 # ---------------------------------------------------------------------------
@@ -455,13 +505,13 @@ def test_decode_i8kv_fused_wo_prologue_kernel_vs_ref(frac):
     v_q = _rand_i8(keys[2], (hkv, s, dh))
     k_s = jax.random.uniform(keys[3], (hkv, s), minval=0.01, maxval=0.05)
     v_s = jax.random.uniform(keys[4], (hkv, s), minval=0.01, maxval=0.05)
-    length = jnp.full((1, 1), int(s * frac), jnp.int32)
+    length = jnp.full((1,), int(s * frac), jnp.int32)
+    args = (q.reshape(1, hkv, g, dh), _stack_layer1(k_q), _stack_layer1(v_q),
+            _stack_layer1(k_s), _stack_layer1(v_s), length, jnp.int32(1))
 
-    o_plain = decode_attend_i8kv_p(q.reshape(hkv, g, dh), k_q, v_q, k_s, v_s,
-                                   length, bs=128, interpret=True)
-    o, o_q, s_x, s1, s2 = decode_attend_i8kv_fused_p(
-        q.reshape(hkv, g, dh), k_q, v_q, k_s, v_s, length,
-        bs=128, interpret=True)
+    o_plain = decode_attend_i8kv_p(*args, bs=128, interpret=True)
+    o, o_q, s_x, s1, s2 = decode_attend_i8kv_fused_p(*args, bs=128,
+                                                     interpret=True)
     np.testing.assert_array_equal(np.asarray(o), np.asarray(o_plain))
     wq, wsx, ws1, ws2 = ref.pdq_prologue_ref(o_plain.reshape(1, H * dh))
     np.testing.assert_allclose(s_x.reshape(1, 1), wsx, rtol=1e-5)

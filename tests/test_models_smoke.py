@@ -2,6 +2,7 @@
 forward/train step + prefill/decode on CPU; asserts shapes and finiteness."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import ALL_ARCHS, reduced_config
@@ -60,12 +61,27 @@ def test_arch_prefill_decode_smoke(arch):
         assert bool(jnp.all(jnp.isfinite(logits))), f"{arch}: non-finite decode logits"
 
 
-@pytest.mark.parametrize("arch", ["stablelm-1.6b", "gemma2-2b", "zamba2-7b",
-                                  "seamless-m4t-medium"])
-def test_decode_matches_prefill(arch):
-    """One-token decode after an (S-1)-prefill must reproduce the S-prefill
-    logits (validates KV/ring/SSM/cross caches)."""
-    cfg = reduced_config(arch)
+# (arch, cfg overrides, decode steps after the prefill).  Every decode
+# step runs the layer-stacked GQA caches in place (transformer.lm_apply);
+# the other cache kinds keep the scan's xs/ys.
+_DECODE_CASES = {
+    "stablelm-1.6b": ("stablelm-1.6b", {}, 1),                 # fp GQA
+    "gemma2-2b": ("gemma2-2b", {}, 1),                         # window ring
+    "zamba2-7b": ("zamba2-7b", {}, 1),                         # SSM + shared
+    "seamless-m4t-medium": ("seamless-m4t-medium", {}, 1),     # cross K/V
+    "stablelm-1.6b-int8-kv": ("stablelm-1.6b", {"quant_kv": "dynamic"}, 4),
+    "gemma2-2b-ring-past-window": ("gemma2-2b", {}, 20),       # window 16
+    "zamba2-7b-shared-attn-steps": ("zamba2-7b", {}, 6),
+}
+
+
+@pytest.mark.parametrize("case", list(_DECODE_CASES))
+def test_decode_matches_prefill(case):
+    """Decoding the last ``n`` tokens one at a time after an (S-n)-prefill
+    must reproduce the S-prefill logits (validates KV/ring/SSM/cross
+    caches, the in-place decode update included)."""
+    arch, overrides, n = _DECODE_CASES[case]
+    cfg = reduced_config(arch, **overrides)
     m = build_model(cfg)
     params = m.init(jax.random.PRNGKey(0))
     B, S = 2, 24
@@ -76,12 +92,48 @@ def test_decode_matches_prefill(arch):
     full, _ = m.prefill(params, batch, caches)
     caches = m.init_caches(B, S, mem_len)
     b2 = dict(batch)
-    b2["tokens"] = batch["tokens"][:, : S - 1]
+    b2["tokens"] = batch["tokens"][:, : S - n]
     _, caches = m.prefill(params, b2, caches)
-    dec, _ = m.decode_step(params, caches, batch["tokens"][:, S - 1:],
-                           jnp.full((B, 1), S - 1, jnp.int32))
+    for p in range(S - n, S):
+        dec, caches = m.decode_step(params, caches, batch["tokens"][:, p:p + 1],
+                                    jnp.full((B, 1), p, jnp.int32))
     scale = float(jnp.abs(full).max()) + 1e-6
     assert float(jnp.abs(full - dec).max()) / scale < 0.05
+
+
+@pytest.mark.parametrize("arch,quant_kv", [("stablelm-1.6b", "none"),
+                                           ("stablelm-1.6b", "dynamic"),
+                                           ("gemma2-2b", "none")],
+                         ids=["fp", "int8", "window_ring"])
+def test_decode_step_writes_only_its_token(arch, quant_kv):
+    """A decode step changes the layer-stacked GQA caches only at its own
+    (layer, row, slot) entries: every other byte of every layer stays."""
+    cfg = reduced_config(arch, quant_kv=quant_kv)
+    m = build_model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    B, S = 2, 20                           # past gemma2's reduced window 16
+    toks = jax.random.randint(jax.random.PRNGKey(4), (B, S), 0, cfg.vocab)
+    _, before = m.prefill(params, {"tokens": toks}, m.init_caches(B, S + 4))
+    pos = jnp.array([[S], [S - 3]], jnp.int32)   # row 1 rewrites a slot
+    _, after = jax.jit(m.decode_step)(params, before, toks[:, -1:], pos)
+    assert not jax.tree.leaves(before["head"]) + jax.tree.leaves(before["tail"])
+    for blk_before, blk_after in zip(before["blocks"], after["blocks"]):
+        W = blk_before["pos"].shape[-1]
+        slots = np.asarray(pos[:, 0]) % W
+        for name, a in blk_before.items():
+            a, got = np.array(a), np.asarray(blk_after[name])
+            for b, slot in enumerate(slots):
+                if name == "len":
+                    idx = (slice(None), b)
+                elif quant_kv != "none" and name != "pos":  # (L, B, H, S, ..)
+                    idx = (slice(None), b, slice(None), slot)
+                else:                                       # (L, B, S, ...)
+                    idx = (slice(None), b, slot)
+                a[idx] = got[idx]
+            np.testing.assert_array_equal(got, a, err_msg=name)
+        np.testing.assert_array_equal(
+            np.asarray(blk_after["pos"])[:, np.arange(B), slots],
+            np.broadcast_to(pos[:, 0], (cfg.n_blocks, B)))
 
 
 def test_int8_kv_cache_decode_close_to_fp():

@@ -18,7 +18,8 @@ Pinned table (DESIGN.md "Decode fast path" documents the breakdown):
                      prologue)
   decode_int8kv  7   the int8-KV attend's output stage emits wo's PDQ
                      prologue (decode_attend_i8kv_fused_p), so wo costs
-                     one W8A8 matmul launch
+                     one W8A8 matmul launch; the same launch writes the
+                     step's K/V token into the cache
   prefill        7   same budget at S>1: the fusions are mode-agnostic
   lin_quantized  2   one PDQ prologue + one W8A8 matmul per quantized
                      projection outside the fused blocks
