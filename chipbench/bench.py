@@ -3,11 +3,15 @@
 Every lookup is relative to a root that holds ``BENCHMARK.json``: the
 configuration file is the one the entry names, a traffic mix is
 ``chipbench/traffic/<traffic>.json``, its generator is
-``chipbench/traffic/kinds/<kind>.py`` and a metric is
-``chipbench/metrics/<name>.py``.  A new cell, mix or metric is a new file.
+``chipbench/traffic/kinds/<kind>.py``, a metric is
+``chipbench/metrics/<name>.py`` and a model family (its weights, program
+configuration, reference forward and FLOPs) is
+``chipbench/models/<family>.py``.  A new cell, mix, metric or architecture
+is a new file.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from dataclasses import dataclass
@@ -79,6 +83,21 @@ def load_kind(kind: str, root: Path = ROOT):
     """The generator module of a traffic kind."""
     return _load_file(Path(root) / PKG / "traffic" / "kinds" / f"{kind}.py",
                       f"{PKG}_kind_{kind}")
+
+
+def family(config: dict) -> str:
+    """The model family a configuration file names (``dense`` by default)."""
+    return config.get("family", "dense")
+
+
+@functools.lru_cache(maxsize=None)
+def load_model(name: str, root: Path = ROOT):
+    """The family module ``name``: ``arch_config(config)``,
+    ``make_params(config, seed)``, ``reference_logits(config, bits)``,
+    ``prefill_flops(config, p)`` and ``decode_flops(config, context)``.
+    Loaded once per root, so its compiled makers are built once."""
+    return _load_file(Path(root) / PKG / "models" / f"{name}.py",
+                      f"{PKG}_model_{name.replace('.', '_')}")
 
 
 def load_roofline(kernel: str, root: Path = ROOT):

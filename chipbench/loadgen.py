@@ -32,6 +32,7 @@ class Req:
     finish: str | None = None
     error: str | None = None
     warmup: bool = False
+    session: tuple | None = None  # (client, session, turn) of a conversation
 
     @property
     def completed(self) -> bool:
@@ -42,7 +43,8 @@ class Req:
                 "max_tokens": self.max_tokens, "due": self.due,
                 "sent": self.sent, "status": self.status, "times": self.times,
                 "n_tokens": len(self.tokens), "finish": self.finish,
-                "error": self.error, "warmup": self.warmup}
+                "error": self.error, "warmup": self.warmup,
+                "session": self.session}
 
 
 # ------------------------------------------------------------------ sizes

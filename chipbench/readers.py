@@ -3,6 +3,7 @@
 A reader gets the run's collected data as one dict:
 
   config, traffic     the cell's configuration and traffic mix
+  root, chips         the benchmark's root; the chips the cell runs on
   seconds, t0, t1     the window (host ``perf_counter`` seconds)
   t_start, t_stop     process start; when the harness stopped waiting
   records             one dict per request (loadgen.Req.as_record)

@@ -1,12 +1,10 @@
-"""The plain reference, and the comparison that decides ``correct``.
+"""The comparison with the plain reference that decides ``correct``.
 
-A decoder written out in ``jax.numpy`` at float32 with the matmul precision
-set to ``highest``, taking nothing from the program: the weights are made
-again from the seed by ``weights.make_params``.  The block is the one the
-configuration states (its ``assumed`` list names where it departs from the
-published model): RMSNorm with gain ``1 + w``, rotary embedding on the whole
-head (halves rotated), causal softmax attention, SwiGLU, the output head tied
-to the embedding.
+The reference forward is the configuration's family's
+(``models/<family>.py``: ``reference_logits``), written out in ``jax.numpy``
+at float32 with the matmul precision set to ``highest``, taking nothing from
+the program: the weights are made again from the seed by the family's
+``make_params``.
 
 What is compared: each served request's prompt and served tokens are run
 through the reference once, teacher-forced, and at every served position the
@@ -16,10 +14,9 @@ token the reference ranks first or within rounding of first; the widest gap
 over the sample is the number compared with the configuration's limit.
 
 The control (``control=True``) is the reference computed one precision
-lower, in the program's place: every projection's weights (per output
-channel) and inputs (per token), and the attention keys and values (per
-token and head), rounded to the configuration's ``control_bits``.  At each
-position the token it puts first is read with the same gap.
+lower, in the program's place: the family's forward with its rounding
+(``fq``) at the configuration's ``control_bits``.  At each position the
+token it puts first is read with the same gap.
 """
 from __future__ import annotations
 
@@ -30,8 +27,9 @@ import numpy as np
 ROWS_PER_BLOCK = 4
 
 
-def _fq(x, bits: int, axis: int):
-    """Symmetric round-to-nearest onto ``bits``-bit integers along ``axis``."""
+def fq(x, bits: int, axis: int):
+    """Symmetric round-to-nearest onto ``bits``-bit integers along ``axis``:
+    the control's rounding, which every family's reference applies."""
     import jax.numpy as jnp
     qmax = 2 ** (bits - 1) - 1
     s = jnp.maximum(jnp.max(jnp.abs(x), axis=axis, keepdims=True), 1e-12) / qmax
@@ -39,59 +37,10 @@ def _fq(x, bits: int, axis: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _programs(L, d, H, Hkv, Dh, F, V, theta, eps, bits):
+def _programs(logits):
+    """The gap and the first-ranked token of a family's reference forward."""
     import jax
     import jax.numpy as jnp
-    hi = jax.lax.Precision.HIGHEST
-    f32 = jnp.float32
-
-    def lin(x, w):
-        w = w.astype(f32)
-        if bits:
-            x, w = _fq(x, bits, -1), _fq(w, bits, 0)
-        return jnp.einsum("bsk,kn->bsn", x, w, precision=hi)
-
-    def rms(x, g):
-        return (x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
-                * (1.0 + g.astype(f32)))
-
-    def rope(x, pos):
-        freqs = 1.0 / (theta ** (jnp.arange(0, Dh, 2, dtype=f32) / Dh))
-        ang = pos[:, None].astype(f32) * freqs               # (S, Dh/2)
-        sin, cos = jnp.sin(ang)[:, None], jnp.cos(ang)[:, None]
-        x1, x2 = jnp.split(x, 2, axis=-1)
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-    def logits(params, tokens):
-        B, S = tokens.shape
-        pos = jnp.arange(S)
-        h = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(f32)
-        causal = pos[:, None] >= pos[None, :]
-
-        def layer(h, p):
-            a, f = p["attn"], p["ffn"]
-            x = rms(h, p["attn_norm"])
-            q = rope(lin(x, a["wq"]).reshape(B, S, H, Dh), pos)
-            k = rope(lin(x, a["wk"]).reshape(B, S, Hkv, Dh), pos)
-            v = lin(x, a["wv"]).reshape(B, S, Hkv, Dh)
-            if bits:
-                k, v = _fq(k, bits, -1), _fq(v, bits, -1)
-            k = jnp.repeat(k, H // Hkv, axis=2)
-            v = jnp.repeat(v, H // Hkv, axis=2)
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=hi) * Dh ** -0.5
-            s = jnp.where(causal, s, -jnp.inf)
-            o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v,
-                           precision=hi)
-            h = h + lin(o.reshape(B, S, H * Dh), a["wo"])
-            x = rms(h, p["ffn_norm"])
-            g = lin(x, f["w_gate"])
-            u = lin(x, f["w_up"])
-            return h + lin(jax.nn.silu(g) * u, f["w_down"]), None
-
-        h, _ = jax.lax.scan(layer, h, params["blocks"][0])
-        h = rms(h, params["final_norm"])
-        emb = params["embed"]["embedding"].astype(f32)
-        return jnp.einsum("bsd,vd->bsv", h, emb, precision=hi)
 
     @jax.jit
     def gaps(params, tokens, targets):
@@ -126,14 +75,13 @@ def _rows(seqs, S: int):
     return tokens, targets, mask
 
 
-def compare(params, config: dict, seqs: list, control: bool = False) -> dict:
+def compare(model, params, config: dict, seqs: list,
+            control: bool = False) -> dict:
+    """Gaps of the served ``seqs`` under ``model``'s reference (the family
+    module, ``bench.load_model``); with ``control`` also the control's."""
     import jax
 
-    from chipbench.weights import dims
-    m = dims(config)
-    key = (m["L"], m["d"], m["H"], m["Hkv"], m["Dh"], m["F"], m["V"],
-           m["theta"], m["eps"])
-    gaps_fn, _ = _programs(*key, 0)
+    gaps_fn, _ = _programs(model.reference_logits(config, 0))
     S = int(config["serve"]["max_len"])
     tokens, targets, mask = _rows(seqs, S)
     out = {"n_seqs": len(seqs), "n_tokens": int(mask.sum())}
@@ -154,7 +102,8 @@ def compare(params, config: dict, seqs: list, control: bool = False) -> dict:
         out.update(gap_max=float(gap.max()), gap_mean=float(gap.mean()),
                    argmax_agree=float(agree.mean()))
         if control:
-            _, top_fn = _programs(*key, int(config["check"]["control_bits"]))
+            _, top_fn = _programs(model.reference_logits(
+                config, int(config["check"]["control_bits"])))
             ctrl = np.concatenate(blocks(top_fn, tokens))
             cg = np.concatenate([g for g, _ in blocks(gaps_fn, tokens, ctrl)])
             cg = cg[mask]

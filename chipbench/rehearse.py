@@ -31,7 +31,8 @@ def rehearse(config: dict, slots: int) -> dict:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from chipbench.server import arch_config, serve_config
+    from chipbench import bench
+    from chipbench.server import serve_config
     from repro.kernels import ops
     from repro.models import build_model
     from repro.models.linops import quantize_param_tree
@@ -50,7 +51,7 @@ def rehearse(config: dict, slots: int) -> dict:
                 lambda: self.bundle.init_caches(self.slots, self.max_len,
                                                 self.mem_len))
 
-    cfg = arch_config(config)
+    cfg = bench.load_model(bench.family(config)).arch_config(config)
     sc = dataclasses.replace(serve_config(config), slots=slots)
     params = jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0))
     if sc.quantize_weights:

@@ -137,12 +137,19 @@ class Ctx:
 def log_window(ctx, reqs) -> None:
     """What the window did, for the reader of a run's log: the server's
     counters over the window and the tokens received in each second."""
-    keys = ("prefill_batches", "prefill_requests", "decode_steps",
-            "decode_tokens", "completed", "failed", "preemptions",
-            "prefix_hits", "cow_copies")
+    keys = ("prefill_batches", "prefill_requests", "prefill_tokens",
+            "decode_steps", "decode_tokens", "completed", "failed",
+            "preemptions", "prefix_hits", "prefix_shared_pages",
+            "cow_copies", "shed")
     s0, s1 = ctx.snap0["stats"], ctx.snap1["stats"]
     log("window counters: " + json.dumps(
         {k: s1[k] - s0[k] for k in keys if k in s0 and k in s1}))
+    turns = [r.session for r in reqs if r.session is not None
+             and ctx.t0 <= r.due < ctx.t0 + ctx.seconds]
+    if turns:
+        log(f"conversations: {len(turns)} turns sent in the window, "
+            f"{len({(c, s) for c, s, _ in turns})} sessions, "
+            f"{sum(t == 0 for _, _, t in turns)} of them opened there")
     per_s = [0] * int(ctx.seconds + 1)
     for r in reqs:
         for t in r.times:
@@ -194,8 +201,8 @@ async def run_cell(args, cell) -> dict:
     finally:
         await child.close()
     run = {"cell": cell.name, "config": cell.config, "traffic": cell.traffic,
-           "seconds": ctx.seconds, "t_start": T_START, "t0": ctx.t0,
-           "t1": ctx.t1, "t_stop": t_stop,
+           "root": cell.root, "chips": cell.chips, "seconds": ctx.seconds,
+           "t_start": T_START, "t0": ctx.t0, "t1": ctx.t1, "t_stop": t_stop,
            "records": [r.as_record() for r in reqs],
            "snap0": ctx.snap0, "snap1": ctx.snap1,
            "trace": check.get("trace"), "device": ready["device"],

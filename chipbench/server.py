@@ -2,13 +2,16 @@
 
 Started by ``run.py`` with one JSON argument (the cell's configuration, the
 seed, the prefill buckets its traffic uses).  It checks the device, makes
-the weights on the device from the seed, builds the engine with
-``build_engine(ServeConfig(...))``, wraps it in ``ServeService`` behind
-``HttpFrontend``, warms up the cell's shapes, and prints a ready line.  Then
-it answers commands, one JSON object per stdin line:
+the weights on the device from the seed with the configuration's family
+(``models/<family>.py``), builds the engine with
+``build_engine(ServeConfig(...))`` on the chip layout the configuration
+states, wraps it in ``ServeService`` behind ``HttpFrontend``, warms up the
+cell's shapes, and prints a ready line.  Then it answers commands, one JSON
+object per stdin line:
 
   mark         {"what": "open" | "close"}: the window's edges (compile
-               count, device memory in use and its peak so far)
+               count, device memory in use and its peak so far, on the
+               fullest of the cell's chips)
   trace_start  start a ``jax.profiler`` trace into a private temp directory
   trace_stop   stop it; the reduction runs later, at ``check``
   check        {"seqs": [...], "control": bool}: stop serving, read the
@@ -25,7 +28,6 @@ import time
 T_START = time.perf_counter()
 
 import asyncio  # noqa: E402
-import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -63,18 +65,26 @@ class Server:
     def setup(self) -> None:
         import jax
 
-        from chipbench import device, weights
-        self.device = device.require_chip(int(self.spec["chips"]),
-                                          Path(self.spec["root"]))
+        from chipbench import bench, device, weights
+        chips = int(self.spec["chips"])
+        root = Path(self.spec["root"])
+        self.device = device.require_chip(chips, root)
+        self.chips = jax.devices()[:chips]
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.monitoring.register_event_duration_secs_listener(self._on_event)
 
         from repro.serve import HttpFrontend, ServeService, build_engine
-        self.cfg = arch_config(self.config)
-        params = weights.make_params(self.config, self.seed)
+        self.model = bench.load_model(bench.family(self.config), root)
+        self.cfg = self.model.arch_config(self.config)
+        replicas, shards = layout(self.config, chips)
+        mesh = None
+        if replicas * shards > 1:
+            from repro.launch.mesh import make_serve_mesh
+            mesh = make_serve_mesh(replicas, shards)
+        params = self.model.make_params(self.config, self.seed)
         weights.check_layout(params, self.cfg)
-        eng = build_engine(serve_config(self.config), cfg=self.cfg,
+        eng = build_engine(serve_config(self.config, mesh), cfg=self.cfg,
                            params=params)
         # the engine holds what it serves (for PDQ, its quantized copy)
         del params
@@ -120,19 +130,31 @@ class Server:
         if event == COMPILE_EVENT:
             self.compiles += 1
 
+    def memory_stats(self) -> dict:
+        """The memory counters of the fullest of the cell's chips (by its
+        peak so far), with every chip's in the log."""
+        per = [d.memory_stats() or {} for d in self.chips]
+        if len(per) > 1:
+            print("[server] device memory per chip (in use, peak): " + ", ".join(
+                f"{st.get('bytes_in_use')} {st.get('peak_bytes_in_use')}"
+                for st in per), file=sys.stderr, flush=True)
+        return max(per, key=lambda st: (st.get("peak_bytes_in_use") or 0,
+                                        st.get("bytes_in_use") or 0))
+
     # ---------------------------------------------------------- commands
     def serve(self) -> None:
         import jax
         self.reply(port=self.fe.port, device=self.device,
                    setup_s=time.perf_counter() - T_START,
                    buckets=list(self.eng.buckets), slots=self.eng.slots,
+                   replicas=self.eng.n_replicas,
                    compiles=self.compiles)
         for line in sys.stdin:
             msg = json.loads(line)
             cmd = msg["cmd"]
             if cmd == "mark":
                 what = msg["what"]
-                stats = jax.devices()[0].memory_stats() or {}
+                stats = self.memory_stats()
                 self.memory[what] = stats
                 if what == "open":
                     self.window_compiles = self.compiles
@@ -174,11 +196,9 @@ class Server:
                                "back to fp")
 
     def check(self, msg: dict) -> dict:
-        import jax
-
-        from chipbench import reference, weights
+        from chipbench import reference
         self.stop_serving()
-        peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
+        peak = self.memory_stats().get("peak_bytes_in_use")
         eng = self.eng
         eng.caches = eng._prefill_pool = eng.params = None
         self.eng = self.svc = self.fe = None
@@ -197,35 +217,38 @@ class Server:
                                                    self.trace_t)
             shutil.rmtree(self.trace_dir, ignore_errors=True)
         t0 = time.perf_counter()
-        params = weights.make_params(self.config, self.seed)
-        res = reference.compare(params, self.config, msg["seqs"],
+        params = self.model.make_params(self.config, self.seed)
+        res = reference.compare(self.model, params, self.config, msg["seqs"],
                                 control=bool(msg.get("control")))
         out.update(res, seconds=time.perf_counter() - t0)
         return out
 
 
-def arch_config(config: dict):
-    """The program's model configuration, with every width the file states."""
-    from repro.configs import get_config
-    base = get_config(config["arch"])
-    m = {"n_layers": config["num_hidden_layers"],
-         "d_model": config["hidden_size"],
-         "n_heads": config["num_attention_heads"],
-         "n_kv_heads": config["num_key_value_heads"],
-         "head_dim": config["hidden_size"] // config["num_attention_heads"],
-         "d_ff": config["intermediate_size"],
-         "vocab": config["vocab_size"],
-         "rope_theta": float(config["rope_theta"]),
-         "norm_eps": float(config["norm_eps"]),
-         "dtype": config["torch_dtype"],
-         "quant_kv": config["serve"]["kv"]}
-    return dataclasses.replace(base, **m).validate()
+def layout(config: dict, chips: int) -> tuple[int, int]:
+    """(replicas, chips per replica) of the configuration on the cell's
+    ``chips``: ``serve.replicas`` (1 by default) data-parallel replicas, the
+    chips left over splitting each replica's model.  A layout that does not
+    use exactly the cell's chips is an error, never a fallback to one."""
+    s = config["serve"]
+    replicas = int(s.get("replicas", 1))
+    if replicas < 1 or chips % replicas:
+        raise ValueError(f"{replicas} replicas do not divide the cell's "
+                         f"{chips} chips")
+    if int(s["slots"]) % replicas:
+        raise ValueError(f"{s['slots']} slots do not split over {replicas} "
+                         "replicas")
+    return replicas, chips // replicas
 
 
-def serve_config(config: dict):
+def serve_config(config: dict, mesh=None):
+    """The engine the configuration states; on a ``mesh`` the cell's
+    ``slots`` (every decode row of the cell) split over its replicas."""
     from repro.serve import ServeConfig
     s = config["serve"]
+    spr = (int(s["slots"]) // int(mesh.shape["data"]) if mesh is not None
+           else None)
     return ServeConfig(arch=config["arch"], reduced=False,
+                       mesh=mesh, slots_per_replica=spr,
                        slots=int(s["slots"]), max_len=int(s["max_len"]),
                        buckets=tuple(int(b) for b in s["buckets"]),
                        decode_steps=int(s["decode_steps"]),

@@ -2,9 +2,10 @@
 
     python cpu_server.py <fault> <spec json>
 
-The device check is replaced by a stand-in that accepts the CPU (the
-harness's own check refuses it).  Faults, each planted where the timed path
-produces its result:
+The device check is replaced by a stand-in that accepts the CPU's devices
+(the harness's own check refuses them; ``XLA_FLAGS`` with
+``--xla_force_host_platform_device_count`` gives a cell of several chips
+as many).  Faults, each planted where the timed path produces its result:
 
   none    the program as it is
   token   every sampled token moved to the next id (the sampler)
@@ -15,7 +16,11 @@ import sys
 
 
 def fake_chip(chips, root):
-    return {"platform": "cpu", "kind": "TPU v5 lite", "count": 1}
+    import jax
+    n = len(jax.devices())
+    if n < chips:
+        raise RuntimeError(f"the cell needs {chips} devices, found {n}")
+    return {"platform": "cpu", "kind": "TPU v5 lite", "count": n}
 
 
 def plant(fault: str) -> None:

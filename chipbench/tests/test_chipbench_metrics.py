@@ -19,8 +19,10 @@ def rec(due, times, prompt_len=10, status=200, warmup=False, finish="complete"):
             "warmup": warmup}
 
 
-def run_of(records, stats0=None, stats1=None, m0=None, m1=None, trace=None):
-    return {"config": CONFIG, "seconds": 10.0, "t_start": 80.0, "t0": 100.0,
+def run_of(records, stats0=None, stats1=None, m0=None, m1=None, trace=None,
+           chips=1):
+    return {"config": CONFIG, "root": bench.ROOT, "chips": chips,
+            "seconds": 10.0, "t_start": 80.0, "t0": 100.0,
             "t1": 110.0, "t_stop": 125.0, "records": records,
             "snap0": {"stats": stats0 or {}, "metrics": m0 or {}},
             "snap1": {"stats": stats1 or {}, "metrics": m1 or {}},
@@ -99,12 +101,29 @@ def test_swiglu_roofline_from_call_signatures():
         pytest.approx(want)
 
 
-def test_step_mfu_counts_prompts_and_tokens_in_the_window():
+@pytest.mark.parametrize("chips", [1, 4])
+def test_step_mfu_counts_prompts_and_tokens_in_the_window(chips):
     # prompt of 10 whose first token came in the window, then 2 tokens
-    # (contexts 11, 12); a second request's token outside the window
+    # (contexts 11, 12); a second request's token outside the window; the
+    # peak is the cell's chips' together
     rs = [rec(99.0, [100.2, 100.3, 100.4]), rec(80.0, [90.0, 111.0])]
     L, n_layer, hd, head = 24, 51380224, 2048, 205520896
     prefill = 2 * L * n_layer * 10 + 4 * L * hd * 55 + 2 * head
     decode = sum(2 * L * n_layer + 4 * L * hd * c + 2 * head for c in (11, 12))
-    want = 100 * (prefill + decode) / (10.0 * 393e12)
-    assert read("step_mfu", run_of(rs)) == pytest.approx(want)
+    want = 100 * (prefill + decode) / (10.0 * chips * 393e12)
+    assert read("step_mfu", run_of(rs, chips=chips)) == pytest.approx(want)
+
+
+def test_prefix_hit_share_from_shared_pages():
+    r = run_of([], stats0={"prefill_tokens": 1000, "prefix_shared_pages": 5},
+               stats1={"prefill_tokens": 9000, "prefix_shared_pages": 65})
+    r["config"] = dict(CONFIG, serve=dict(CONFIG["serve"], page_size=64))
+    # 60 pages of 64 tokens from other requests' landing, of 8000 admitted
+    assert read("prefix_hit_share", r) == pytest.approx(100 * 3840 / 8000)
+    idle = run_of([], stats0={"prefill_tokens": 9, "prefix_shared_pages": 0},
+                  stats1={"prefill_tokens": 9, "prefix_shared_pages": 0})
+    assert read("prefix_hit_share", idle) is None
+    # an engine without a paged pool has no such counter
+    unpaged = run_of([], stats0={"prefill_tokens": 0},
+                     stats1={"prefill_tokens": 100})
+    assert read("prefix_hit_share", unpaged) is None

@@ -36,7 +36,7 @@ def test_swiglu_calls_are_told_by_signature(sig, want):
 
 
 def test_model_flops_at_stablelm_widths():
-    mf = bench.load_roofline("model_flops")
+    mf = bench.load_model("dense")
     # layers: 24 x (4 x 2048^2 + 3 x 2048 x 5632) = 1,233,125,376 params;
     # head 2048 x 100,352 = 205,520,896
     assert mf.decode_flops(STABLELM, 100) == 2_896_953_344

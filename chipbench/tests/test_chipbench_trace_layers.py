@@ -10,7 +10,7 @@ from pathlib import Path
 
 import pytest
 
-from chipbench import trace_layers, trace_reduce
+from chipbench import bench, trace_layers, trace_reduce
 
 DATA = Path(__file__).with_name("data")
 OLD = DATA / "trace_v5e_pdq_decode.json.gz"
@@ -155,3 +155,28 @@ def test_the_recorded_trace_carries_the_program_names():
     assert set(old) == {"busy_s", "window_s", "n_ops", "custom_calls",
                         "breakdown"}
     assert old["n_ops"] == 947
+
+
+def test_kv_move_and_idle_in_host_shares_on_the_recorded_trace():
+    ev = _load(NAMED)
+    red = trace_reduce.reduce_all(ev, ev["window_s"])
+    # what trace_reduce gives, with trace_layers' readings beside it
+    assert red["busy_s"] == trace_reduce.reduce_events(
+        ev, ev["window_s"])["busy_s"]
+    assert red["scope_s"] == trace_layers.reduce_layers(ev)["scope_s"]
+    run = {"trace": red}
+    kv = bench.load_metric("kv_move_share").read(run)
+    assert kv == pytest.approx(100 * (0.053704604 + 0.011802898)
+                               / red["busy_s"], rel=1e-6)
+    idle = bench.load_metric("idle_in_host_share").read(run)
+    assert idle == pytest.approx(100 * 0.010611327 / ev["window_s"], rel=1e-6)
+    assert 0 < idle < bench.load_metric("idle_share").read(run)
+
+
+def test_a_trace_without_program_names_reads_no_shares():
+    old = _load(OLD)
+    run = {"trace": trace_reduce.reduce_all(old, old["window_s"])}
+    assert bench.load_metric("kv_move_share").read(run) is None
+    assert bench.load_metric("idle_in_host_share").read(run) is None
+    for name in ("kv_move_share", "idle_in_host_share"):
+        assert bench.load_metric(name).read({"trace": None}) is None

@@ -97,3 +97,92 @@ def test_closed_loop_sends_the_seeds_requests_in_order(seed):
     assert ctx1.t0 is not None and ctx1.snap1["stats"]["pending"] == 0
     assert all(r.warmup == (r.due < ctx1.t0) for r in r1)
     assert any(r.warmup for r in r1) and not all(r.warmup for r in r1)
+
+
+SESSIONS = {"kind": "sessions", "clients_per_slot": 2, "system_prompts": 3,
+            "system_len": 12, "system_zipf": 1.0,
+            "user_len": {"dist": "uniform", "min": 2, "max": 6},
+            "answer_len": {"dist": "uniform", "min": 3, "max": 8},
+            "max_prompt": 48, "warmup_s": 0.2, "stagger_s": 0.01,
+            "pool": 64, "check_sample": 2}
+
+
+def _by_client(reqs):
+    out = {}
+    for r in reqs:
+        out.setdefault(r.session[0], []).append(
+            (r.prompt, r.max_tokens, r.session))
+    return out
+
+
+@pytest.fixture(scope="module")
+def sessions_runs():
+    return {seed: _drive("sessions", SESSIONS, seed, 0.6)
+            for seed in (7, 2**31 + 11)}
+
+
+def test_sessions_are_seeded(sessions_runs):
+    seed = 2**31 + 11
+    _, again = _drive("sessions", SESSIONS, seed, 0.6)
+    a, b = _by_client(sessions_runs[seed][1]), _by_client(again)
+    assert sorted(a) == sorted(b) == [0, 1, 2, 3]
+    for c in a:
+        n = min(len(a[c]), len(b[c]))
+        assert n > 3
+        # a client's conversations do not depend on how clients interleave
+        assert a[c][:n] == b[c][:n]
+    # another seed sends other tokens in the same sizes: the same work
+    other = _by_client(sessions_runs[7][1])
+    for c in a:
+        n = min(len(a[c]), len(other[c]))
+        assert other[c][0][0] != a[c][0][0]
+        assert ([(len(p), m, sess) for p, m, sess in other[c][:n]]
+                == [(len(p), m, sess) for p, m, sess in a[c][:n]])
+
+
+def test_every_session_prompt_stays_within_the_largest_bucket(sessions_runs):
+    lo, hi = bench.load_kind("sessions").prompt_range(SESSIONS)
+    assert (lo, hi) == (14, 48)
+    for _, reqs in sessions_runs.values():
+        assert reqs and all(lo <= len(r.prompt) <= hi for r in reqs)
+
+
+def test_a_turn_extends_the_previous_prompt_and_answer(sessions_runs):
+    for _, reqs in sessions_runs.values():
+        by = {r.session: r for r in reqs}
+        later = [r for r in reqs if r.session[2] > 0]
+        assert len(later) > 10
+        for r in later:
+            c, s, t = r.session
+            prev = by[(c, s, t - 1)]
+            history = prev.prompt + prev.tokens
+            assert prev.completed and r.prompt[:len(history)] == history
+            assert 2 <= len(r.prompt) - len(history) <= 6
+        # a session ends only where its next turn might not fit (a user
+        # turn is up to 6 tokens)
+        last = {}
+        for r in reqs:
+            last[r.session[:2]] = r
+        for (c, s), r in last.items():
+            if (c, s + 1, 0) in by and r.completed:
+                assert len(r.prompt) + len(r.tokens) + 6 > 48
+
+
+def test_system_prompts_repeat_across_sessions(sessions_runs):
+    for seed, (_, reqs) in sessions_runs.items():
+        first = [tuple(r.prompt[:12]) for r in reqs if r.session[2] == 0]
+        assert len(first) > 3 * len(set(first))
+        assert len(set(first)) <= 3
+        # Zipf: the first system prompt opens the most sessions
+        counts = sorted(first.count(p) for p in set(first))
+        assert counts[-1] > counts[0]
+
+
+def test_zipf_picks_are_one_multiset_in_seeded_orders():
+    kind = bench.load_kind("sessions")
+    a = kind.zipf_picks(8, 1.0, 1000, np.random.default_rng(1))
+    b = kind.zipf_picks(8, 1.0, 1000, np.random.default_rng(2))
+    assert sorted(a) == sorted(b) and not (a == b).all()
+    w = 1 / np.arange(1, 9)
+    want = 1000 * w / w.sum()
+    assert np.abs(np.bincount(a, minlength=8) - want).max() <= 1

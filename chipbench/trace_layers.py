@@ -1,5 +1,5 @@
 """The program's own names in a ``jax.profiler`` trace (the reduction needs
-no JAX; ``events_from_xplane`` reads the file).
+no JAX; ``events_from_xplane``, which reads the file, needs it).
 
 ``trace_reduce`` names what it finds by HLO instruction and by the
 shortest host event.  The program adds names of its own: every
@@ -147,8 +147,9 @@ def op_names(data: bytes) -> dict[str, dict[str, str]]:
 
 
 def events_from_xplane(path: str) -> dict:
-    """``trace_reduce.events_from_xplane``'s lists plus
-    ``{"scopes": {plane: [program name of each op]}}``."""
+    """``{"devices": {plane: [[op text, start_ns, dur_ns], ...]},
+    "host": [[name, start_ns, dur_ns], ...],
+    "scopes": {plane: [program name of each op]}}`` from one ``.xplane.pb``."""
     import jax
     with open(path, "rb") as f:
         names = op_names(f.read())
@@ -220,13 +221,3 @@ def reduce_layers(ev: dict) -> dict | None:
     # a program that writes no spans leaves nothing to overlap with
     return {"host_spans": spans, "idle_in_host_s": idle / n if work else None,
             "scope_s": {k: v / n for k, v in sorted(scope_s.items())}}
-
-
-def reduce_dir(trace_dir: str) -> dict | None:
-    import glob
-    import os
-    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                      recursive=True)
-    if not files:
-        return None
-    return reduce_layers(events_from_xplane(files[0]))

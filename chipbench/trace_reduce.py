@@ -1,8 +1,10 @@
 """From a ``jax.profiler`` trace to the numbers the per-layer metrics read.
 
-``events_from_xplane`` turns the trace file into plain lists (the one place
-that knows the profiler's format); ``reduce_events`` works on those lists
-only, so a recorded trace can be checked without JAX.
+``trace_layers.events_from_xplane`` turns the trace file into plain lists
+(the one place that knows the profiler's format); ``reduce_events`` works on
+those lists only, so a recorded trace can be checked without JAX.
+``reduce_all`` adds ``trace_layers.reduce_layers``'s readings of the
+program's own names (``host_spans``, ``idle_in_host_s``, ``scope_s``).
 
 On a TPU the op line ("XLA Ops") holds every HLO instruction that ran, named
 by its HLO text, with control-flow ops (a ``while`` of a scan) spanning the
@@ -34,27 +36,6 @@ def compact(hlo: str, limit: int = 400) -> str:
     hlo = hlo.split(", custom_call_target=")[0]
     hlo = _LAYOUT.sub("", hlo)
     return hlo[:limit]
-
-
-def events_from_xplane(path: str) -> dict:
-    """{"devices": {plane: [[op text, start_ns, dur_ns], ...]},
-    "host": [[name, start_ns, dur_ns], ...]} from one ``.xplane.pb``."""
-    import jax
-    pd = jax.profiler.ProfileData.from_file(path)
-    devices, host = {}, []
-    for plane in pd.planes:
-        if plane.name.startswith("/device:TPU:"):
-            ops = []
-            for line in plane.lines:
-                if line.name == OP_LINE:
-                    ops += [[compact(ev.name), float(ev.start_ns),
-                             float(ev.duration_ns)] for ev in line.events]
-            devices[plane.name] = ops
-        elif plane.name.startswith("/host:CPU"):
-            for line in plane.lines:
-                host += [[ev.name, float(ev.start_ns), float(ev.duration_ns)]
-                         for ev in line.events if ev.duration_ns > 0]
-    return {"devices": devices, "host": host}
 
 
 def _union(intervals):
@@ -127,9 +108,19 @@ def reduce_events(ev: dict, window_s: float) -> dict | None:
                           "idle_gaps": idle}}
 
 
+def reduce_all(ev: dict, window_s: float) -> dict | None:
+    """``reduce_events`` with ``trace_layers.reduce_layers`` merged in."""
+    from chipbench import trace_layers
+    out = reduce_events(ev, window_s)
+    if out is not None:
+        out.update(trace_layers.reduce_layers(ev))
+    return out
+
+
 def reduce_dir(trace_dir: str, window_s: float) -> dict | None:
+    from chipbench import trace_layers
     files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                       recursive=True)
     if not files:
         return None
-    return reduce_events(events_from_xplane(files[0]), window_s)
+    return reduce_all(trace_layers.events_from_xplane(files[0]), window_s)
