@@ -5,6 +5,7 @@ import importlib
 
 _MODULES = {
     "deepseek-v2-236b": "deepseek_v2_236b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "arctic-480b": "arctic_480b",
     "mamba2-2.7b": "mamba2_2p7b",
     "seamless-m4t-medium": "seamless_m4t_medium",

@@ -121,23 +121,30 @@ def pdq_guard(enable: bool = True):
         _PDQ_GUARD = prev
 
 
-class PdqTelemetryCollector:
-    """Trace-time accumulator for quantization-health scalars.
+# the summary every launch returns and engines unpack, field by field:
+# guard fallbacks, int8 clip hits, outputs checked for clipping, and the
+# routed (row, expert) assignments MoE layers gave the experts they hold
+TEL_FIELDS = ("fallbacks", "clip_hits", "clip_total", "expert_rows")
+PDQ_TEL_WIDTH = len(TEL_FIELDS)
 
-    While ``pdq_telemetry`` is active, every PDQ projection appends jnp
-    SCALARS here as it traces: the guard's fallback-activation flag (the
-    same fused finiteness reduction the guard's ``cond`` already
-    computes), int8 clip-saturation hit counts and the elements checked.
-    ``summary()`` folds them into ONE (3,) float32 the launch returns
-    alongside its tokens - the host reads it in the existing token
-    gather, so quantization health costs zero extra round-trips and adds
-    no pallas_calls (pure jnp reductions; the kernel census is pinned
+
+class PdqTelemetryCollector:
+    """Trace-time accumulator for serving-health scalars.
+
+    While ``pdq_telemetry`` is active, every PDQ projection records jnp
+    SCALARS here as it traces (``tel_count``): the guard's
+    fallback-activation flag (the same fused finiteness reduction the
+    guard's ``cond`` already computes), int8 clip-saturation hit counts
+    and the elements checked; an MoE layer records the routed rows its
+    held experts took.  ``summary()`` folds them into ONE
+    (``PDQ_TEL_WIDTH``,) float32 in ``TEL_FIELDS`` order that the launch
+    returns alongside its tokens - the host reads it in the existing
+    token gather, so it costs zero extra round-trips and adds no
+    pallas_calls (pure jnp reductions; the kernel census is pinned
     unchanged)."""
 
     def __init__(self):
-        self.fallbacks: list = []
-        self.clip_hits: list = []
-        self.clip_total: list = []
+        self.counts: dict[str, list] = {f: [] for f in TEL_FIELDS}
 
     def summary(self):
         def tot(xs):
@@ -146,12 +153,14 @@ class PdqTelemetryCollector:
                 acc = acc + x
             return acc
 
-        return jnp.stack([tot(self.fallbacks), tot(self.clip_hits),
-                          tot(self.clip_total)])
+        return jnp.stack([tot(self.counts[f]) for f in TEL_FIELDS])
 
 
-# the summary layout engines unpack: [fallbacks, clip_hits, clip_total]
-PDQ_TEL_WIDTH = 3
+def tel_count(field: str, n) -> None:
+    """Add ``n`` to the launch's ``field`` (one of ``TEL_FIELDS``); a
+    no-op outside ``pdq_telemetry``."""
+    if _PDQ_TEL is not None:
+        _PDQ_TEL.counts[field].append(jnp.asarray(n, jnp.float32))
 
 
 @contextlib.contextmanager
@@ -173,7 +182,7 @@ def pdq_telemetry_scan(body, init, xs):
     """``lax.scan(body, init, xs)`` for a body that traces PDQ projections
     (the model's layer-stacked blocks).  Collector scalars traced inside a
     scan body must not escape it: each iteration collects into its own
-    collector, returns the (3,) summary as a scan output, and the
+    collector, returns its summary as a scan output, and the
     layer-summed summary is recorded into the enclosing collector."""
     outer = _PDQ_TEL
 
@@ -185,9 +194,8 @@ def pdq_telemetry_scan(body, init, xs):
     carry, (ys, tel) = jax.lax.scan(wrapped, init, xs)
     if outer is not None:
         tel = jnp.sum(tel, axis=0)
-        outer.fallbacks.append(tel[0])
-        outer.clip_hits.append(tel[1])
-        outer.clip_total.append(tel[2])
+        for i, f in enumerate(TEL_FIELDS):
+            outer.counts[f].append(tel[i])
     return carry, ys
 
 
@@ -197,18 +205,17 @@ def _tel_clip(y, lo, hi):
     on the representable boundary, which the rate treats the same)."""
     if _PDQ_TEL is None:
         return
-    hits = jnp.sum(((y <= lo) | (y >= hi)).astype(jnp.float32))
-    _PDQ_TEL.clip_hits.append(hits)
-    _PDQ_TEL.clip_total.append(jnp.float32(y.size))
+    tel_count("clip_hits", jnp.sum(((y <= lo) | (y >= hi)).astype(jnp.float32)))
+    tel_count("clip_total", y.size)
 
 
 def _tel_clip_q(y_q):
     """Int8-out flavor: saturation is the grid's edge codes."""
     if _PDQ_TEL is None:
         return
-    hits = jnp.sum(((y_q == 127) | (y_q == -128)).astype(jnp.float32))
-    _PDQ_TEL.clip_hits.append(hits)
-    _PDQ_TEL.clip_total.append(jnp.float32(y_q.size))
+    tel_count("clip_hits",
+              jnp.sum(((y_q == 127) | (y_q == -128)).astype(jnp.float32)))
+    tel_count("clip_total", y_q.size)
 
 
 @contextlib.contextmanager
@@ -242,7 +249,7 @@ def _guard_pdq(y, x, w_q, scale, out_dtype):
     if _PDQ_TEL is not None:
         # the fallback-activation count rides the SAME fused reduction the
         # cond consumes: telemetry reuses it, costing nothing extra
-        _PDQ_TEL.fallbacks.append(1.0 - ok.astype(jnp.float32))
+        tel_count("fallbacks", 1.0 - ok.astype(jnp.float32))
     return jax.lax.cond(ok,
                         lambda: y,
                         lambda: _fp_dequant_matmul(x, w_q, scale, out_dtype))
@@ -271,7 +278,7 @@ def _norm_row(a, M, dtype):
 
 def w8a8_matmul(x_q, w_q, s_x, z_x, s_w, s_out=None, z_out=None, *,
                 colsum=None, fp_range=None, out_dtype=jnp.float32,
-                block=(128, 128, 128)):
+                block=(128, 128, 128), name: str = "w8a8_matmul"):
     """y = s_x*s_w*(x_q @ w_q - z_x*colsum); requantized int8 iff s_out given.
 
     x_q: (..., K) int8; w_q: (K, N) int8. s_x/z_x: scalar, (...) or
@@ -285,6 +292,8 @@ def w8a8_matmul(x_q, w_q, s_x, z_x, s_w, s_out=None, z_out=None, *,
     (..., N // bn) with bn the N block - which gives each 128-lane output
     segment of a grouped matmul its own surrogate grid (requires N to be a
     multiple of bn; see ``pdq_dense_grouped``).
+
+    ``name`` is the kernel's name in a trace.
     """
     lead = x_q.shape[:-1]
     K = x_q.shape[-1]
@@ -359,7 +368,7 @@ def w8a8_matmul(x_q, w_q, s_x, z_x, s_w, s_out=None, z_out=None, *,
         _pad_to(so, **pads, value=1.0), _pad_to(zo, **pads),
         _pad_to(lo, **pads), _pad_to(hi, **pads),
         requant=requant, fp_clamp=fp_clamp, per_nblock=per_nblock,
-        out_dtype=out_dtype, block=block, interpret=_interpret(),
+        out_dtype=out_dtype, block=block, interpret=_interpret(), name=name,
     )
     return y[:M, :N].reshape(*lead, N)
 
@@ -418,7 +427,7 @@ def pdq_interval(wrec, s1, s2):
 
 
 def pdq_dense(x, wrec, *, out="fp", out_dtype=None, block=(128, 128, 128),
-              prologue_block=(128, 512)):
+              prologue_block=(128, 512), name: str = "w8a8_matmul"):
     """The fused PDQ serving-path dense layer: one prologue + one matmul.
 
     ``wrec`` is a weight record from ``models.linops.quantize_weight``:
@@ -440,15 +449,16 @@ def pdq_dense(x, wrec, *, out="fp", out_dtype=None, block=(128, 128, 128),
     if out == "int8":
         y_q = w8a8_matmul(x_q, wrec["q"], s_x, 0, wrec["scale"],
                           s_out, z_out.astype(jnp.int32),
-                          colsum=wrec["colsum"], block=block)
+                          colsum=wrec["colsum"], block=block, name=name)
         _tel_clip_q(y_q)
         return y_q, s_out, z_out.astype(jnp.int32)
     return pdq_dense_from_prologue(x, x_q, s_x, s1, s2, wrec,
-                                   out_dtype=out_dtype, block=block)
+                                   out_dtype=out_dtype, block=block,
+                                   name=name)
 
 
 def pdq_dense_from_prologue(x, x_q, s_x, s1, s2, wrec, *, out_dtype=None,
-                            block=(128, 128, 128)):
+                            block=(128, 128, 128), name: str = "w8a8_matmul"):
     """``pdq_dense(out='fp')`` with the prologue already computed upstream.
 
     The serving decode path fuses the wo projection's prologue into the
@@ -478,7 +488,8 @@ def pdq_dense_from_prologue(x, x_q, s_x, s1, s2, wrec, *, out_dtype=None,
         sc_l = _tp_cols(wrec["scale"], Nl, idx, 0)
         y = w8a8_matmul(x_q, wq_l, s_x, 0, sc_l,
                         colsum=_tp_cols(wrec["colsum"], Nl, idx, 1),
-                        fp_range=(lo_g, hi_g), out_dtype=out_dtype, block=block)
+                        fp_range=(lo_g, hi_g), out_dtype=out_dtype, block=block,
+                        name=name)
         # telemetry counts this shard's columns; the engine psums the
         # collector summary over the mesh to recover fleet-wide counts
         _tel_clip(y, lo_g, hi_g)
@@ -489,13 +500,14 @@ def pdq_dense_from_prologue(x, x_q, s_x, s1, s2, wrec, *, out_dtype=None,
         return jax.lax.all_gather(y, ax, axis=y.ndim - 1, tiled=True)
     y = w8a8_matmul(x_q, wrec["q"], s_x, 0, wrec["scale"],
                     colsum=wrec["colsum"], fp_range=(lo_g, hi_g),
-                    out_dtype=out_dtype, block=block)
+                    out_dtype=out_dtype, block=block, name=name)
     _tel_clip(y, lo_g, hi_g)
     return _guard_pdq(y, x, wrec["q"], wrec["scale"], out_dtype)
 
 
 def pdq_dense_grouped(x, grec, *, out="fp", out_dtype=None,
-                      block=(128, 128, 128), prologue_block=(128, 512)):
+                      block=(128, 128, 128), prologue_block=(128, 512),
+                      name: str = "w8a8_matmul"):
     """Grouped PDQ dense: ONE prologue + ONE wide W8A8 matmul for every
     projection consuming the same input (DESIGN.md "Grouped execution").
 
@@ -532,7 +544,7 @@ def pdq_dense_grouped(x, grec, *, out="fp", out_dtype=None,
     if out == "int8":
         y_q = w8a8_matmul(x_q, grec["q"], s_x, 0, grec["scale"],
                           blockwise(s_out), blockwise(z_out).astype(jnp.int32),
-                          colsum=grec["colsum"], block=block)
+                          colsum=grec["colsum"], block=block, name=name)
         _tel_clip_q(y_q)
         ys = tuple(y_q[..., o:o + n] for o, n in bounds)
         return ys, s_out, z_out.astype(jnp.int32)
@@ -553,7 +565,7 @@ def pdq_dense_grouped(x, grec, *, out="fp", out_dtype=None,
         y = w8a8_matmul(x_q, wq_l, s_x, 0, sc_l,
                         colsum=_tp_cols(grec["colsum"], Nl, idx, 1),
                         fp_range=(lo_l, hi_l),
-                        out_dtype=out_dtype, block=block)
+                        out_dtype=out_dtype, block=block, name=name)
         if _PDQ_TEL is not None:
             _tel_clip(y, jnp.repeat(lo_l, bn, axis=-1),
                       jnp.repeat(hi_l, bn, axis=-1))
@@ -563,7 +575,7 @@ def pdq_dense_grouped(x, grec, *, out="fp", out_dtype=None,
     y = w8a8_matmul(x_q, grec["q"], s_x, 0, grec["scale"],
                     colsum=grec["colsum"],
                     fp_range=(blockwise(lo_g), blockwise(hi_g)),
-                    out_dtype=out_dtype, block=block)
+                    out_dtype=out_dtype, block=block, name=name)
     if _PDQ_TEL is not None:
         _tel_clip(y, jnp.repeat(blockwise(lo_g), bn, axis=-1),
                   jnp.repeat(blockwise(hi_g), bn, axis=-1))

@@ -103,6 +103,7 @@ def w8a8_matmul_p(
     block: tuple[int, int, int] = (128, 128, 128),
     interpret: bool = False,
     out_dtype=jnp.float32,
+    name: str = "w8a8_matmul",
 ) -> jax.Array:
     """Raw pallas call; all dims must already be multiples of the block.
 
@@ -114,6 +115,8 @@ def w8a8_matmul_p(
     s_out/z_out/lo/hi must then be shaped (M, N/bn) and are indexed by the
     N-grid coordinate, giving every 128-lane output segment its own
     surrogate grid (the grouped-projection path).
+
+    ``name`` names the kernel in the HLO and in a profiler trace.
     """
     M, K = x_q.shape
     _, N = w_q.shape
@@ -128,9 +131,9 @@ def w8a8_matmul_p(
     if hi is None:
         hi = jnp.zeros((M, 1 if not per_nblock else N // bn), jnp.float32)
     epi_cols = N // bn if per_nblock else 1
-    for name, op in (("s_out", s_out), ("z_out", z_out), ("lo", lo), ("hi", hi)):
+    for what, op in (("s_out", s_out), ("z_out", z_out), ("lo", lo), ("hi", hi)):
         assert op.shape == (M, epi_cols), (
-            f"{name} must be (M, {epi_cols}) with per_nblock={per_nblock}, "
+            f"{what} must be (M, {epi_cols}) with per_nblock={per_nblock}, "
             f"got {op.shape}")
     n_k = K // bk
     grid = (M // bm, N // bn, n_k)
@@ -155,7 +158,7 @@ def w8a8_matmul_p(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int8 if requant else out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        name="w8a8_matmul",
+        name=name,
         interpret=interpret,
     )(x_q, w_q, s_x, z_x, s_w, colsum, s_out, z_out, lo, hi)
 

@@ -109,8 +109,10 @@ def lower_cell(arch: str, shape_name: str, mesh, variant: str = "baseline"):
     cfg = get_config(arch)
     if variant == "opt" and SHAPES[shape_name].kind == "train":
         cfg = _dc.replace(cfg, remat="save_heavy")
-    if variant == "opt" and SHAPES[shape_name].kind == "decode" and cfg.ssm is None:
-        # PDQ-int8 serving: int8 KV cache + W8A8 weights (paper tie-in)
+    if (variant == "opt" and SHAPES[shape_name].kind == "decode"
+            and cfg.ssm is None and cfg.mla is None):
+        # PDQ-int8 serving: int8 KV cache + W8A8 weights (paper tie-in);
+        # the MLA latent cache has no int8 layout
         cfg = _dc.replace(cfg, quant_kv="dynamic")
     bundle = build_model(cfg)
     sp = SHAPES[shape_name]

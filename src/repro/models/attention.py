@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
-from .layers import apply_rope, dense_init, rms_norm, softcap
+from .layers import (YarnConfig, apply_rope, dense_init, rms_norm, softcap,
+                     yarn_softmax_scale)
 from .linops import is_quantized, is_segment_view, lin, lin_grouped
 
 NEG = -2.0e30
@@ -55,15 +56,18 @@ def chunked_attention(
     q_chunk: int = 512,
     kv_chunk: int = 1024,
     parallel_q: bool = False,
+    scale: float | None = None,
 ) -> jax.Array:
     """Online-softmax attention; scores exist only per (q_chunk x kv_chunk).
 
-    q/k share head_dim Dh; v may have a different head_dim Dv (MLA)."""
+    q/k share head_dim Dh; v may have a different head_dim Dv (MLA).
+    ``scale`` multiplies the scores (default Dh^-0.5)."""
     B, Sq, H, Dh = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     Dv = v.shape[-1]
     G = H // Hkv
-    scale = Dh ** -0.5
+    if scale is None:
+        scale = Dh ** -0.5
     q_chunk = min(q_chunk, Sq)
     kv_chunk = min(kv_chunk, Sk)
     nq, nk = Sq // q_chunk, Sk // kv_chunk
@@ -483,21 +487,32 @@ def cross_memory(p, dims: AttnDims, memory):
 class MLADims:
     d_model: int
     n_heads: int
-    q_lora: int
+    q_lora: int | None                 # None: one wq, no query compression
     kv_lora: int
     qk_nope: int
     qk_rope: int
     v_head: int
     rope_theta: float = 10_000.0
+    yarn: YarnConfig | None = None
+
+    @property
+    def scale(self) -> float:
+        """Softmax scale over the (qk_nope + qk_rope)-wide query-key dot."""
+        return yarn_softmax_scale(self.qk_nope + self.qk_rope, self.yarn)
 
 
 def mla_init(key, m: MLADims, dtype):
     ks = jax.random.split(key, 7)
     H = m.n_heads
+    dq = H * (m.qk_nope + m.qk_rope)
+    if m.q_lora is None:
+        q = {"wq": dense_init(ks[0], m.d_model, dq, dtype)}
+    else:
+        q = {"wq_a": dense_init(ks[0], m.d_model, m.q_lora, dtype),
+             "q_norm": jnp.zeros((m.q_lora,), dtype),
+             "wq_b": dense_init(ks[1], m.q_lora, dq, dtype)}
     return {
-        "wq_a": dense_init(ks[0], m.d_model, m.q_lora, dtype),
-        "q_norm": jnp.zeros((m.q_lora,), dtype),
-        "wq_b": dense_init(ks[1], m.q_lora, H * (m.qk_nope + m.qk_rope), dtype),
+        **q,
         "wkv_a": dense_init(ks[2], m.d_model, m.kv_lora + m.qk_rope, dtype),
         "kv_norm": jnp.zeros((m.kv_lora,), dtype),
         "wk_b": dense_init(ks[3], m.kv_lora, H * m.qk_nope, dtype),
@@ -518,14 +533,18 @@ def mla_init_cache(m: MLADims, batch: int, max_len: int, dtype):
 def _mla_qkv(p, m: MLADims, x, positions):
     B, S, _ = x.shape
     H = m.n_heads
-    # the two input-side low-rank projections share x -> one grouped call
-    qa, kv = lin_grouped(x, (p["wq_a"], p["wkv_a"]))
-    q = lin(rms_norm(qa, p["q_norm"]), p["wq_b"])
+    # the two input-side projections share x -> one grouped call
+    if "wq" in p:
+        q, kv = lin_grouped(x, (p["wq"], p["wkv_a"]))
+    else:
+        qa, kv = lin_grouped(x, (p["wq_a"], p["wkv_a"]))
+        q = lin(rms_norm(qa, p["q_norm"]), p["wq_b"])
     q = q.reshape(B, S, H, m.qk_nope + m.qk_rope)
     q_nope, q_rope = q[..., : m.qk_nope], q[..., m.qk_nope:]
-    q_rope = apply_rope(q_rope, positions, m.rope_theta)
+    q_rope = apply_rope(q_rope, positions, m.rope_theta, m.yarn)
     ckv = rms_norm(kv[..., : m.kv_lora], p["kv_norm"])
-    krope = apply_rope(kv[..., None, m.kv_lora:], positions, m.rope_theta)[..., 0, :]
+    krope = apply_rope(kv[..., None, m.kv_lora:], positions, m.rope_theta,
+                       m.yarn)[..., 0, :]
     return q_nope, q_rope, ckv, krope
 
 
@@ -560,8 +579,10 @@ def mla_apply(p, m: MLADims, x, positions, *, mode: str, cache=None,
             [k_nope, jnp.broadcast_to(cache["krope"][:, :, None],
                                       (B, Sb, H, m.qk_rope))], -1)
         q = jnp.concatenate([q_nope, q_rope], -1)
-        o = chunked_attention(q, k, v, positions, _valid_k_pos(cache["pos"]),
-                              causal=True, q_chunk=S, kv_chunk=Sb)
+        with jax.named_scope("mla_attend"):
+            o = chunked_attention(q, k, v, positions,
+                                  _valid_k_pos(cache["pos"]), causal=True,
+                                  q_chunk=S, kv_chunk=Sb, scale=m.scale)
         return lin(o.reshape(B, S, H * m.v_head), p["wo"]), cache
 
     if mode in ("train", "prefill"):
@@ -571,7 +592,9 @@ def mla_apply(p, m: MLADims, x, positions, *, mode: str, cache=None,
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(krope[:, :, None], (B, S, H, m.qk_rope))], -1)
         q = jnp.concatenate([q_nope, q_rope], -1)
-        o = chunked_attention(q, k, v, positions, positions, causal=True)
+        with jax.named_scope("mla_attend"):
+            o = chunked_attention(q, k, v, positions, positions, causal=True,
+                                  scale=m.scale)
         y = lin(o.reshape(B, S, H * m.v_head), p["wo"])
         if mode == "train":
             return y, None
@@ -595,23 +618,35 @@ def mla_apply(p, m: MLADims, x, positions, *, mode: str, cache=None,
     cache["pos"] = cache["pos"].at[bidx, positions].set(positions)
     cache["len"] = jnp.maximum(cache["len"], positions[:, -1] + 1)
 
-    from .linops import is_quantized
-    wk_b_arr = (p["wk_b"]["q"].astype(jnp.float32) * p["wk_b"]["scale"][None, :]
-                if is_quantized(p["wk_b"]) else p["wk_b"])
-    wk_b = wk_b_arr.reshape(m.kv_lora, H, m.qk_nope)
-    q_eff = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], wk_b)          # (B, H, r)
-    scale = (m.qk_nope + m.qk_rope) ** -0.5
+    with jax.named_scope("mla_attend"):
+        o = _mla_absorbed_attend(p, m, q_nope[:, 0], q_rope[:, 0], cache,
+                                 positions, x.dtype)
+    return lin(o.reshape(B, 1, H * m.v_head), p["wo"]), cache
+
+
+def _dequant(w) -> jax.Array:
+    """An fp (or dequantized f32) view of a projection weight."""
+    if is_quantized(w):
+        return w["q"].astype(jnp.float32) * w["scale"][None, :]
+    return w
+
+
+def _mla_absorbed_attend(p, m: MLADims, q_nope, q_rope, cache, positions,
+                         dtype):
+    """One decode token's attention in the compressed space: wk_b folds
+    into the query, wv_b into the output.  q_nope (B, H, qk_nope), q_rope
+    (B, H, qk_rope); returns (B, H, v_head)."""
+    H = m.n_heads
+    wk_b = _dequant(p["wk_b"]).reshape(m.kv_lora, H, m.qk_nope)
+    q_eff = jnp.einsum("bhn,rhn->bhr", q_nope, wk_b)                # (B, H, r)
     s = (jnp.einsum("bhr,bsr->bhs", q_eff, cache["ckv"],
                     preferred_element_type=jnp.float32)
-         + jnp.einsum("bhr,bsr->bhs", q_rope[:, 0], cache["krope"],
-                      preferred_element_type=jnp.float32)) * scale
+         + jnp.einsum("bhr,bsr->bhs", q_rope, cache["krope"],
+                      preferred_element_type=jnp.float32)) * m.scale
     ok = (cache["pos"] <= positions[:, :1]) & (cache["pos"] >= 0)
     s = jnp.where(ok[:, None, :], s, NEG)
     prob = jax.nn.softmax(s, axis=-1)
     ctx = jnp.einsum("bhs,bsr->bhr", prob.astype(cache["ckv"].dtype), cache["ckv"],
                      preferred_element_type=jnp.float32)            # (B, H, r)
-    wv_b_arr = (p["wv_b"]["q"].astype(jnp.float32) * p["wv_b"]["scale"][None, :]
-                if is_quantized(p["wv_b"]) else p["wv_b"])
-    wv_b = wv_b_arr.reshape(m.kv_lora, H, m.v_head)
-    o = jnp.einsum("bhr,rhv->bhv", ctx.astype(x.dtype), wv_b)
-    return lin(o.reshape(B, 1, H * m.v_head), p["wo"]), cache
+    wv_b = _dequant(p["wv_b"]).reshape(m.kv_lora, H, m.v_head)
+    return jnp.einsum("bhr,rhv->bhv", ctx.astype(dtype), wv_b)

@@ -17,13 +17,14 @@ from __future__ import annotations
 
 import dataclasses
 
+from .layers import YarnConfig
 from .moe import MoEConfig
 from .ssm import SSMConfig
 
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    q_lora: int
+    q_lora: int | None              # None: one wq, no query compression
     kv_lora: int
     qk_nope: int = 128
     qk_rope: int = 64
@@ -48,6 +49,7 @@ class ArchConfig:
     attn_softcap: float | None = None
     logit_softcap: float | None = None
     rope_theta: float = 10_000.0
+    yarn: YarnConfig | None = None         # YaRN rotary (MLA's rope dims)
     norm_eps: float = 1e-6
     moe: MoEConfig | None = None
     mla: MLAConfig | None = None
@@ -77,6 +79,16 @@ class ArchConfig:
         _ = self.n_blocks
         if self.moe:
             assert self.moe.n_experts % 1 == 0
+            assert 0 <= self.moe.held_offset and (
+                self.moe.held_offset + self.moe.held <= self.moe.n_experts), (
+                f"{self.name}: experts {self.moe.held_offset}.."
+                f"{self.moe.held_offset + self.moe.held - 1} held of "
+                f"{self.moe.n_experts}")
+        if self.mla is not None and self.quant_kv != "none":
+            # the latent cache has no int8 layout: refuse, never ignore
+            raise ValueError(f"{self.name}: quant_kv={self.quant_kv!r} is not "
+                             "supported with MLA layers (the latent cache "
+                             "stays in the model dtype)")
         return self
 
 
@@ -103,9 +115,11 @@ def reduced(cfg: ArchConfig, **overrides) -> ArchConfig:
         base["moe"] = dataclasses.replace(
             cfg.moe, n_experts=8, top_k=min(cfg.moe.top_k, 2), d_ff_expert=32,
             d_ff_dense=64 if (cfg.moe.n_shared or cfg.moe.dense_residual) else 0,
-            capacity_factor=8.0)  # avoid capacity drops in tiny smoke tests
+            capacity_factor=8.0,  # avoid capacity drops in tiny smoke tests
+            n_held=cfg.moe.n_held and 2, held_offset=0)
     if cfg.mla:
-        base["mla"] = MLAConfig(q_lora=32, kv_lora=32, qk_nope=16, qk_rope=8, v_head=16)
+        base["mla"] = MLAConfig(q_lora=32 if cfg.mla.q_lora else None,
+                                kv_lora=32, qk_nope=16, qk_rope=8, v_head=16)
     if cfg.ssm:
         base["ssm"] = SSMConfig(d_model=64, d_state=16, head_dim=16, expand=2,
                                 d_conv=4, chunk=16)

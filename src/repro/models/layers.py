@@ -5,6 +5,9 @@ Params are nested dicts; leaf names are the contract the sharding rules in
 """
 from __future__ import annotations
 
+import dataclasses
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -52,13 +55,65 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (``rope_scaling`` of type "yarn")."""
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """m(s, a) = 0.1 a ln s + 1 (1 for s <= 1)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_ramp_bounds(dim: int, base: float, y: YarnConfig) -> tuple[int, int]:
+    """(low, high): the rotary pairs below ``low`` keep their frequency,
+    those from ``high`` on are interpolated by ``factor``."""
+    def corr(r):
+        return dim * math.log(y.original_max / (2 * math.pi * r)) / (
+            2 * math.log(base))
+    return (max(math.floor(corr(y.beta_fast)), 0),
+            min(math.ceil(corr(y.beta_slow)), dim - 1))
+
+
+def yarn_freqs(dim: int, base: float, y: YarnConfig) -> jax.Array:
+    """The (dim/2,) YaRN inverse frequencies: a linear ramp between the
+    plain frequencies and those divided by ``factor``."""
+    extra = rope_freqs(dim, base)
+    low, high = yarn_ramp_bounds(dim, base, y)
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    ramp = jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / y.factor * ramp + extra * (1.0 - ramp)
+
+
+def yarn_softmax_scale(head_dim: int, y: YarnConfig | None) -> float:
+    """head_dim^-0.5, times m(factor, mscale_all_dim)^2 under YaRN."""
+    m = 1.0 if y is None else yarn_mscale(y.factor, y.mscale_all_dim)
+    return head_dim ** -0.5 * m * m
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn: YarnConfig | None = None) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: (..., seq).  ``yarn``
+    swaps in YaRN's frequencies and scales cos and sin by
+    m(factor, mscale) / m(factor, mscale_all_dim)."""
     dh = x.shape[-1]
-    freqs = rope_freqs(dh, theta)                              # (dh/2,)
+    if yarn is None:
+        freqs = rope_freqs(dh, theta)                          # (dh/2,)
+    else:
+        freqs = yarn_freqs(dh, theta, yarn)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., seq, dh/2)
     sin = jnp.sin(angles)[..., None, :]                        # (..., seq, 1, dh/2)
     cos = jnp.cos(angles)[..., None, :]
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        sin, cos = sin * m, cos * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -29,6 +29,7 @@ round-trip).  See DESIGN.md Sec. 2.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 
 import jax
@@ -272,11 +273,35 @@ def lin_grouped(x: jax.Array, ws) -> tuple:
 # (see models/layers.py header and distributed/sharding._RULES), so a
 # renamed cross block must update all three places together.
 GROUP_SIBLING_SETS = (("wq", "wk", "wv"), ("w_gate", "w_up"),
-                      ("wq_a", "wkv_a"))
+                      ("wq_a", "wkv_a"), ("wq", "wkv_a"))
+# a routed-expert stack (models/moe.py): (E, d, F) gate and up, (E, F, d)
+# down, quantized together by ``quantize_experts``
+EXPERT_KEYS = ("we_gate", "we_up", "we_down")
 CROSS_SIBLING_SETS = (("wk", "wv"),)
 # top-level param subtrees whose leaves carry a leading layer axis (the
 # scanned pattern blocks and the encoder stack; models/transformer.py)
 STACKED_KEYS = ("blocks", "enc_blocks")
+
+
+@functools.partial(jax.jit, static_argnames=("alpha", "beta"))
+def quantize_experts(we_gate, we_up, we_down, alpha: float = 6.0,
+                     beta: float = 6.0) -> dict:
+    """Deploy-time records of one layer's expert stack (E experts): the
+    gate and up projections as ONE grouped record over 2E segments (every
+    gate, then every up: each expert's projections keep their own
+    per-channel scales and surrogate stats), and the down projections
+    stacked along K into one (E F, d) record - the weighted sum over
+    experts is then one matmul's reduction (``moe._expert_ffn_pdq``).
+
+    Jitted, so the float temporaries of a large stack are fused away."""
+    E = we_gate.shape[0]
+    return {
+        "we_gate_up": group_quantize_weights(
+            [we_gate[e] for e in range(E)] + [we_up[e] for e in range(E)],
+            alpha, beta, names=("we_gate",) * E + ("we_up",) * E),
+        "we_down": quantize_weight(
+            we_down.reshape(-1, we_down.shape[-1]), alpha, beta),
+    }
 
 
 def quantize_param_tree(params, path_pred=None, alpha: float = 6.0,
@@ -284,7 +309,10 @@ def quantize_param_tree(params, path_pred=None, alpha: float = 6.0,
     """Replace selected 2-D weight leaves with quantized records.
 
     path_pred(path_str, leaf) -> bool selects leaves; default: every 2-D
-    float leaf whose name starts with 'w' or ends with '_proj'.
+    float leaf whose name starts with 'w' or ends with '_proj'.  A whole
+    expert stack (``EXPERT_KEYS``, 3-D per layer) becomes the two records
+    of ``quantize_experts`` under ``we_gate_up`` and ``we_down``; with a
+    ``path_pred``, only if it selects all three stacks.
 
     With ``group_siblings`` (default), known same-input sibling sets whose
     members all pass the predicate are emitted as ONE grouped record
@@ -310,6 +338,13 @@ def quantize_param_tree(params, path_pred=None, alpha: float = 6.0,
         if isinstance(node, dict):
             out = {}
             done = set()
+            stacks = [node.get(n) for n in EXPERT_KEYS]
+            if all(hasattr(l, "ndim") and l.ndim == 3
+                   and jnp.issubdtype(l.dtype, jnp.floating)
+                   and (path_pred is None or path_pred(join(path, n), l))
+                   for n, l in zip(EXPERT_KEYS, stacks)):
+                out.update(quantize_experts(*stacks, alpha=alpha, beta=beta))
+                done.update(EXPERT_KEYS)
             if group_siblings:
                 sets = CROSS_SIBLING_SETS if key == "cross" else GROUP_SIBLING_SETS
                 for names in sets:

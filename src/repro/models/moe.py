@@ -19,6 +19,16 @@ dispatch machinery would be all overhead.
 Extras: shared experts (DeepSeek) and a dense FFN residual (Arctic), both
 plain TP-sharded MLPs applied to every token; switch-style load-balance aux
 loss.
+
+A layer may hold a SHARE of the experts (``MoEConfig.n_held``): the router
+keeps its published width and top-k over all ``n_experts``, the stacks hold
+experts ``held_offset .. held_offset + n_held - 1`` only, and assignments
+to experts not held add nothing - the part of the layer one chip of an
+expert-parallel deployment computes.  Such a layer runs the dense-masked
+form in every mode (no capacity, so no assignment to a held expert is ever
+dropped).  Under PDQ its experts are quantized records
+(``linops.quantize_experts``): one grouped gate/up W8A8 matmul over every
+held expert and one down matmul over the experts stacked along K.
 """
 from __future__ import annotations
 
@@ -27,7 +37,24 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
+
 from .layers import dense_init, mlp_init
+
+# kernel name of the held experts' W8A8 matmuls (trace-visible)
+EXPERT_KERNEL = "w8a8_expert_matmul"
+
+
+def expert_blocks(rows: int) -> tuple[tuple[int, int, int], ...]:
+    """(bm, bn, bk) of the gate/up and of the down expert matmul for
+    ``rows`` rows.  The W8A8 kernel costs a fixed time per grid step, and
+    the experts' matmuls are wide (N = 2 x experts x width) or deep
+    (K = experts x width): a 512-deep K block cuts their steps 4x, and the
+    down matmul's per-row epilogue lets its N block be 512 too (gate/up
+    keeps 128: every expert's segment, 1408 wide, is a multiple of 128
+    only).  Row blocks of 256 past one block of rows."""
+    bm = 128 if rows <= 128 else 256
+    return (bm, 128, 512), (bm, 512, 512)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,13 +67,21 @@ class MoEConfig:
     d_ff_dense: int = 0             # width of shared/dense-residual FFN
     capacity_factor: float = 1.25
     router_scale: float = 1.0       # gate multiplier (deepseek routed_scaling)
+    norm_topk: bool = True          # renormalise the top-k gates to sum 1
+    n_held: int | None = None       # experts this layer holds (None: all)
+    held_offset: int = 0            # id of the first expert held
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.n_held is None else self.n_held
 
 
 def moe_init(key, d_model: int, cfg: MoEConfig, dtype):
     ks = jax.random.split(key, 6)
-    E, ff = cfg.n_experts, cfg.d_ff_expert
+    E, ff = cfg.held, cfg.d_ff_expert
     p = {
-        "router": dense_init(ks[0], d_model, E, jnp.float32),
+        # the router keeps its published width: it scores every expert
+        "router": dense_init(ks[0], d_model, cfg.n_experts, jnp.float32),
         "we_gate": jax.vmap(lambda k: dense_init(k, d_model, ff, dtype))(
             jax.random.split(ks[1], E)),
         "we_up": jax.vmap(lambda k: dense_init(k, d_model, ff, dtype))(
@@ -73,10 +108,12 @@ def route(x: jax.Array, wr: jax.Array, cfg: MoEConfig,
     bucketed-prefill pad tokens must not consume router capacity.
     """
     E = wr.shape[1]
-    logits = (x.astype(jnp.float32) @ wr)
+    logits = jnp.matmul(x.astype(jnp.float32), wr,
+                        precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, ids = jax.lax.top_k(probs, cfg.top_k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     gates = gates * cfg.router_scale
     if token_mask is not None:
         gates = jnp.where(token_mask[:, None], gates, 0.0)
@@ -153,22 +190,58 @@ def moe_ffn_dense_masked(
     *,
     axis_name: str | None = None,
     token_mask: jax.Array | None = None,
+    count_rows: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Decode-path MoE: every shard computes its local experts over all
-    tokens, masked by gates; psum over the expert axis combines."""
-    E, k = cfg.n_experts, cfg.top_k
-    nshards = 1 if axis_name is None else jax.lax.psum(1, axis_name)  # static int
-    E_loc = E // nshards
-    gates, ids, aux = route(x, p["router"], cfg, token_mask)
-    shard = 0 if axis_name is None else jax.lax.axis_index(axis_name)
-    e_offset = shard * E_loc
+    """Every shard computes its local experts over all tokens, masked by
+    gates; psum over the expert axis combines.  The decode path, and the
+    only path of a layer that holds a share of the experts.
 
-    h = jnp.broadcast_to(x[None], (E_loc, *x.shape))        # (E_loc, T, d)
-    out = _expert_ffn(p, h)                                 # (E_loc, T, d)
+    The local stack holds experts ``held_offset + shard * E_loc`` on.
+    ``count_rows`` records the routed assignments that land on them in the
+    launch's telemetry (``ops.tel_count``)."""
+    with jax.named_scope("moe_route"):
+        gates, ids, aux = route(x, p["router"], cfg, token_mask)
+    E_loc = _n_local(p)
+    shard = 0 if axis_name is None else jax.lax.axis_index(axis_name)
+    e_offset = cfg.held_offset + shard * E_loc
     local_eids = e_offset + jnp.arange(E_loc)               # (E_loc,)
     sel = (ids[None, :, :] == local_eids[:, None, None])    # (E_loc, T, k)
     w = jnp.sum(sel * gates[None], axis=-1)                 # (E_loc, T)
-    y = jnp.einsum("et,etd->td", w.astype(out.dtype), out)
-    if axis_name is not None and nshards > 1:
+    if count_rows:
+        ops.tel_count("expert_rows", jnp.sum(sel.astype(jnp.float32)))
+    with jax.named_scope("moe_experts"):
+        if "we_gate_up" in p:
+            y = _expert_ffn_pdq(p, x, w.T)
+        else:
+            h = jnp.broadcast_to(x[None], (E_loc, *x.shape))    # (E_loc, T, d)
+            out = _expert_ffn(p, h)                             # (E_loc, T, d)
+            y = jnp.einsum("et,etd->td", w.astype(out.dtype), out)
+    if axis_name is not None and jax.lax.psum(1, axis_name) > 1:
         y = jax.lax.psum(y, axis_name)
     return y, aux
+
+
+def _n_local(p) -> int:
+    """Experts in this layer's (local) stack, fp or quantized."""
+    if "we_gate_up" in p:
+        return len(p["we_gate_up"]["segs"].sizes) // 2
+    return p["we_gate"].shape[0]
+
+
+def _expert_ffn_pdq(p, x: jax.Array, w: jax.Array) -> jax.Array:
+    """The held experts under PDQ, x (T, d) and gate weights w (T, E):
+    ONE grouped W8A8 matmul over every expert's gate and up projection
+    (one prologue, a surrogate interval per segment), the SwiGLU pairing
+    weighted by each row's gates, then ONE W8A8 matmul over the experts'
+    down projections stacked along K - the weighted sum over experts is
+    that matmul's reduction."""
+    gate_up, down = expert_blocks(x.shape[0])
+    gu = ops.pdq_dense_grouped(x, p["we_gate_up"], out="fp",
+                               out_dtype=x.dtype, block=gate_up,
+                               name=EXPERT_KERNEL)
+    E = len(gu) // 2
+    g = jnp.stack(gu[:E], axis=1)                            # (T, E, F)
+    u = jnp.stack(gu[E:], axis=1)
+    h = jax.nn.silu(g) * u * w[..., None].astype(x.dtype)
+    return ops.pdq_dense(h.reshape(x.shape[0], -1), p["we_down"], out="fp",
+                         out_dtype=x.dtype, block=down, name=EXPERT_KERNEL)

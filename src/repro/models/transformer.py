@@ -31,7 +31,7 @@ from .moe import moe_ffn_dense_masked, moe_ffn_tokens, moe_init
 from .ssm import ssm_apply, ssm_init, ssm_init_cache
 
 MLADimsFields = ("d_model", "n_heads", "q_lora", "kv_lora", "qk_nope", "qk_rope",
-                 "v_head", "rope_theta")
+                 "v_head", "rope_theta", "yarn")
 
 
 def _attn_dims(cfg: ArchConfig, kind: str) -> AttnDims:
@@ -46,7 +46,7 @@ def _mla_dims(cfg: ArchConfig):
     m = cfg.mla
     return MLADims(d_model=cfg.d_model, n_heads=cfg.n_heads, q_lora=m.q_lora,
                    kv_lora=m.kv_lora, qk_nope=m.qk_nope, qk_rope=m.qk_rope,
-                   v_head=m.v_head, rope_theta=cfg.rope_theta)
+                   v_head=m.v_head, rope_theta=cfg.rope_theta, yarn=cfg.yarn)
 
 
 def _is_moe(cfg: ArchConfig, kind: str) -> bool:
@@ -73,9 +73,9 @@ def layer_init(key, cfg: ArchConfig, kind: str, dtype):
     if _is_moe(cfg, kind):
         p["ffn"] = moe_init(k2, cfg.d_model, cfg.moe, dtype)
     else:
-        d_ff = cfg.d_ff if kind != "global_dense" else (cfg.moe.d_ff_dense
-                                                        if cfg.moe else cfg.d_ff)
-        p["ffn"] = mlp_init(k2, cfg.d_model, d_ff or cfg.d_ff, dtype)
+        # a dense layer of an MoE model (DeepSeek's leading layers) is
+        # d_ff wide; d_ff_dense is the shared experts' width
+        p["ffn"] = mlp_init(k2, cfg.d_model, cfg.d_ff, dtype)
     if cfg.family == "encdec":
         p["cross_norm"] = jnp.zeros((cfg.d_model,), dtype)
         p["cross"] = gqa_init(k3, _attn_dims(cfg, "global"), dtype)
@@ -110,12 +110,23 @@ def _apply_ffn(p_ffn, cfg: ArchConfig, kind: str, h: jax.Array, mode: str,
         mask = (jax.lax.broadcasted_iota(jnp.int32, (B, S), 1)
                 < seq_lens[:, None]).reshape(B * S)
     ctx = context.get_context()
-    routed = {k: p_ffn[k] for k in ("router", "we_gate", "we_up", "we_down")}
-    use_ep = ctx is not None and mode in ("train", "prefill")
+    routed = {k: v for k, v in p_ffn.items() if k not in ("shared", "dense")}
+    # a layer that holds a share of the experts has no capacity to drop
+    # tokens at: it runs the dense-masked form in every mode
+    tokens = mode in ("train", "prefill") and cfg.moe.n_held is None
     if ctx is None:
-        fn = moe_ffn_tokens if mode in ("train", "prefill") else moe_ffn_dense_masked
-        y, aux = fn(routed, x2, cfg.moe, axis_name=None, token_mask=mask)
-    elif use_ep:
+        if tokens:
+            y, aux = moe_ffn_tokens(routed, x2, cfg.moe, axis_name=None,
+                                    token_mask=mask)
+        else:
+            y, aux = moe_ffn_dense_masked(routed, x2, cfg.moe, axis_name=None,
+                                          token_mask=mask,
+                                          count_rows=mode == "decode")
+    elif "we_gate_up" in routed:
+        raise NotImplementedError(
+            "quantized expert stacks run on one device only (no expert-"
+            "parallel layout for their records)")
+    elif tokens:
         def f(rp, xt, mt):
             yy, ax = moe_ffn_tokens(rp, xt, cfg.moe, axis_name=ctx.expert_axis,
                                     token_mask=mt)
@@ -147,7 +158,8 @@ def _apply_ffn(p_ffn, cfg: ArchConfig, kind: str, h: jax.Array, mode: str,
     y = checkpoint_name(y, "moe_out")
     y = y.reshape(B, S, d)
     if cfg.moe.n_shared:
-        y = y + mlp_apply(p_ffn["shared"], h)
+        with jax.named_scope("moe_shared"):
+            y = y + mlp_apply(p_ffn["shared"], h)
     if cfg.moe.dense_residual:
         y = y + mlp_apply(p_ffn["dense"], h)
     return y, aux

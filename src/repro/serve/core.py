@@ -44,6 +44,7 @@ import numpy as np
 
 from repro.distributed.fault import (FailureLog, FaultInjector,
                                      StragglerWatchdog, save_snapshot)
+from repro.kernels import ops
 
 from . import telemetry as tmod
 from .pages import PageError, PagePool, PrefixStore, pages_for
@@ -90,18 +91,19 @@ class Request:
 class PrefillPlan:
     """One bucketed prefill launch spanning every replica: prompts
     right-padded to ``bucket``, replica r's admits in rows [r*spr, r*spr +
-    n_r) of the fixed ``slots``-row batch; rows with seq_lens == 0 are
+    n_r) of a batch of one of the engine's ``prefill_rows`` (every slot
+    row on a replicated engine); rows with seq_lens == 0 are
     dummies the scatter drops.  ``src_map`` carries replica-LOCAL source
     rows (identical to global rows when n_replicas == 1)."""
     bucket: int
-    tokens: np.ndarray               # (slots, bucket) int32
-    seq_lens: np.ndarray             # (slots,) int32; 0 = dummy row
+    tokens: np.ndarray               # (rows, bucket) int32
+    seq_lens: np.ndarray             # (rows,) int32; 0 = dummy row
     src_map: np.ndarray              # (slots,) int32; -1 = keep pool slot
     placed: list[tuple[int, int, Request]]   # (slot, batch row, request)
     per_counts: list[int]            # admits per replica
     real_tokens: int                 # prompt tokens (pads excluded)
-    row_uids: np.ndarray = None      # (slots,) int32; -1 = dummy row
-    row_steps: np.ndarray = None     # (slots,) int32 token index; -1 = dummy
+    row_uids: np.ndarray = None      # (rows,) int32; -1 = dummy row
+    row_steps: np.ndarray = None     # (rows,) int32 token index; -1 = dummy
     # paged pool landing maps (None on slot-row engines): pool page p takes
     # page ``land_js[p]`` of replica-local scratch row ``land_rows[p]``;
     # -1 keeps the page (unallocated, or a shared prefix page)
@@ -188,6 +190,10 @@ class SchedulerCore:
         self.patch_tokens = patch_tokens
         self.batch_prefill = batch_prefill
         self.chunked_prefill = chunked_prefill
+        # the row counts a bucketed prefill launch may take, ascending: a
+        # round's admits ride the smallest that holds them (an engine may
+        # set a ladder; by default every launch takes every slot row)
+        self.prefill_rows: tuple[int, ...] = (slots,)
         self.lengths = np.zeros((slots,), np.int64)
         self.active: list[Request | None] = [None] * slots
         self.last_tokens = np.zeros((slots,), np.int64)
@@ -286,6 +292,8 @@ class SchedulerCore:
             "pdq_fallbacks": 0,        # guarded-projection fp fallbacks fired
             "pdq_clip_hits": 0,        # int8 outputs saturated at clip edges
             "pdq_clip_total": 0,       # int8 outputs checked
+            "expert_rows": 0,          # decode rows routed to held experts
+                                       # (every (row, expert) assignment)
             # per-replica occupancy/admit accounting (single-replica engines
             # report one-element lists)
             "replica_admits": [0] * n_replicas,
@@ -363,16 +371,19 @@ class SchedulerCore:
             return [dict(e) for e in self.failures.events]
 
     def _observe_pdq(self, tel_sum) -> None:
-        """Fold one launch's device-side [fallbacks, clip_hits, clip_total]
-        summary (rode the token gather as host numpy) into stats + the
-        metrics registry."""
+        """Fold one launch's device-side telemetry summary (``ops.
+        TEL_FIELDS``; it rode the token gather as host numpy) into stats +
+        the metrics registry."""
         if tel_sum is None or not self.tel.enabled:
             return
-        fb, hits, total = (float(x) for x in np.asarray(tel_sum).reshape(-1)[:3])
+        tel = dict(zip(ops.TEL_FIELDS,
+                       (float(x) for x in np.asarray(tel_sum).reshape(-1))))
+        fb, hits, total = tel["fallbacks"], tel["clip_hits"], tel["clip_total"]
         with self.stats_lock:
             self.stats["pdq_fallbacks"] += int(round(fb))
             self.stats["pdq_clip_hits"] += int(round(hits))
             self.stats["pdq_clip_total"] += int(round(total))
+            self.stats["expert_rows"] += int(round(tel["expert_rows"]))
         self.tel.observe_pdq(fb, hits, total)
 
     # ------------------------------------------------------------ exec hooks
@@ -705,18 +716,20 @@ class SchedulerCore:
     # ------------------------------------------------------- prefill planning
     def _plan_prefill(self, per: list[list[Request]], bucket: int) -> PrefillPlan:
         """Lay replica r's admits into rows [r*spr, r*spr + len(per[r]))
-        of a fixed ``slots``-row batch and claim their slots.  Rows beyond
-        a replica's admits are dummies: seq_lens == 0 masks every one of
+        of the batch and claim their slots.  The batch has the smallest of
+        ``prefill_rows`` rows that holds the admits.  Rows beyond a
+        replica's admits are dummies: seq_lens == 0 masks every one of
         their tokens out of attention writes, the SSM recurrence and MoE
         routing, and src_map == -1 makes the scatter drop them."""
         spr = self.slots_per_replica
         n = sum(len(g) for g in per)
         assert 0 < n <= self._free_total()
-        tokens = np.zeros((self.slots, bucket), np.int32)
-        seq_lens = np.zeros((self.slots,), np.int32)     # dummy rows: 0
+        rows = next(r for r in self.prefill_rows if r >= n)
+        tokens = np.zeros((rows, bucket), np.int32)
+        seq_lens = np.zeros((rows,), np.int32)           # dummy rows: 0
         src_map = np.full((self.slots,), -1, np.int32)
-        row_uids = np.full((self.slots,), -1, np.int32)
-        row_steps = np.full((self.slots,), -1, np.int32)
+        row_uids = np.full((rows,), -1, np.int32)
+        row_steps = np.full((rows,), -1, np.int32)
         placed: list[tuple[int, int, Request]] = []
         for ri, reqs in enumerate(per):
             for i, r in enumerate(reqs):
@@ -862,7 +875,7 @@ class SchedulerCore:
         self.stats["prefill_batches"] += 1
         self.stats["prefill_requests"] += len(plan.placed)
         self.stats["prefill_tokens"] += plan.real_tokens
-        self.stats["prefill_padded_tokens"] += self.slots * plan.bucket
+        self.stats["prefill_padded_tokens"] += len(plan.seq_lens) * plan.bucket
 
     def _plan_chunked(self, reqs: list[Request],
                       per: list[list[Request]] | None = None) -> ChunkedPlan:

@@ -67,6 +67,21 @@ __all__ = ["DECODE_PAD", "DEFAULT_BUCKETS", "Request", "ServeEngine"]
 DECODE_PAD = -1
 
 
+def prefill_row_ladder(slots: int) -> tuple[int, ...]:
+    """Row counts of a single-device engine's bucketed prefill launches,
+    ascending: ``slots`` and every fourth part of it down to 1 or 2 rows
+    (64 slots: 1, 4, 16, 64).  A launch costs about its rows, and each
+    count is one more compiled prefill per length bucket at set-up; below
+    32 slots a launch of every slot row costs at most 16x one of a single
+    row, and the engine keeps that one shape."""
+    if slots < 32:
+        return (slots,)
+    rows = [slots]
+    while rows[-1] >= 4:
+        rows.append(rows[-1] // 4)
+    return tuple(sorted(rows))
+
+
 def decode_scan(step_fn, sample_fn, n_block: int, collect: bool):
     """Build the N-step fused decode body shared by every engine: a
     ``lax.scan`` of ``n_block`` model steps carrying (cache state, token,
@@ -83,7 +98,8 @@ def decode_scan(step_fn, sample_fn, n_block: int, collect: bool):
     and wrap the whole scan at the call site.
 
     Returns ``run(rng, params, state, tokens, positions, uids, steps,
-    n_steps) -> (toks (B, N), ok (B, N), state, tel (3,))``.
+    n_steps) -> (toks (B, N), ok (B, N), state, tel)``, ``tel`` the
+    block-summed telemetry summary (``ops.TEL_FIELDS``).
     """
     def run(rng, params, state, tokens, positions, uids, steps, n_steps):
         def body(carry, t):
@@ -152,6 +168,8 @@ class ServeEngine(SchedulerCore):
             buckets=buckets, batch_prefill=batch_prefill,
             chunked_prefill=chunked_prefill, decode_steps=decode_steps,
             fault=fault, tel=tel)
+        self.prefill_rows = self._prefill_rows()
+        self._warm_buckets: set[int] = set()
         if paged:
             assert batch_prefill, "the paged pool needs the bucketed path"
             self._paged_ops = self.bundle.paged_cache(
@@ -167,6 +185,11 @@ class ServeEngine(SchedulerCore):
         self._init_pools()
         self._build_sampler()
         self._build_jitted()
+
+    def _prefill_rows(self) -> tuple[int, ...]:
+        """The row ladder of this engine's prefill launches (the mesh
+        engines keep one launch of every slot row)."""
+        return prefill_row_ladder(self.slots)
 
     def _init_pools(self):
         """Allocate the serving cache pools.  The multi-host engine
@@ -221,6 +244,16 @@ class ServeEngine(SchedulerCore):
                                               "prefill_compiles"))
         self._prefill_many = self._traced_jit(self.bundle.prefill_many,
                                               "prefill_compiles")
+        # a launch of fewer rows than slots starts from a zero pool of its
+        # own rows, made in the program (the spare pool has every row)
+        bundle, max_len, mem_len = self.bundle, self.max_len, self.mem_len
+
+        def prefill_fresh(params, batch, seq_lens):
+            pool = bundle.init_caches(batch["tokens"].shape[0], max_len,
+                                      mem_len)
+            return bundle.prefill_many(params, batch, pool, seq_lens)
+
+        self._prefill_few = self._traced_jit(prefill_fresh, "prefill_compiles")
         self._prefill_chunk = self._traced_jit(self.bundle.prefill_chunk,
                                                "chunk_compiles")
         # the pooled cache is rebound to the scatter result immediately, so
@@ -267,12 +300,13 @@ class ServeEngine(SchedulerCore):
         """jit(fn) that bumps ``stats[counter]`` once per (re)trace - i.e.
         once per compiled executable, the quantity the bucket design caps.
 
-        Every launch also returns the pdq health summary ((3,) float32:
-        guard fallbacks, int8 clip hits, clipped-output count) folded
-        device-side by ops.pdq_telemetry - pure jnp reductions, so the
-        pallas_call census is unchanged and the scalars ride the existing
-        token gather instead of adding a host round-trip.  With telemetry
-        off the summary is a constant zeros vector."""
+        Every launch also returns the telemetry summary (``ops.TEL_FIELDS``:
+        guard fallbacks, int8 clip hits, clipped-output count, expert
+        rows) folded device-side by ops.pdq_telemetry - pure jnp
+        reductions, so the pallas_call census is unchanged and the scalars
+        ride the existing token gather instead of adding a host
+        round-trip.  With telemetry off the summary is a constant zeros
+        vector."""
         stats = self.stats
         guard = self.pdq_fallback
         collect = self.tel.enabled
@@ -289,7 +323,7 @@ class ServeEngine(SchedulerCore):
         """jit for the fused decode block.  Unlike _traced_jit it does NOT
         open pdq_telemetry here: the scan body collects per-iteration (the
         summary must be traced inside the body) and ``fn`` already returns
-        the block-summed (3,) vector as its last element.  pdq_guard is
+        the block-summed summary as its last element.  pdq_guard is
         trace-time only, so wrapping the whole scan is safe."""
         stats = self.stats
         guard = self.pdq_fallback
@@ -335,7 +369,7 @@ class ServeEngine(SchedulerCore):
 
     def _sample_rows(self, kind: str, plan, logits):
         """Sample every batch row of a launch; returns device arrays
-        (tokens (slots,), ok (slots,)) without waiting for them.  Applies
+        (tokens (rows,), ok (rows,)) without waiting for them.  Applies
         the fault injector's logits poisoning first (no-op outside fault
         tests)."""
         rows = self.fault.poison_rows(kind, plan)
@@ -349,7 +383,7 @@ class ServeEngine(SchedulerCore):
         if extras:
             # extras are shared across requests (seed semantics): broadcast
             # their leading batch dim across the prefill rows
-            Bp = self.slots
+            Bp = batch["tokens"].shape[0]
             batch.update(jax.tree.map(
                 lambda a: jnp.broadcast_to(jnp.asarray(a)[:1],
                                            (Bp,) + jnp.asarray(a).shape[1:]),
@@ -357,13 +391,45 @@ class ServeEngine(SchedulerCore):
         return batch
 
     # ------------------------------------------------------------ exec hooks
+    def _launch_prefill(self, batch: dict, seq_lens):
+        if batch["tokens"].shape[0] == self.slots:
+            return self._prefill_many(self.params, batch, self._prefill_pool,
+                                      seq_lens)
+        return self._prefill_few(self.params, batch, seq_lens)
+
+    def _warm_prefill_rows(self, bucket: int, extras) -> None:
+        """The first launch of a length bucket first runs it at every row
+        count of the ladder, on dummy rows that land nothing: every shape
+        the bucket can take compiles with the first request that needs it
+        (a server's warm-up), never later."""
+        if len(self.prefill_rows) == 1 or bucket in self._warm_buckets:
+            return
+        self._warm_buckets.add(bucket)
+        keep = np.full((self.slots,), -1, np.int32)
+        for rows in self.prefill_rows:
+            batch = self._extras_batch(
+                {"tokens": jnp.zeros((rows, bucket), jnp.int32)}, extras)
+            (logits, sub), _ = self._launch_prefill(
+                batch, jnp.zeros((rows,), jnp.int32))
+            if self.paged:
+                no_page = jnp.full((self.n_replicas * self.pool_pages,), -1,
+                                   jnp.int32)
+                self.caches = self._land(self.caches, sub, jnp.asarray(keep),
+                                         no_page, no_page)
+            else:
+                self.caches = self._scatter(self.caches, sub,
+                                            jnp.asarray(keep))
+            dummy = jnp.full((rows,), -1, jnp.int32)
+            jax.block_until_ready(self._sampler(self.rng, logits, dummy,
+                                                dummy))
+
     def _exec_prefill(self, plan: PrefillPlan, extras):
+        self._warm_prefill_rows(plan.bucket, extras)
         batch = self._extras_batch({"tokens": jnp.asarray(plan.tokens)},
                                    extras)
         with self._dispatch_span("prefill"):
-            (logits, sub), tel = self._prefill_many(
-                self.params, batch, self._prefill_pool,
-                jnp.asarray(plan.seq_lens))
+            (logits, sub), tel = self._launch_prefill(
+                batch, jnp.asarray(plan.seq_lens))
             self._land_sub(plan, sub)
             toks, ok = self._sample_rows("prefill", plan, logits)
         # the pdq summary rides the token gather: one fetch for all three

@@ -94,6 +94,9 @@ class ShardedServeEngine(ServeEngine):
                          prefix_sharing=prefix_sharing,
                          telemetry=telemetry, trace=trace, tel=tel)
 
+    def _prefill_rows(self) -> tuple[int, ...]:
+        return (self.slots,)
+
     # ------------------------------------------------------- device programs
     def _sharded(self, fn, in_specs, out_specs, tel: bool = False):
         """shard_map(fn) over the mesh with TP (and, when enabled, the
@@ -101,7 +104,7 @@ class ShardedServeEngine(ServeEngine):
 
         ``tel=True`` additionally opens the pdq telemetry collector INSIDE
         the body (the TP/guard context is per-shard, so the collector must
-        be too) and psums the (3,) health summary over both mesh axes: the
+        be too) and psums the telemetry summary over both mesh axes: the
         launch returns ``(out, summary)`` with the summary replicated, so
         the coordinator reads fleet totals off the same device sync as the
         sampled tokens."""
@@ -142,7 +145,7 @@ class ShardedServeEngine(ServeEngine):
         body returning (toks, ok, state, tel): telemetry is collected
         INSIDE the scan (per iteration, per shard), so this wrapper only
         opens tp_shard/pdq_guard around it and psums the block-summed
-        (3,) health vector over both mesh axes.  Sampling runs in-body:
+        telemetry summary over both mesh axes.  Sampling runs in-body:
         each replica samples its OWN slot block with the per-(uid, step)
         keys - the per-row keys make that bit-identical to global
         sampling, and the launch returns (slots, N) int32 tokens instead

@@ -18,6 +18,7 @@ Pins:
 """
 import asyncio
 import contextlib
+import gc
 import http.client
 import json
 import os
@@ -130,6 +131,33 @@ def test_streamed_tokens_match_batch_run(small_model):
     assert st["pending"] == 0 and st["free_slots"] == st["slots"]
     svc.stop()
     assert not svc._streams           # stream table drained, nothing leaked
+
+
+def test_heap_frozen_after_traces_and_released_at_stop(small_model):
+    """A round that traced programs collects and freezes the heap, so a
+    full collection while serving walks only what serving allocated; a
+    round that traced nothing settles nothing, and stopping the service
+    hands the heap back to the collector."""
+    cfg, m, params = small_model
+    eng = _engine(cfg, params)
+    svc = ServeService(eng, max_pending=16)
+    settled = []
+    settle = svc._settle_heap
+    svc._settle_heap = lambda: (settled.append(gc.get_freeze_count()),
+                                settle())
+    svc.start()
+    prompt = _prompts(cfg, [5])[0]
+    svc.submit(prompt, max_new=6).result(timeout=300)
+    assert eng.stats["prefill_compiles"] and eng.stats["decode_compiles"]
+    assert settled and gc.get_freeze_count() > 0
+    n = len(settled)
+    traced = (eng.stats["prefill_compiles"], eng.stats["decode_compiles"])
+    svc.submit(prompt, max_new=6).result(timeout=300)
+    assert (eng.stats["prefill_compiles"],
+            eng.stats["decode_compiles"]) == traced
+    assert len(settled) == n
+    svc.stop()
+    assert gc.get_freeze_count() == 0
 
 
 # ---------------------------------------------------------------------------

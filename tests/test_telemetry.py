@@ -255,7 +255,9 @@ def test_pdq_collector_counts_clip_and_fallbacks():
             return y, col.summary()
 
     y, tel = jax.jit(prog)(x)
-    fb, hits, total = np.asarray(tel)
+    tel = dict(zip(ops.TEL_FIELDS, np.asarray(tel)))
+    fb, hits, total = tel["fallbacks"], tel["clip_hits"], tel["clip_total"]
+    assert tel["expert_rows"] == 0.0                  # no MoE layer here
     assert total == x.shape[0] * rec["q"].shape[1]    # every output checked
     assert 0 <= hits <= total
     assert fb == 0.0                                  # healthy fast path
@@ -280,7 +282,7 @@ def test_pdq_collector_disabled_is_constant_zeros():
             return ops.pdq_dense(x, rec), col.summary()
 
     _, tel = jax.jit(prog)(x)
-    assert np.asarray(tel).tolist() == [0.0, 0.0, 0.0]
+    assert np.asarray(tel).tolist() == [0.0] * len(ops.TEL_FIELDS)
     assert np.asarray(tel).shape == (ops.PDQ_TEL_WIDTH,)
 
 

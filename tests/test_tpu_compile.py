@@ -88,6 +88,32 @@ def test_w8a8_matmul_compiles_for_v5e(one_chip, N, cols):
              ((M, cols), f32))
 
 
+# DeepSeek-V2-Lite's 8 held experts of width 1408: their gate and up
+# projections as one grouped call, their down projections stacked along K,
+# at a decode's 64 rows and a prefill's 64 x 128, in the model's blocks
+@pytest.mark.parametrize("rows", [64, 64 * 128], ids=["decode", "prefill"])
+@pytest.mark.parametrize("which", [0, 1], ids=["experts_gate_up",
+                                               "experts_down"])
+def test_w8a8_expert_matmul_compiles_for_v5e(one_chip, rows, which):
+    from repro.models.moe import EXPERT_KERNEL, expert_blocks
+    K, N, cols = ((D_MODEL, 2 * 8 * 1408, 2 * 8 * 11),
+                  (8 * 1408, D_MODEL, 1))[which]
+    block = expert_blocks(rows)[which]
+    Mp = rows + (-rows) % block[0]
+
+    def fn(x, w, sx, zx, sw, cs, so, zo, lo, hi):
+        return w8a8_matmul_p(x, w, sx, zx, sw, cs, so, zo, lo, hi,
+                             requant=False, fp_clamp=True,
+                             per_nblock=cols > 1, out_dtype=bf16,
+                             block=block, name=EXPERT_KERNEL)
+
+    compiled = _compile(fn, one_chip, ((Mp, K), i8), ((K, N), i8),
+                        ((Mp, 1), f32), ((Mp, 1), i32), ((1, N), f32),
+                        ((1, N), i32), ((Mp, cols), f32), ((Mp, cols), i32),
+                        ((Mp, cols), f32), ((Mp, cols), f32))
+    assert EXPERT_KERNEL in compiled.as_text()
+
+
 def test_w8a8_swiglu_matmul_compiles_for_v5e(one_chip):
     N = 2 * D_FF                                  # gate | up, 128-padded
     nb = N // 128
