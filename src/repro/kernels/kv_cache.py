@@ -12,10 +12,13 @@ fp-dequantized cache never exists in HBM.
 
 Layout: one query token, grouped-query attention (H = G * Hkv), read
 from the model's layer-stacked cache (L, B, Hkv, S, Dh) at a
-scalar-prefetched layer index, K and V sequence-minor.  Grid (B, S/bs,
-Hkv); each head's m/l/acc live in VMEM scratch across the S dimension.  A
-decode step's launch also writes the step's token into the stack, in
-place.
+scalar-prefetched layer index, K and V sequence-minor.  Grid (B, S/bs): a
+step holds one S-block of a row for every KV head (``attend_tiling``
+sizes the block), and m/l/acc live in VMEM scratch across the S
+dimension.  Only the S-blocks a row holds are read and attended: past the
+row's last live block (``_last_block``) the index maps repeat that block,
+so no DMA is issued, and the step does nothing.  A decode step's launch
+also writes the step's token into the stack, in place.
 """
 from __future__ import annotations
 
@@ -28,88 +31,70 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
 
+# VMEM budget of one grid step's K tile of every head (its V tile is as
+# large; both are double-buffered, and the step widens both to f32: 8x
+# this budget of temporaries at most)
+_TILE_BYTES = 512 * 1024
 
-def _attend_step(b, s, h, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                 m_ref, l_ref, acc_ref, write, *, bs: int, scale: float):
-    """One (S-block, head) step of the online-softmax recurrence.
 
-    K and V arrive as (Dh, bs) tiles, the sequence on the lanes.  The
-    per-token K/V scales arrive lane-dense as (Hkv, bs) blocks, one per
-    S-block for every head, so they scale the (G, bs) logits and
+def attend_tiling(S: int, Hkv: int, Dh: int, itemsize: int = 1,
+                  bs: int = 256) -> int:
+    """The S-block one grid step of the attend holds, from the cache's
+    shapes alone: the largest of ``bs``, ``bs / 2``, ... that divides S (a
+    ragged S - not a 128 multiple - gets 32, and the caller pads S to it),
+    halved down to 128 while the (Hkv, Dh, bs) K tile of every head
+    outgrows ``_TILE_BYTES``."""
+    bs = min(bs, S)
+    while bs > 32 and S % bs:
+        bs //= 2
+    while (bs > 128 and Hkv * Dh * bs * itemsize > _TILE_BYTES
+           and S % (bs // 2) == 0):
+        bs //= 2
+    return bs
+
+
+def _attend_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, mask, m_ref, l_ref,
+                  acc_ref, write, *, scale: float):
+    """One S-block of the online-softmax recurrence for every KV head at
+    once: the (Hkv, Dh, bs) K and V tiles, widened, and the lane-dense
+    (Hkv, bs) scales, in two head-batched dot_generals.  ``write`` = (hit,
+    col, new token refs, K/V staging buffers): in the block that holds the
+    slot, patch the tiles and stage them.
+
+    The per-token K/V scales scale the (Hkv, G, bs) logits and
     probabilities instead of the tiles: q . (k * ks) == (q . k) * ks per
-    token, and likewise for v.  ``write`` (see ``_write_token``) puts the
-    step's new token into the tiles first, and into the cache."""
-    @pl.when(s == 0)
-    def _init():
-        m_ref[h] = jnp.full(m_ref.shape[1:], _NEG, jnp.float32)
-        l_ref[h] = jnp.zeros(l_ref.shape[1:], jnp.float32)
-        acc_ref[h] = jnp.zeros(acc_ref.shape[1:], jnp.float32)
-
-    offs = s * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    mask = offs < len_ref[b]                                    # (1, bs)
-
-    kf = k_ref[...].astype(jnp.float32)                         # (Dh, bs)
-    vf = v_ref[...].astype(jnp.float32)
-    ks = ks_ref[pl.ds(h, 1), :]                                 # (1, bs)
-    vs = vs_ref[pl.ds(h, 1), :]
+    token, and likewise for v."""
+    f32 = jnp.float32
+    tiles = (k_ref[...].astype(f32), v_ref[...].astype(f32), ks_ref[...],
+             vs_ref[...])
     if write is not None:
-        kf, vf, ks, vs = _write_token(b, s, h, offs, kf, vf, ks, vs, ks_ref,
-                                      vs_ref, *write, bs=bs)
+        hit, col, (kn, vn, ksn, vsn), kbuf, vbuf = write
 
-    logits = jnp.dot(q_ref[h], kf, preferred_element_type=jnp.float32)
-    logits = logits * (ks * scale)                              # (G, bs)
-    logits = jnp.where(mask, logits, _NEG)
+        def patch(kf, vf, ks, vs):
+            return (jnp.where(col[None], kn[...], kf),
+                    jnp.where(col[None], vn[...], vf),
+                    jnp.where(col, ksn[...], ks), jnp.where(col, vsn[...], vs))
+        tiles = jax.lax.cond(hit, patch, lambda *a: a, *tiles)
 
-    m_prev = m_ref[h]                                           # (G, 1)
+        @pl.when(hit)
+        def _stage():
+            kbuf[...] = tiles[0].astype(kbuf.dtype)
+            vbuf[...] = tiles[1].astype(vbuf.dtype)
+    kf, vf, ks, vs = tiles
+    logits = jax.lax.dot_general(q_ref[...], kf, (((2,), (1,)), ((0,), (0,))),
+                                 preferred_element_type=f32)
+    logits = logits * (ks * scale)[:, None, :]                  # (Hkv, G, bs)
+    logits = jnp.where(mask[None], logits, _NEG)
+
+    m_prev = m_ref[...]                                         # (Hkv, G, 1)
     m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-    p = jnp.exp(logits - m_new) * mask.astype(jnp.float32)      # (G, bs)
-    corr = jnp.exp(m_prev - m_new)                              # (G, 1)
-    l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
-        p * vs, vf, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[h] = m_new
-
-
-def _write_token(b, s, h, offs, kf, vf, ks, vs, ks_ref, vs_ref, layer_ref,
-                 slot_ref, kn_ref, vn_ref, ksn_ref, vsn_ref, *copy, bs: int):
-    """The decode step's token write, in the attend launch that reads it.
-
-    In the S-block that holds ``slot[b]``, row b's new K/V column (int8
-    values, carried as f32) and scales replace that lane of the tiles each
-    head attends, and the patched tiles are staged; after the block's last
-    head, DMAs put the block back into the cache: every head's K and V
-    tiles and their scales.  They run behind the attention, and
-    ``_wait_token`` collects them at the row's end.  The cache operands
-    alias the DMAs' destinations, so the write lands in place and the rest
-    of the cache is never touched."""
-    kbuf, vbuf, sbuf = copy[4:7]
-    slot = slot_ref[b]
-    lo = pl.multiple_of(s * bs, bs)
-    hit = (slot >= lo) & (slot < lo + bs)
-    col = offs == slot                                          # (1, bs)
-
-    def patch(kf, vf, ks, vs):
-        return (jnp.where(col, kn_ref[h], kf),                  # (Dh, 1) new
-                jnp.where(col, vn_ref[h], vf),
-                jnp.where(col, ksn_ref[pl.ds(h, 1), :], ks),    # (1, 1) new
-                jnp.where(col, vsn_ref[pl.ds(h, 1), :], vs))
-
-    kf, vf, ks, vs = jax.lax.cond(hit, patch, lambda *a: a, kf, vf, ks, vs)
-
-    @pl.when(hit)
-    def _stage():
-        kbuf[h] = kf.astype(kbuf.dtype)
-        vbuf[h] = vf.astype(vbuf.dtype)
-
-        @pl.when(h == kbuf.shape[0] - 1)
-        def _store():
-            sbuf[0] = jnp.where(col, ksn_ref[...], ks_ref[...])  # (Hkv, bs)
-            sbuf[1] = jnp.where(col, vsn_ref[...], vs_ref[...])
-            for c in _token_copies(layer_ref[0], b, lo, bs, *copy):
-                c.start()
-
-    return kf, vf, ks, vs
+    p = jnp.exp(logits - m_new) * mask[None].astype(f32)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+        p * vs[:, None, :], vf, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=f32)
+    m_ref[...] = m_new
 
 
 def _token_copies(layer, b, lo, bs, k_hbm, v_hbm, ks_hbm, vs_hbm, kbuf, vbuf,
@@ -123,14 +108,6 @@ def _token_copies(layer, b, lo, bs, k_hbm, v_hbm, ks_hbm, vs_hbm, kbuf, vbuf,
                                   sem),
             pltpu.make_async_copy(sbuf.at[1], vs_hbm.at[layer, b, :, window],
                                   sem))
-
-
-def _wait_token(b, layer_ref, slot_ref, *copy, bs: int):
-    """Wait for row b's token write (before the next row reuses the
-    buffers, and before the launch ends)."""
-    lo = pl.multiple_of(slot_ref[b] // bs * bs, bs)
-    for c in _token_copies(layer_ref[0], b, lo, bs, *copy):
-        c.wait()
 
 
 def _finish_plain(o, o_ref):
@@ -155,36 +132,84 @@ def _finish_fused(o, o_ref, oq_ref, sx_ref, s1_ref, s2_ref):
 
 def _kernel(*refs, bs: int, scale: float, n_out: int, write: bool, finish):
     """Both attend kernels over the operands of ``_attend_call`` in order,
-    then the outputs, then the scratch."""
+    then the outputs, then the scratch.
+
+    A step attends S-block s of row b for every KV head, if the row holds
+    it (``_last_block``).  With the token write, the block holding
+    ``slot[b]`` is patched before it is attended, and its patched tiles
+    are staged; DMAs then put the block back into the cache: every head's
+    K and V tiles and their scales.  They run behind the attention, and
+    are collected at the row's end.  The cache operands alias the DMAs'
+    destinations, so the write lands in place and the rest of the cache is
+    never touched."""
     if write:
         layer_ref, len_ref, slot_ref, *refs = refs
     else:
         layer_ref, len_ref, *refs = refs
+        slot_ref = None
     q_ref, k_ref, v_ref, ks_ref, vs_ref, *refs = refs
     news, refs = (refs[:4], refs[4:]) if write else ((), refs)
     outs, refs = refs[:n_out], refs[n_out:]
     hbm, refs = (refs[:4], refs[4:]) if write else ((), refs)
     m_ref, l_ref, acc_ref, *bufs = refs
-    b, s, h = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    copy = (*hbm, *bufs)
-    _attend_step(b, s, h, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                 m_ref, l_ref, acc_ref,
-                 (layer_ref, slot_ref, *news, *copy) if write else None,
-                 bs=bs, scale=scale)
+    b, s = pl.program_id(0), pl.program_id(1)
 
-    # at a batch row's last step every head has seen every S-block, so the
-    # whole row's output is finished at once
-    @pl.when((s == pl.num_programs(1) - 1) & (h == pl.num_programs(2) - 1))
+    @pl.when(s == 0)
+    def _init():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    lo = pl.multiple_of(s * bs, bs)
+    offs = lo + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+    mask = offs < len_ref[b]                                    # (1, bs)
+    if write:
+        copy = (*hbm, *bufs)
+        kbuf, vbuf, sbuf = bufs[:3]
+        slot = slot_ref[b]
+        hit = (slot >= lo) & (slot < lo + bs)
+        col = offs == slot                                      # (1, bs)
+
+    @pl.when(s <= _last_block(b, len_ref, slot_ref, bs))
+    def _step():
+        _attend_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, mask, m_ref,
+                      l_ref, acc_ref,
+                      (hit, col, news, kbuf, vbuf) if write else None,
+                      scale=scale)
+        if write:
+            @pl.when(hit)
+            def _store():
+                sbuf[0] = jnp.where(col, news[2][...], ks_ref[...])
+                sbuf[1] = jnp.where(col, news[3][...], vs_ref[...])
+                for c in _token_copies(layer_ref[0], b, lo, bs, *copy):
+                    c.start()
+
+    @pl.when(s == pl.num_programs(1) - 1)
     def _finish():
         finish(acc_ref[...] / jnp.maximum(l_ref[...], 1e-30), *outs)
         if write:
-            _wait_token(b, layer_ref, slot_ref, *copy, bs=bs)
+            start = pl.multiple_of(slot // bs * bs, bs)
+            for c in _token_copies(layer_ref[0], b, start, bs, *copy):
+                c.wait()
 
 
 def _row_spec(*tail):
     """One batch row's whole block (the query, the new token, the outputs)."""
-    return pl.BlockSpec((None,) + tail,
-                        lambda b, s, h, *_: (b,) + (0,) * len(tail))
+    return pl.BlockSpec((None,) + tail, lambda b, s, *_: (b,) + (0,) * len(tail))
+
+
+def _last_block(b, len_ref, slot_ref, bs: int):
+    """The last S-block of row b that a launch attends: the one holding the
+    row's last token (-1 for an empty row: none), or, with the token write,
+    the slot's if that lies further.  The kernel attends no step past it,
+    and the index maps clamp to it (a repeated block index issues no DMA).
+    Attending a wholly masked block would add exactly nothing, so where the
+    slot lies past the length the clamp changes no result."""
+    n = len_ref[b]
+    last = jnp.where(n > 0, (n - 1) // bs, -1)
+    if slot_ref is not None:
+        last = jnp.maximum(last, slot_ref[b] // bs)
+    return last
 
 
 def _check_s(name: str, S: int, bs: int) -> None:
@@ -196,26 +221,35 @@ def _check_s(name: str, S: int, bs: int) -> None:
 
 def _attend_call(name, fused, q, k_q, v_q, k_scale, v_scale, length, layer,
                  new, bs, interpret):
-    """One launch over grid (B, S/bs, Hkv) of the LAYER-STACKED cache.
+    """One launch over grid (B, S/bs) of the LAYER-STACKED cache.
 
     The layer index and the per-row lengths (and the write slots) are
     scalar-prefetched, and the K/V index maps pick the layer's tiles
     straight out of the (L, B, Hkv, S, Dh) stack, so no per-layer slice of
     the cache is ever materialised.  K and V are read sequence-minor, as
-    (Dh, bs) tiles of the stack viewed (L, B, Hkv, Dh, S): that is how XLA
-    lays out the carried int8 stack (a 64-wide Dh would fill half of every
-    128-lane tile), so the view is a relabelling, not a copy.  The head
-    axis runs innermost so an S-block's (Hkv, bs) scale block (the
-    smallest legal block of an (Hkv, S) row: a (1, bs) one breaks the
-    TPU's (8, 128) tiling) is fetched once for all heads."""
+    (Hkv, Dh, bs) tiles of the stack viewed (L, B, Hkv, Dh, S): that is how
+    XLA lays out the carried int8 stack (a 64-wide Dh would fill half of
+    every 128-lane tile), so the view is a relabelling, not a copy.  The
+    scales come as lane-dense (Hkv, bs) blocks of the (Hkv, S) rows."""
     B, Hkv, G, Dh = q.shape
     S = k_q.shape[3]
-    bs = min(bs, S)
+    bs = attend_tiling(S, Hkv, Dh, k_q.dtype.itemsize, min(bs, S))
     _check_s(name, S, bs)
-    tile = pl.BlockSpec((None, None, None, Dh, bs),
-                        lambda b, s, h, l, *_: (l[0], b, h, 0, s))
+    assert Hkv * Dh * bs * k_q.dtype.itemsize <= _TILE_BYTES, (
+        f"{name}: the ({Hkv}, {Dh}, {bs}) K tile of every head outgrows the "
+        f"kernel's {_TILE_BYTES}-byte budget")
+    write = new is not None
+
+    def block(b, s, len_ref, *slot_ref):
+        # step s reads block s while the row holds it, then its last block
+        # again (block 0 for an empty row)
+        last = _last_block(b, len_ref, slot_ref[0] if write else None, bs)
+        return jnp.maximum(jnp.minimum(s, last), 0)
+
+    tile = pl.BlockSpec((None, None, Hkv, Dh, bs),
+                        lambda b, s, l, *r: (l[0], b, 0, 0, block(b, s, *r)))
     scales = pl.BlockSpec((None, None, Hkv, bs),
-                          lambda b, s, h, l, *_: (l[0], b, 0, s))
+                          lambda b, s, l, *r: (l[0], b, 0, block(b, s, *r)))
     prefetch = [jnp.reshape(layer, (1,)).astype(jnp.int32),
                 length.astype(jnp.int32)]
     args = [q, jnp.swapaxes(k_q, 3, 4), jnp.swapaxes(v_q, 3, 4), k_scale,
@@ -235,7 +269,7 @@ def _attend_call(name, fused, q, k_q, v_q, k_scale, v_scale, length, layer,
                pltpu.VMEM((Hkv, G, Dh), jnp.float32)]
     n_out = len(out_shape)
     aliases = {}
-    if new is not None:
+    if write:
         slots, k_new, v_new, ks_new, vs_new = new
         prefetch.append(slots.astype(jnp.int32))
         args += [k_new.astype(jnp.float32)[..., None],
@@ -255,11 +289,11 @@ def _attend_call(name, fused, q, k_q, v_q, k_scale, v_scale, length, layer,
                     pltpu.SemaphoreType.DMA]
     kern = functools.partial(
         _kernel, bs=bs, scale=1.0 / (Dh ** 0.5), n_out=n_out,
-        write=new is not None, finish=_finish_fused if fused else _finish_plain)
+        write=write, finish=_finish_fused if fused else _finish_plain)
     outs = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch), grid=(B, S // bs, Hkv),
+            num_scalar_prefetch=len(prefetch), grid=(B, S // bs),
             in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
         out_shape=out_shape,
         input_output_aliases=aliases,
@@ -267,7 +301,7 @@ def _attend_call(name, fused, q, k_q, v_q, k_scale, v_scale, length, layer,
         interpret=interpret,
     )(*prefetch, *args)
     o = tuple(outs[:n_out]) if fused else outs[0]
-    if new is None:
+    if not write:
         return o
     kt, vt, ks, vs = outs[n_out:]
     return o, (jnp.swapaxes(kt, 3, 4), jnp.swapaxes(vt, 3, 4), ks, vs)

@@ -19,7 +19,7 @@ import numpy as np
 
 from . import ref
 from .act_stats import act_stats_p
-from .kv_cache import (cache_scatter_p, cache_scatter_pages_p,
+from .kv_cache import (attend_tiling, cache_scatter_p, cache_scatter_pages_p,
                        decode_attend_i8kv_fused_p, decode_attend_i8kv_p,
                        scatter_rows_block)
 from .pdq_prologue import pdq_prologue_p
@@ -122,9 +122,11 @@ def pdq_guard(enable: bool = True):
 
 
 # the summary every launch returns and engines unpack, field by field:
-# guard fallbacks, int8 clip hits, outputs checked for clipping, and the
-# routed (row, expert) assignments MoE layers gave the experts they hold
-TEL_FIELDS = ("fallbacks", "clip_hits", "clip_total", "expert_rows")
+# guard fallbacks, int8 clip hits, outputs checked for clipping, the
+# routed (row, expert) assignments MoE layers gave the experts they hold,
+# and the S-blocks the int8-KV attend read and its grid spanned
+TEL_FIELDS = ("fallbacks", "clip_hits", "clip_total", "expert_rows",
+              "attend_blocks", "attend_blocks_grid")
 PDQ_TEL_WIDTH = len(TEL_FIELDS)
 
 
@@ -136,7 +138,8 @@ class PdqTelemetryCollector:
     fallback-activation flag (the same fused finiteness reduction the
     guard's ``cond`` already computes), int8 clip-saturation hit counts
     and the elements checked; an MoE layer records the routed rows its
-    held experts took.  ``summary()`` folds them into ONE
+    held experts took, and the int8-KV attend the S-blocks it read.
+    ``summary()`` folds them into ONE
     (``PDQ_TEL_WIDTH``,) float32 in ``TEL_FIELDS`` order that the launch
     returns alongside its tokens - the host reads it in the existing
     token gather, so it costs zero extra round-trips and adds no
@@ -760,7 +763,8 @@ def decode_attend_i8kv(q, k_q, v_q, k_scale, v_scale, length, *, layer=None,
 
     q: (B, H, Dh) f32; k_q/v_q: (B, Hkv, S, Dh) int8;
     k_scale/v_scale: (B, Hkv, S) f32; length: (B,) int32.
-    Returns (B, H, Dh) f32.
+    Returns (B, H, Dh) f32.  ``bs`` bounds the kernel's S-block; the
+    block follows from the shapes (``kv_cache.attend_tiling``).
 
     ``layer`` (a traced or static int) reads a LAYER-STACKED cache
     instead: k_q/v_q (L, B, Hkv, S, Dh), scales (L, B, Hkv, S), and the
@@ -797,6 +801,15 @@ def decode_attend_i8kv(q, k_q, v_q, k_scale, v_scale, length, *, layer=None,
     Hkv, S = cache[0].shape[2], cache[0].shape[3]
     G = H // Hkv
 
+    # the S-blocks the kernel reads (its grid skips those past a row's
+    # length) and those its grid spans, counted on either path
+    bss = attend_tiling(S, Hkv, Dh, cache[0].dtype.itemsize, bs)
+    nb = -(-S // bss)
+    tel_count("attend_blocks",
+              jnp.sum(jnp.minimum(-(-jnp.asarray(length, jnp.int32) // bss),
+                                  nb)))
+    tel_count("attend_blocks_grid", B * nb)
+
     if not _use_kernel():
         if new is not None:
             slots, *tok = new
@@ -813,11 +826,8 @@ def decode_attend_i8kv(q, k_q, v_q, k_scale, v_scale, length, *, layer=None,
             of = o.astype(pro_dtype) if pro_dtype is not None else o
             o = (o, *ref.pdq_prologue_ref(of.reshape(B, H * Dh)))
     else:
-        # prefer a scan block that divides S (true whenever the cache came
-        # from init_cache, which rounds S to a 128 multiple) over padding
-        bss = min(bs, S)
-        while bss > 32 and S % bss:
-            bss //= 2
+        # a block that divides S (true whenever the cache came from
+        # init_cache, which rounds S to a 128 multiple) needs no padding
         cache = (_pad_to(cache[0], 3, bss), _pad_to(cache[1], 3, bss),
                  _pad_to(cache[2], 3, bss, value=1.0),
                  _pad_to(cache[3], 3, bss, value=1.0))

@@ -294,6 +294,8 @@ class SchedulerCore:
             "pdq_clip_total": 0,       # int8 outputs checked
             "expert_rows": 0,          # decode rows routed to held experts
                                        # (every (row, expert) assignment)
+            "attend_blocks": 0,        # S-blocks the int8-KV attend read
+            "attend_blocks_grid": 0,   # S-blocks its grid spanned
             # per-replica occupancy/admit accounting (single-replica engines
             # report one-element lists)
             "replica_admits": [0] * n_replicas,
@@ -383,7 +385,8 @@ class SchedulerCore:
             self.stats["pdq_fallbacks"] += int(round(fb))
             self.stats["pdq_clip_hits"] += int(round(hits))
             self.stats["pdq_clip_total"] += int(round(total))
-            self.stats["expert_rows"] += int(round(tel["expert_rows"]))
+            for f in ("expert_rows", "attend_blocks", "attend_blocks_grid"):
+                self.stats[f] += int(round(tel[f]))
         self.tel.observe_pdq(fb, hits, total)
 
     # ------------------------------------------------------------ exec hooks

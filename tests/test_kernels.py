@@ -12,7 +12,7 @@ try:
 except ImportError:  # clean envs: deterministic shim, see requirements-dev.txt
     from _hypo_compat import given, settings, strategies as st
 
-from repro.kernels import ops, ref
+from repro.kernels import kv_cache, ops, ref
 from repro.kernels.act_stats import act_stats_p
 from repro.kernels.kv_cache import decode_attend_i8kv_fused_p, decode_attend_i8kv_p
 from repro.kernels.pdq_prologue import pdq_prologue_p
@@ -187,18 +187,49 @@ def _stack_layer1(a):
     return jnp.stack([jnp.flip(a, -1), a])[:, None]
 
 
-@pytest.mark.parametrize("s", [200, 256])   # ragged (padded per call) + aligned
-def test_decode_i8kv_ops_batched(s):
+# lengths per row that sit on and around the S-blocks' edges (bs 128 of a
+# 512 cache), and the KV-head shapes of the configurations: stablelm's 32 x
+# 64 heads (G 1), yi-6b's 4 x 128 (G 8) and phi-3's 32 x 96 (G 1)
+EDGE_LENS = [0, 1, 127, 128, 129, 512]
+HEAD_CASES = [
+    pytest.param(512, (32, 1, 64), EDGE_LENS, id="stablelm_heads"),
+    pytest.param(512, (4, 8, 128), EDGE_LENS, id="yi_heads"),
+    pytest.param(512, (32, 1, 96), EDGE_LENS, id="phi3_heads"),
+]
+
+
+@pytest.mark.parametrize("S,heads,bs", [
+    pytest.param(1024, (32, 64), 256, id="stablelm"),
+    pytest.param(1024, (4, 128), 256, id="yi"),
+    pytest.param(1024, (32, 96), 128, id="phi3"),      # halved to fit
+    pytest.param(200, (32, 64), 200, id="short"),      # one whole block
+    pytest.param(1000, (32, 64), 32, id="ragged"),     # padded to 1024
+])
+def test_attend_tiling_from_shapes(S, heads, bs):
+    """The attend's S-block follows from the cache's shapes: the largest of
+    256, 128, ... (down to 32) that divides S, or S itself when shorter,
+    halved while the K tile of every head outgrows the budget."""
+    Hkv, Dh = heads
+    assert kv_cache.attend_tiling(S, Hkv, Dh) == bs
+    assert Hkv * Dh * bs <= kv_cache._TILE_BYTES
+
+
+@pytest.mark.parametrize("s,shape,lens", [
+    pytest.param(200, (2, 2, 64), [130, 57], id="200"),  # ragged: padded
+    pytest.param(256, (2, 2, 64), [130, 57], id="256"),  # aligned
+    *HEAD_CASES])
+def test_decode_i8kv_ops_batched(s, shape, lens):
     """ops takes the cache in KERNEL layout (B, Hkv, S, Dh); the oracle
-    keeps the logical (S, Hkv) layout."""
-    B, Hkv, G, Dh = 2, 2, 2, 64
+    keeps the logical (S, Hkv) layout.  A row of length 0 reads zeros."""
+    Hkv, G, Dh = shape
+    B = len(lens)
     keys = jax.random.split(jax.random.PRNGKey(0), 5)
     q = jax.random.normal(keys[0], (B, Hkv * G, Dh))
     k_q = _rand_i8(keys[1], (B, Hkv, s, Dh))
     v_q = _rand_i8(keys[2], (B, Hkv, s, Dh))
     k_s = jax.random.uniform(keys[3], (B, Hkv, s), minval=0.01, maxval=0.05)
     v_s = jax.random.uniform(keys[4], (B, Hkv, s), minval=0.01, maxval=0.05)
-    lens = jnp.array([130, 57], jnp.int32)
+    lens = jnp.array(lens, jnp.int32)
     ops.set_impl("kernel")
     try:
         got = ops.decode_attend_i8kv(q, k_q, v_q, k_s, v_s, lens, bs=128)
@@ -207,24 +238,34 @@ def test_decode_i8kv_ops_batched(s):
     want = jax.vmap(ref.decode_attend_i8kv_ref)(
         q, jnp.transpose(k_q, (0, 2, 1, 3)), jnp.transpose(v_q, (0, 2, 1, 3)),
         jnp.transpose(k_s, (0, 2, 1)), jnp.transpose(v_s, (0, 2, 1)), lens)
-    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=2e-4, atol=2e-4)
+    assert not np.asarray(got)[~live].any()
 
 
-@pytest.mark.parametrize("s", [200, 256])   # ragged (padded per call) + aligned
+@pytest.mark.parametrize("s,shape,lens", [
+    pytest.param(200, (2, 2, 64), [1, 131, 200], id="200"),
+    pytest.param(256, (2, 2, 64), [1, 131, 256], id="256"),
+    *HEAD_CASES])
 @pytest.mark.parametrize("wo_prologue", [False, True], ids=["plain", "fused"])
-def test_decode_i8kv_writes_token_in_place(wo_prologue, s):
+def test_decode_i8kv_writes_token_in_place(wo_prologue, s, shape, lens):
     """With ``new`` the attend launch writes the step's token into its layer
     of the stack, then attends it: the kernel's stacks equal the written
     stacks bit for bit (every other entry unchanged), and its output is the
-    oracle's over them."""
-    L, B, Hkv, G, Dh = 3, 3, 2, 2, 64
+    oracle's over them.  The token goes to the row's last position (the
+    first, second and last block of the 200/256 caches), and to position 0
+    of a row of length 0, whose output is zeros."""
+    Hkv, G, Dh = shape
+    L, B = 3, len(lens)
     keys = jax.random.split(jax.random.PRNGKey(11), 9)
     q = jax.random.normal(keys[0], (B, Hkv * G, Dh))
     cache = (_rand_i8(keys[1], (L, B, Hkv, s, Dh)),
              _rand_i8(keys[2], (L, B, Hkv, s, Dh)),
              jax.random.uniform(keys[3], (L, B, Hkv, s), minval=0.01, maxval=0.05),
              jax.random.uniform(keys[4], (L, B, Hkv, s), minval=0.01, maxval=0.05))
-    slots = jnp.array([0, 130, s - 1], jnp.int32)   # first, second, last block
+    lens = jnp.array(lens, jnp.int32)
+    slots = jnp.maximum(lens - 1, 0)
     tok = (_rand_i8(keys[5], (B, Hkv, Dh)), _rand_i8(keys[6], (B, Hkv, Dh)),
            jax.random.uniform(keys[7], (B, Hkv), minval=0.01, maxval=0.05),
            jax.random.uniform(keys[8], (B, Hkv), minval=0.01, maxval=0.05))
@@ -235,7 +276,7 @@ def test_decode_i8kv_writes_token_in_place(wo_prologue, s):
         ops.set_impl(impl)
         try:
             got[impl] = ops.decode_attend_i8kv(
-                q, *cache, slots + 1, layer=jnp.int32(1), new=(slots, *tok),
+                q, *cache, lens, layer=jnp.int32(1), new=(slots, *tok),
                 bs=128, wo_prologue=wo_prologue,
                 pro_dtype=jnp.float32 if wo_prologue else None)
         finally:
@@ -247,7 +288,45 @@ def test_decode_i8kv_writes_token_in_place(wo_prologue, s):
     o_ref, o_kernel = got["ref"][0], got["kernel"][0]
     if wo_prologue:
         o_ref, o_kernel = o_ref[0], o_kernel[0]
-    np.testing.assert_allclose(o_kernel, o_ref, rtol=2e-4, atol=2e-4)
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(np.asarray(o_kernel)[live],
+                               np.asarray(o_ref)[live], rtol=2e-4, atol=2e-4)
+    assert not np.asarray(o_kernel)[~live].any()
+
+
+@pytest.mark.parametrize("shape", [(32, 1, 64), (4, 8, 128)],
+                         ids=["stablelm_heads", "yi_heads"])
+@pytest.mark.parametrize("fused", [False, True], ids=["plain", "fused"])
+def test_decode_i8kv_never_reads_blocks_past_the_length(shape, fused):
+    """Every S-block wholly past a row's length holds NaN scales: the
+    kernel still equals the oracle over the row's first ``len`` tokens and
+    is finite, because it neither attends nor reads those blocks (a
+    masked block that were attended would spread 0 x NaN).  A row of
+    length 0 reads zeros."""
+    Hkv, G, Dh = shape
+    s, bs, L = 512, 128, 2
+    lens = np.array(EDGE_LENS, np.int32)
+    B = len(lens)
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    q = jax.random.normal(keys[0], (B, Hkv, G, Dh))
+    k_q = _rand_i8(keys[1], (L, B, Hkv, s, Dh))
+    v_q = _rand_i8(keys[2], (L, B, Hkv, s, Dh))
+    dead = np.arange(s)[None, None, :] >= (-(-lens // bs) * bs)[:, None, None]
+    k_s, v_s = (jnp.where(dead[None], jnp.nan, jax.random.uniform(
+        k, (L, B, Hkv, s), minval=0.01, maxval=0.05)) for k in keys[3:])
+    kern = decode_attend_i8kv_fused_p if fused else decode_attend_i8kv_p
+    got = kern(q, k_q, v_q, k_s, v_s, jnp.asarray(lens), jnp.int32(1), bs=bs,
+               interpret=True)
+    got = np.asarray(got[0] if fused else got).reshape(B, Hkv * G, Dh)
+    assert np.isfinite(got).all()
+    for b, n in enumerate(lens):
+        if n == 0:
+            assert not got[b].any()
+            continue
+        row = [jnp.swapaxes(a[1, b, :, :n], 0, 1) for a in (k_q, v_q, k_s, v_s)]
+        want = ref.decode_attend_i8kv_ref(q[b].reshape(Hkv * G, Dh), *row,
+                                          jnp.int32(n))
+        np.testing.assert_allclose(got[b], want, rtol=2e-4, atol=2e-4)
 
 
 # ---------------------------------------------------------------------------

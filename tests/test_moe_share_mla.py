@@ -149,7 +149,8 @@ def test_no_held_assignment_is_dropped(quantize):
     with ops.pdq_telemetry(True) as col:
         y, _ = moe_ffn_dense_masked(p, x, cfg, count_rows=True)
         rows = col.summary()
-    assert rows.shape == (4,) and float(rows[3]) == 64
+    assert rows.shape == (ops.PDQ_TEL_WIDTH,)
+    assert float(rows[ops.TEL_FIELDS.index("expert_rows")]) == 64
     tol = 0.05 * float(jnp.abs(want).max()) if quantize else 1e-5
     assert float(jnp.abs(y - want).max()) < tol
     assert bool(jnp.all(jnp.abs(y).max(axis=-1) > 0))

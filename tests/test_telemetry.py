@@ -23,8 +23,10 @@ Pins the observability contract:
     serving loop (the PR-9 snapshot-under-lock fix - list-valued counters
     used to be serialized while the loop thread resized them);
   * the device-side pdq collector counts clip saturation and guard
-    fallbacks without adding pallas_calls (census pinned elsewhere).
+    fallbacks without adding pallas_calls (census pinned elsewhere), and
+    the S-blocks the int8-KV attend reads and its grid spans.
 """
+import dataclasses
 import glob
 import http.client
 import json
@@ -45,6 +47,7 @@ from test_serve_service import _http, _prompts, _req, _sse, _wait
 
 from repro.configs import reduced_config
 from repro.kernels import ops
+from repro.kernels.kv_cache import attend_tiling
 from repro.models import build_model
 from repro.models.linops import quantize_weight
 from repro.serve import Request, ServeConfig, ServeService, build_engine
@@ -284,6 +287,27 @@ def test_pdq_collector_disabled_is_constant_zeros():
     _, tel = jax.jit(prog)(x)
     assert np.asarray(tel).tolist() == [0.0] * len(ops.TEL_FIELDS)
     assert np.asarray(tel).shape == (ops.PDQ_TEL_WIDTH,)
+
+
+def test_engine_counts_the_attend_blocks_it_reads(small_model):
+    """``attend_blocks`` in ``/v1/stats`` is the sum over decode steps,
+    layers and rows of ceil(len / bs), the S-blocks the int8-KV attend
+    reads; ``attend_blocks_grid`` is rows x S / bs x layers x steps.
+    Counted on the jnp path too, with the kernel's block."""
+    cfg, _, params = small_model
+    cfg = dataclasses.replace(cfg, quant_kv="dynamic")
+    eng = _engine(cfg, params, slots=1, max_len=512, buckets=(256,))
+    prompt = np.arange(250, dtype=np.int32) % cfg.vocab
+    eng.run([Request(uid=0, prompt=prompt, max_new=12)])
+    stats = eng.stats_snapshot()
+    S = 512
+    bs = attend_tiling(S, cfg.n_kv_heads, cfg.head_dim)
+    assert S // bs == 2
+    steps = stats["decode_steps"]
+    assert steps == 11                      # the prefill gave the first token
+    lens = len(prompt) + 1 + np.arange(steps)   # 251 .. 261: crosses 256
+    assert stats["attend_blocks"] == cfg.n_layers * int(np.sum(-(-lens // bs)))
+    assert stats["attend_blocks_grid"] == cfg.n_layers * steps * S // bs
 
 
 # ---------------------------------------------------------------------------

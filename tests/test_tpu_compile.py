@@ -25,7 +25,7 @@ from jax.experimental.layout import Format, Layout
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels import ops
+from repro.kernels import kv_cache, ops
 from repro.kernels.kv_cache import (cache_scatter_p, decode_attend_i8kv_fused_p,
                                     decode_attend_i8kv_p, scatter_rows_block)
 from repro.kernels.pdq_prologue import pdq_prologue_p
@@ -128,28 +128,45 @@ def test_w8a8_swiglu_matmul_compiles_for_v5e(one_chip):
              ((M, nb), f32), ((M, nb), f32))
 
 
-@pytest.mark.parametrize("fused,layers", [(True, 1), (False, 1), (True, 24),
-                                          (False, 24)],
-                         ids=["fused", "plain", "fused_stacked", "plain_stacked"])
-def test_decode_attend_i8kv_compiles_for_v5e(one_chip, fused, layers):
+@pytest.mark.parametrize("fused,layers,B,S,heads", [
+    pytest.param(True, 1, 8, 2048, (N_HEADS, 1, HEAD_DIM), id="fused"),
+    pytest.param(False, 1, 8, 2048, (N_HEADS, 1, HEAD_DIM), id="plain"),
+    pytest.param(True, 24, 8, 2048, (N_HEADS, 1, HEAD_DIM),
+                 id="fused_stacked"),
+    pytest.param(False, 24, 8, 2048, (N_HEADS, 1, HEAD_DIM),
+                 id="plain_stacked"),
+    # the pdq-int8.decode cell: 16 rows of a 1024-token cache
+    pytest.param(True, 24, 16, 1024, (N_HEADS, 1, HEAD_DIM), id="fused_cell"),
+    pytest.param(False, 24, 16, 1024, (N_HEADS, 1, HEAD_DIM),
+                 id="plain_cell"),
+    # yi-6b's 4 KV heads of 128, 8 queries each
+    pytest.param(True, 32, 16, 1024, (4, 8, 128), id="fused_yi"),
+    # phi-3's 32 KV heads of 96 outgrow the tile budget at 256 tokens:
+    # 128-token blocks
+    pytest.param(True, 32, 16, 1024, (32, 1, 96), id="fused_phi3"),
+])
+def test_decode_attend_i8kv_compiles_for_v5e(one_chip, fused, layers, B, S,
+                                             heads):
     # one layer's cache; or, as the decode step runs it, a layer of the
-    # whole 24-layer stack (a traced index) with the step's token written
-    # into it in place by the same launch
-    B, S = 8, 2048
+    # whole stack (a traced index) with the step's token written into it in
+    # place by the same launch.  A grid step holds the S-block of every KV
+    # head, and has to fit the kernel's VMEM.
+    Hkv, G, Dh = heads
+    bs = kv_cache.attend_tiling(S, Hkv, Dh)
+    assert bs == (128 if Dh == 96 else 256)
     kern = decode_attend_i8kv_fused_p if fused else decode_attend_i8kv_p
     stacked = layers > 1
 
     def fn(q, k, v, ks, vs, ln, layer, slots, kn, vn, ksn, vsn):
         new = (slots, kn, vn, ksn, vsn) if stacked else None
-        return kern(q, k, v, ks, vs, ln, layer, new, bs=256)
+        return kern(q, k, v, ks, vs, ln, layer, new, bs=bs)
 
     _compile(
-        fn, one_chip, ((B, N_HEADS, 1, HEAD_DIM), f32),
-        ((layers, B, N_HEADS, S, HEAD_DIM), i8),
-        ((layers, B, N_HEADS, S, HEAD_DIM), i8),
-        ((layers, B, N_HEADS, S), f32), ((layers, B, N_HEADS, S), f32),
-        ((B,), i32), ((), i32), ((B,), i32), ((B, N_HEADS, HEAD_DIM), i8),
-        ((B, N_HEADS, HEAD_DIM), i8), ((B, N_HEADS), f32), ((B, N_HEADS), f32))
+        fn, one_chip, ((B, Hkv, G, Dh), f32),
+        ((layers, B, Hkv, S, Dh), i8), ((layers, B, Hkv, S, Dh), i8),
+        ((layers, B, Hkv, S), f32), ((layers, B, Hkv, S), f32),
+        ((B,), i32), ((), i32), ((B,), i32), ((B, Hkv, Dh), i8),
+        ((B, Hkv, Dh), i8), ((B, Hkv), f32), ((B, Hkv), f32))
 
 
 @pytest.mark.parametrize("dtype,rows", [(bf16, 1024), (i8, 1024), (f32, 64)],
